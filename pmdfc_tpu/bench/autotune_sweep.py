@@ -238,7 +238,7 @@ def main() -> int:
         stamp_live_device)
     from pmdfc_tpu.config import autotune_enabled, net_pipe_enabled
 
-    enable_compile_cache(strict=True)
+    enable_compile_cache()
     if not net_pipe_enabled():
         print("[autotune_sweep] PMDFC_NET_PIPE=off — the coalesced "
               "tier is disabled; nothing to sweep")

@@ -162,7 +162,7 @@ def main() -> int:
         net_pipe_enabled
     from pmdfc_tpu.runtime.net import NetServer
 
-    enable_compile_cache(strict=True)
+    enable_compile_cache()
     if not net_pipe_enabled():
         print("[fastpath_sweep] PMDFC_NET_PIPE=off — the coalesced tier "
               "is disabled; nothing to sweep")

@@ -115,7 +115,7 @@ def main() -> int:
     from pmdfc_tpu.parallel.plane import make_serving_backend
     from pmdfc_tpu.runtime.net import NetServer
 
-    enable_compile_cache(strict=True)
+    enable_compile_cache()
     if not mesh_enabled():
         print("[mesh_sweep] PMDFC_MESH=off — nothing to sweep")
         return 2

@@ -256,7 +256,7 @@ def main() -> int:
     from pmdfc_tpu.config import net_pipe_enabled, qos_enabled
     from pmdfc_tpu.runtime import qos as qos_mod
 
-    enable_compile_cache(strict=True)
+    enable_compile_cache()
     if not net_pipe_enabled():
         print("[qos_soak] PMDFC_NET_PIPE=off — the coalesced tier is "
               "disabled; nothing to soak")

@@ -26,9 +26,8 @@ What the artifact prices:
 - `misses == Σ causes` — asserted at every stats poll, throughout
   recovery (the `miss_recovering` lane keeps the taxonomy exact).
 
-Run: `python -m pmdfc_tpu.bench.recovery_soak --smoke` (CI hook via
-`tools/tpu_agenda.sh step recovery_smoke`; asserts the invariants and
-exits nonzero) or with real sizes; `--history` appends paired
+Run: `python -m pmdfc_tpu.bench.recovery_soak --smoke` (asserts the
+invariants and exits nonzero on a violation) or with real sizes; `--history` appends paired
 `host_evidence` rows under `tools/check_bench.py`.
 """
 
@@ -109,7 +108,7 @@ def run(args) -> dict:
     from pmdfc_tpu.runtime.net import TcpBackend
     from tools.crashbox import Crashbox
 
-    enable_compile_cache(strict=True)
+    enable_compile_cache()
     if args.device == "cpu":
         pin_cpu()
     kv_cfg = KVConfig(index=IndexConfig(capacity=args.capacity),

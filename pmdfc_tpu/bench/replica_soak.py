@@ -154,7 +154,7 @@ def run(args) -> dict:
         append_history, enable_compile_cache, pin_cpu, stamp_live_device)
     from pmdfc_tpu.config import BloomConfig, IndexConfig, KVConfig
 
-    enable_compile_cache(strict=True)  # bench rows need the verified pin
+    enable_compile_cache()
     if args.device == "cpu":
         pin_cpu()
     kv_cfg = KVConfig(

@@ -12,6 +12,7 @@ Reference probe geometry being mirrored: 4 pairs/cacheline × 8 cachelines =
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from pmdfc_tpu.utils.keys import INVALID_WORD, is_invalid
@@ -20,9 +21,7 @@ from pmdfc_tpu.utils.keys import INVALID_WORD, is_invalid
 def match_mask(rows: jnp.ndarray, keys: jnp.ndarray, s: int) -> jnp.ndarray:
     """eq[B, S]: key-equality one-hot with INVALID queries masked off —
     the single definition of "this lane holds this key"."""
-    eq = (rows[:, 0:s] == keys[:, None, 0]) & (
-        rows[:, s : 2 * s] == keys[:, None, 1]
-    )
+    eq = (rows[:, 0:s] == keys[:, 0:1]) & (rows[:, s : 2 * s] == keys[:, 1:2])
     return eq & ~is_invalid(keys)[:, None]
 
 
@@ -34,9 +33,12 @@ def match_rows(rows: jnp.ndarray, keys: jnp.ndarray, s: int):
 
 
 def lane_pick(rows: jnp.ndarray, onehot: jnp.ndarray, lo: int, s: int):
-    """Masked-sum extraction of ONE lane per row (≤1 hot lane per row)."""
-    grp = rows[:, lo : lo + s]
-    return jnp.where(onehot, grp, jnp.uint32(0)).sum(axis=1, dtype=jnp.uint32)
+    """Masked-sum extraction of ONE lane per row (≤1 hot lane per row).
+    Summed as int32 (same bits: one nonzero term, wrapping adds) because
+    Mosaic cannot reduce unsigned integers."""
+    grp = jax.lax.bitcast_convert_type(rows[:, lo : lo + s], jnp.int32)
+    picked = jnp.where(onehot, grp, jnp.int32(0)).sum(axis=1, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(picked, jnp.uint32)
 
 
 def pick_kv(rows: jnp.ndarray, onehot: jnp.ndarray, s: int):
@@ -144,7 +146,6 @@ def lean_miss_tail(keys: jnp.ndarray, missed: jnp.ndarray,
     guaranteed misses (every match helper here does). Returns the merged
     `(values[B, 2], found[B])`.
     """
-    import jax
 
     b = keys.shape[0]
     W = width if width is not None else min(b, max(1024, b // 8))

@@ -1,48 +1,51 @@
-"""Device-fused GET: Pallas probe→gather→verify→classify in ONE kernel.
+"""Device-fused GET: Pallas probe→gather→digest in ONE kernel.
 
 The composed GET program (`kv._get_core`) is a chain of XLA HLOs — index
 row gather, lane match, pool row gather, digest recompute, tier/generation
 fold, miss-cause classify — with an HBM-materialized intermediate between
-every stage. This module executes the whole verb as one Pallas TPU kernel
-per index family: bucket rows, page rows, and every sidecar element are
-DMA'd once into VMEM and the entire match/verify/classify pipeline runs on
-VPU lanes without touching HBM again (HashMem's "move the map into the
-memory device" argument, applied to the serving GET).
+every stage. This module runs the row traffic of the verb as one Pallas
+TPU kernel per index family: bucket rows and page rows are DMA'd into
+VMEM, and the match and the digest recompute run on VPU lanes there
+(HashMem's "move the map into the memory device" argument, applied to the
+serving GET).
 
 Kernel anatomy (per `tile` keys of the padded batch, grid = w / tile):
 
-1. **address fold** (vector): murmur3 bucket/window hashes and the two
-   evicted-sketch slots are computed on VPU lanes, then one local DMA
-   lands the address matrix in SMEM (DMA descriptors index from scalar
-   memory). CCEH's directory walk is a scalar loop over the SMEM-resident
-   replicated directory.
-2. **probe** (DMA pipeline, depth 8): one row DMA per key lands the
-   `[khi|klo|vhi|vlo]` bucket row in VMEM; the two sketch words ride the
-   same pipeline.
+1. **address fold** (vector): murmur3 bucket/window hashes on VPU lanes,
+   then one local DMA lands them in SMEM (DMA descriptors index from
+   scalar memory). CCEH's directory walk is a scalar loop over the
+   SMEM-resident replicated directory.
+2. **probe** (DMA ring, depth 8): each key's `[khi|klo|vhi|vlo]` bucket
+   row lands in VMEM.
 3. **match** (vector): `rowops.match_mask`/`lane_pick` semantics on the
    VMEM-resident rows — found/values/slot per lane, tag split
    (EXTENT/NOPAGE), exactly as the composed program.
-4. **gather+verify** (DMA pipeline + vector): page rows DMA straight into
-   the output block; the digest sidecar element, cold-row generation, and
-   live bit ride along; the at-rest digest is recomputed in VMEM
-   (`pagepool.page_digest`, xor tree-fold) and compared.
-5. **classify** (vector): every lane gets exactly one cause code
-   (hit / pad / cold / evicted / extent-cold / parked / stale / digest),
-   the same disjoint-plane taxonomy `_get_core` bumps — so
-   `misses == Σ causes` holds bit-exactly on the folded stats vector.
+4. **gather + digest** (DMA ring + vector): page rows land in the output
+   block and the at-rest digest is recomputed in VMEM
+   (`pagepool.page_digest`, xor tree-fold).
+5. **pre-classify** (vector): every lane leaves with hit / pad / cold /
+   extent / parked. The sidecar verdicts — evicted sketch, digest
+   compare, tier generation and liveness — are single-word lookups,
+   which Mosaic cannot DMA per key (HBM is (8, 128)-tiled), so `get_core`
+   finishes them in XLA inside the same jitted program with the composed
+   program's own helpers: `misses == Σ causes` holds bit-exactly.
+
+Mosaic DMAs whole (8, 128) tiles only, so each key fetches the aligned
+8-row group holding its row and copies the row out of VMEM (see
+`_pipeline`): 8x the bytes of the rows themselves.
 
 `get_core` is the drop-in twin of `kv._get_core` (same signature, same
 returns, bit-identical outputs and stats deltas); the counting tiered
 epilogue (`tier.on_get`) and the recovering reattribution stay composed
 XLA *inside the same jitted program* — they are scatter-heavy state
 updates, not row traffic. Unsupported configurations (index families
-other than linear/cceh, unpaged pools, non-pow2 geometry) silently ride
-the composed program — `supports()` is the one gate.
+other than linear/cceh, unpaged pools, non-pow2 page widths, row counts
+off the 8-row group) silently ride the composed program.
 
-Platform gate: the kernel always carries `interpret=` keyed off
-`jax.default_backend()` — off-TPU it runs in Pallas interpret mode
+Platform gate: off-TPU the kernel runs in Pallas interpret mode
 (conformance/parity only; `resolve()` never *selects* fused off-chip
 unless forced with PMDFC_FUSED=on / `KVConfig(fused_get="on")`).
+`tests/test_chip_compile.py` compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -70,10 +73,10 @@ from pmdfc_tpu.utils.keys import is_invalid
 
 # mirrored from kv (which imports us lazily — no module cycle); `get_core`
 # asserts parity at trace time so drift is impossible to miss
-_SK0, _SK1 = 0x0E51C7ED, 0x0E51C7ED ^ 0x9E3779B9   # kv._SKETCH_SEEDS
-_EXTENT_TAG = 0x80000000                            # kv.EXTENT_TAG
+_EXTENT_TAG = 0x80000000  # kv.EXTENT_TAG
 
 _DEPTH = 8  # in-flight DMAs per stream (each stream has its own sem ring)
+_SUB = 8    # rows per (8, 128) HBM tile: the unit one DMA may move
 
 FUSED_FAMILIES = (IndexKind.LINEAR, IndexKind.CCEH)
 
@@ -86,12 +89,10 @@ def supports(config: KVConfig) -> bool:
         return False
     if not config.paged:
         return False
-    pw, nb = config.page_words, config.evicted_sketch_bits
-    # pow2 geometry: the kernel's xor tree-fold digest and masked sketch
-    # slots require it (composed uses % / ufunc-reduce, equal on pow2)
-    if pw & (pw - 1) or nb & (nb - 1):
-        return False
-    return True
+    # pow2 page width: the kernel's xor tree-fold digest requires it
+    # (composed uses a ufunc reduce, equal on pow2)
+    pw = config.page_words
+    return not pw & (pw - 1)
 
 
 def resolve(config: KVConfig) -> bool:
@@ -118,6 +119,12 @@ def resolve(config: KVConfig) -> bool:
     return fused
 
 
+def _interpret() -> bool:
+    """Pallas interpret mode everywhere but a TPU backend (the AOT
+    compile tests patch this to lower for a described chip)."""
+    return jax.default_backend() != "tpu"
+
+
 def tile_for(w: int) -> int:
     """Keys per kernel grid step. 128 keys × a 4 KB page is a 512 KB
     output block + one 64 KB bucket-row block — comfortably inside VMEM
@@ -142,35 +149,31 @@ def _digest_rows(pages: jnp.ndarray) -> jnp.ndarray:
     return h ^ (h >> 13)
 
 
-def _get_kernel(*refs, family, tiered, CL, S, W, Gmax, msb, H, CC, NR, nb,
-                T):
-    """One grid step = `T` keys through the whole GET verb (module
-    docstring stages 1-5). Ref layout is positional per `_pallas_get`."""
+def _get_kernel(*refs, family, tiered, S, W, Gmax, msb, NR, T):
+    """One grid step = `T` keys through probe, match, page gather and
+    digest (module docstring stages 1-4). Ref layout is positional per
+    `_pallas_get`."""
     i = 0
     keys_ref = refs[i]; i += 1
     table_ref = refs[i]; i += 1
     if family == "cceh":
         dirr_ref = refs[i]; i += 1
     pages_ref = refs[i]; i += 1
-    sums_ref = refs[i]; i += 1
-    sk_ref = refs[i]; i += 1
-    if tiered:
-        cgen_ref = refs[i]; i += 1
-        live_ref = refs[i]; i += 1
-    out_ref, cause_ref, rows_ref, slots_ref = refs[i:i + 4]; i += 4
+    out_ref, code_ref, rows_ref, slots_ref, vhi_ref, dig_ref = refs[i:i + 6]
+    i += 6
     brow_ref = refs[i]; i += 1     # VMEM [T, 4S] bucket rows
+    grp1_ref = refs[i]; i += 1     # VMEM [DEPTH, 8, 4S] bucket-row groups
+    grp2_ref = refs[i]; i += 1     # VMEM [DEPTH, 8, PW] page-row groups
     a1v_ref = refs[i]; i += 1      # VMEM [A1, T] round-1 addresses
     a1s_ref = refs[i]; i += 1      # SMEM twin (DMA indices live in SMEM)
     rowv_ref = refs[i]; i += 1     # VMEM [1, T] resolved table row ids
     rows_s_ref = refs[i]; i += 1   # SMEM twin
-    a2v_ref = refs[i]; i += 1      # VMEM [2, T] round-2 addresses
+    a2v_ref = refs[i]; i += 1      # VMEM [1, T] pool rows to gather
     a2s_ref = refs[i]; i += 1      # SMEM twin
-    meta_u_ref = refs[i]; i += 1   # VMEM [2, T] u32 sidecars: sums, cgen
-    meta_i_ref = refs[i]; i += 1   # VMEM [3, T] i32 sidecars: sk0, sk1, live
     sem_cp = refs[i]; i += 1       # local VMEM<->SMEM copies
-    sem1 = refs[i]; i += 1         # probe-round streams [3, DEPTH]
-    sem2 = refs[i]; i += 1         # gather-round streams [4, DEPTH]
-    d = _DEPTH
+    sem1 = refs[i]; i += 1         # probe-round stream [DEPTH]
+    sem2 = refs[i]; i += 1         # gather-round stream [DEPTH]
+    d = min(_DEPTH, T)
 
     # -- stage 1: address fold (vector) -> SMEM ---------------------------
     keys = keys_ref[...]
@@ -184,24 +187,11 @@ def _get_kernel(*refs, family, tiered, CL, S, W, Gmax, msb, H, CC, NR, nb,
         hwin = (hash_u64(khi, klo, seed=WINDOW_SEED)
                 & jnp.uint32(W - 1)).astype(jnp.int32)
     else:
-        bucket = (h & jnp.uint32(CL - 1)).astype(jnp.int32)
-    sk0 = (hash_u64(khi, klo, seed=_SK0) & jnp.uint32(nb - 1)) \
-        .astype(jnp.int32)
-    sk1 = (hash_u64(khi, klo, seed=_SK1) & jnp.uint32(nb - 1)) \
-        .astype(jnp.int32)
+        bucket = (h & jnp.uint32(table_ref.shape[0] - 1)).astype(jnp.int32)
     a1v_ref[0, :] = bucket
     if family == "cceh":
         a1v_ref[1, :] = hwin
-        a1v_ref[2, :] = sk0
-        a1v_ref[3, :] = sk1
-    else:
-        a1v_ref[1, :] = sk0
-        a1v_ref[2, :] = sk1
-    cp = pltpu.make_async_copy(a1v_ref, a1s_ref, sem_cp.at[0])
-    cp.start()
-    cp.wait()
-    ks0 = 2 if family == "cceh" else 1
-    ks1 = ks0 + 1
+    _local_copy(a1v_ref, a1s_ref, sem_cp)
 
     # resolved table row per key: cceh walks the SMEM directory (scalar
     # loop — the probe address depends on a replicated-dir deref); linear
@@ -212,30 +202,14 @@ def _get_kernel(*refs, family, tiered, CL, S, W, Gmax, msb, H, CC, NR, nb,
             return _
 
         jax.lax.fori_loop(0, T, walk, 0)
-        cp = pltpu.make_async_copy(rows_s_ref, rowv_ref, sem_cp.at[0])
-        cp.start()
-        cp.wait()
-
-        def trow(i):
-            return rows_s_ref[0, i]
+        _local_copy(rows_s_ref, rowv_ref, sem_cp)
+        trow_s = rows_s_ref
     else:
-        def trow(i):
-            return a1s_ref[0, i]
+        trow_s = a1s_ref
 
-    # -- stage 2: probe DMA pipeline (bucket row + sketch words) ----------
-    def r1(i):
-        return (
-            pltpu.make_async_copy(
-                table_ref.at[trow(i)], brow_ref.at[i], sem1.at[0, i % d]),
-            pltpu.make_async_copy(
-                sk_ref.at[pl.ds(a1s_ref[ks0, i], 1)],
-                meta_i_ref.at[0, pl.ds(i, 1)], sem1.at[1, i % d]),
-            pltpu.make_async_copy(
-                sk_ref.at[pl.ds(a1s_ref[ks1, i], 1)],
-                meta_i_ref.at[1, pl.ds(i, 1)], sem1.at[2, i % d]),
-        )
-
-    _pipeline(r1, T, d)
+    # -- stage 2: probe DMA pipeline (bucket rows) ------------------------
+    _pipeline(table_ref, grp1_ref, brow_ref, lambda i: trow_s[0, i],
+              sem1, T, d)
 
     # -- stage 3: match (vector, exactly `get_batch`'s lane semantics) ----
     brows = brow_ref[...]
@@ -249,141 +223,96 @@ def _get_kernel(*refs, family, tiered, CL, S, W, Gmax, msb, H, CC, NR, nb,
     gslot = jnp.where(found0, trow_vec * S + jnp.minimum(lane, S - 1),
                       jnp.int32(-1))
     rowv = vlo.astype(jnp.int32)
-
     if tiered:
         tag = vhi >> 30
         nopage = found0 & (tag == jnp.uint32(3))
         ext = found0 & (tag != jnp.uint32(0)) & ~nopage
-        f1 = found0 & (tag == jnp.uint32(0))
     else:
         ext = found0 & (vhi == jnp.uint32(_EXTENT_TAG))
         nopage = jnp.zeros_like(found0)
-        f1 = found0 & ~ext
+    f1 = found0 & ~ext & ~nopage
 
-    # -- stage 4: page gather + sidecar DMA pipeline ----------------------
-    safe_row = jnp.clip(jnp.where(f1, rowv, 0), 0, NR - 1)
-    crow = jnp.clip(rowv - H, 0, max(CC - 1, 0)) if tiered \
-        else jnp.zeros_like(rowv)
-    a2v_ref[0, :] = safe_row
-    a2v_ref[1, :] = crow
-    cp = pltpu.make_async_copy(a2v_ref, a2s_ref, sem_cp.at[0])
+    # -- stage 4: page gather DMA pipeline --------------------------------
+    a2v_ref[0, :] = jnp.clip(jnp.where(f1, rowv, 0), 0, NR - 1)
+    _local_copy(a2v_ref, a2s_ref, sem_cp)
+    _pipeline(pages_ref, grp2_ref, out_ref, lambda i: a2s_ref[0, i],
+              sem2, T, d)
+
+    # -- stage 5: digest (vector) + pre-classification --------------------
+    # index misses leave as COLD and page candidates as HIT; `get_core`'s
+    # XLA epilogue splits off evicted / stale / parked / digest (their
+    # sidecars are single words, which Mosaic cannot DMA per key)
+    valid = ~is_invalid(keys)
+    code = jnp.full((T,), CAUSE_HIT, jnp.int32)
+    code = jnp.where(~valid, CAUSE_PAD, code)
+    code = jnp.where(valid & ~found0, CAUSE_COLD, code)
+    code = jnp.where(ext, CAUSE_EXT, code)
+    code = jnp.where(nopage, CAUSE_PARKED, code)
+    code_ref[0, :] = code
+    rows_ref[0, :] = jnp.where(f1, rowv, jnp.int32(-1))
+    slots_ref[0, :] = gslot
+    vhi_ref[0, :] = vhi
+    dig_ref[0, :] = _digest_rows(out_ref[...])
+
+
+def _local_copy(src, dst, sem):
+    """One local DMA between VMEM and SMEM (vector results become DMA
+    addresses, which the scalar core reads from SMEM)."""
+    cp = pltpu.make_async_copy(src, dst, sem.at[0])
     cp.start()
     cp.wait()
 
-    def r2(i):
-        r = a2s_ref[0, i]
-        cps = (
-            pltpu.make_async_copy(
-                pages_ref.at[r], out_ref.at[i], sem2.at[0, i % d]),
-            pltpu.make_async_copy(
-                sums_ref.at[pl.ds(r, 1)],
-                meta_u_ref.at[0, pl.ds(i, 1)], sem2.at[1, i % d]),
-        )
-        if tiered:
-            c = a2s_ref[1, i]
-            cps += (
-                pltpu.make_async_copy(
-                    cgen_ref.at[pl.ds(c, 1)],
-                    meta_u_ref.at[1, pl.ds(i, 1)], sem2.at[2, i % d]),
-                pltpu.make_async_copy(
-                    live_ref.at[pl.ds(c, 1)],
-                    meta_i_ref.at[2, pl.ds(i, 1)], sem2.at[3, i % d]),
-            )
-        return cps
 
-    _pipeline(r2, T, d)
+def _pipeline(src_ref, grp_ref, dst_ref, row_of, sem, t, d):
+    """Row gather `dst[i] = src[row_of(i)]` for i < t as a DMA ring of
+    depth `d`: warm `d` keys, steady wait(i-d)/start(i), drain the tail.
 
-    # -- stage 5: verify + classify (vector) ------------------------------
-    valid = ~is_invalid(keys)
-    sums_elem = meta_u_ref[0, :]
-    skhit = (meta_i_ref[0, :] != 0) & (meta_i_ref[1, :] != 0)
-    if tiered:
-        # generation gate (`tier.entry_current`): cold rows carry a gen,
-        # everything else must read gen 0
-        ec_cold = (rowv >= H) & (rowv < H + CC)
-        gen_ok = jnp.where(ec_cold, vhi == meta_u_ref[1, :],
-                           vhi == jnp.uint32(0))
-        stale = f1 & ~gen_ok
-        f2 = f1 & gen_ok
-        row2 = jnp.where(f2, rowv, jnp.int32(-1))
-        # liveness gate (`tier.row_live`): hot rows always, cold rows per
-        # the live bitmap; a parked row is a legal miss, never wrong bytes
-        rl_hot = (row2 >= 0) & (row2 < H)
-        rl_cold = row2 >= H
-        live_ok = rl_hot | (rl_cold & (meta_i_ref[2, :] != 0))
-        dead = f2 & ~live_ok
-        dig = _digest_rows(out_ref[...])
-        sums_ok = dig == sums_elem
-        corrupt = f2 & live_ok & ~sums_ok
-        foundf = f2 & live_ok & sums_ok
-    else:
-        stale = jnp.zeros_like(found0)
-        dead = jnp.zeros_like(found0)
-        f2 = f1
-        row2 = jnp.where(f2, rowv, jnp.int32(-1))
-        dig = _digest_rows(out_ref[...])
-        ok = (row2 >= 0) & (dig == sums_elem)
-        corrupt = f2 & ~ok
-        foundf = f2 & ok
+    HBM arrays are (8, 128)-tiled, and Mosaic only DMAs whole tiles: a
+    lone row is a sub-tile slice it refuses. So each key fetches the
+    aligned 8-row group holding its row into ring slot `i % d`, and the
+    row is copied out of VMEM (a dynamic-sublane load) when it lands."""
 
-    idx_miss = valid & ~found0
-    ev = idx_miss & skhit
-    cause = jnp.full((T,), CAUSE_HIT, jnp.int32)
-    cause = jnp.where(~valid, CAUSE_PAD, cause)
-    cause = jnp.where(idx_miss & ~ev, CAUSE_COLD, cause)
-    cause = jnp.where(ev, CAUSE_EVICTED, cause)
-    cause = jnp.where(ext, CAUSE_EXT, cause)
-    cause = jnp.where(nopage | dead, CAUSE_PARKED, cause)
-    cause = jnp.where(stale, CAUSE_STALE, cause)
-    cause = jnp.where(corrupt, CAUSE_DIGEST, cause)
+    def copy(i):
+        base = pl.multiple_of((row_of(i) // _SUB) * _SUB, _SUB)
+        return pltpu.make_async_copy(
+            src_ref.at[pl.ds(base, _SUB)], grp_ref.at[i % d], sem.at[i % d])
 
-    out_ref[...] = jnp.where(foundf[:, None], out_ref[...], jnp.uint32(0))
-    cause_ref[0, :] = cause
-    rows_ref[0, :] = row2
-    slots_ref[0, :] = gslot
-
-
-def _pipeline(mk, t, d):
-    """Seed-bench DMA pipeline shape (`bench/pallas_gather.py`): warm
-    `d` keys of every stream, steady wait(i-d)/start(i), drain the tail.
-    `mk(i)` builds the per-key copy-descriptor bundle."""
+    def land(i):
+        copy(i).wait()
+        dst_ref[pl.ds(i, 1), :] = grp_ref[i % d, pl.ds(row_of(i) % _SUB, 1), :]
 
     def warm(i, _):
-        for cp in mk(i):
-            cp.start()
+        copy(i).start()
         return _
 
     jax.lax.fori_loop(0, d, warm, 0)
 
     def steady(i, _):
-        for cp in mk(i - d):
-            cp.wait()
-        for cp in mk(i):
-            cp.start()
+        land(i - d)
+        copy(i).start()
         return _
 
     jax.lax.fori_loop(d, t, steady, 0)
 
     def drain(i, _):
-        for cp in mk(i):
-            cp.wait()
+        land(i)
         return _
 
     jax.lax.fori_loop(t - d, t, drain, 0)
 
 
-def _pallas_get(keys, table, dirr, pages, sums, sk32, cgen, live32, *,
-                family, tiered, CL, S, W, Gmax, msb, H, CC, nb, tile):
+def _pallas_get(keys, table, dirr, pages, *, family, tiered, S, W, Gmax,
+                msb, tile):
     """Build + launch the fused kernel over the padded batch. Returns
-    (out[w, PW], cause[w], rows[w], slots[w]) — classification codes are
-    folded into the stats vector by `get_core` (plain int32 sums, the
-    same reductions `_get_core` runs)."""
+    (out[w, PW] raw gathered bytes, code[w] pre-classification, rows[w]
+    page-candidate pool rows or -1, slots[w], vhi[w], dig[w] digests of
+    the gathered bytes) — `get_core` finishes the verdict in XLA."""
     w = keys.shape[0]
     nr, pw = pages.shape
     t = min(tile, w)
     lanes = table.shape[1]
     grid = (w // t,)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
 
     from pmdfc_tpu.runtime import telemetry as tele
 
@@ -393,25 +322,21 @@ def _pallas_get(keys, table, dirr, pages, sums, sk32, cgen, live32, *,
         detail=f"family={family},w={w},tile={t},vw={pw}",
     )
 
-    kern = partial(
-        _get_kernel, family=family, tiered=tiered, CL=CL, S=S, W=W,
-        Gmax=Gmax, msb=msb, H=H, CC=CC, NR=nr, nb=nb, T=t,
-    )
-    in_specs = [pl.BlockSpec((t, 2), lambda g: (g, 0))]
-    args = [keys]
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-    args.append(table)
+    kern = partial(_get_kernel, family=family, tiered=tiered, S=S, W=W,
+                   Gmax=Gmax, msb=msb, NR=nr, T=t)
+    in_specs = [pl.BlockSpec((t, 2), lambda g: (g, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    args = [keys, table]
     if family == "cceh":
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(dirr)
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 3
-    args += [pages, sums, sk32]
-    if tiered:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        args += [cgen, live32]
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    args.append(pages)
 
-    a1 = 4 if family == "cceh" else 3
-    out, cause, rows, slots = pl.pallas_call(
+    a1 = 2 if family == "cceh" else 1
+    d = min(_DEPTH, t)
+    row_spec = pl.BlockSpec((1, t), lambda g: (0, g))
+    out, code, rows, slots, vhi, dig = pl.pallas_call(
         kern,
         grid=grid,
         out_shape=[
@@ -419,81 +344,91 @@ def _pallas_get(keys, table, dirr, pages, sums, sk32, cgen, live32, *,
             jax.ShapeDtypeStruct((1, w), jnp.int32),
             jax.ShapeDtypeStruct((1, w), jnp.int32),
             jax.ShapeDtypeStruct((1, w), jnp.int32),
+            jax.ShapeDtypeStruct((1, w), jnp.uint32),
+            jax.ShapeDtypeStruct((1, w), jnp.uint32),
         ],
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((t, pw), lambda g: (g, 0)),
-            pl.BlockSpec((1, t), lambda g: (0, g)),
-            pl.BlockSpec((1, t), lambda g: (0, g)),
-            pl.BlockSpec((1, t), lambda g: (0, g)),
-        ],
+        out_specs=[pl.BlockSpec((t, pw), lambda g: (g, 0))] + [row_spec] * 5,
         scratch_shapes=[
-            pltpu.VMEM((t, 4 * S), jnp.uint32),
+            pltpu.VMEM((t, lanes), jnp.uint32),
+            pltpu.VMEM((d, _SUB, lanes), jnp.uint32),
+            pltpu.VMEM((d, _SUB, pw), jnp.uint32),
             pltpu.VMEM((a1, t), jnp.int32),
             pltpu.SMEM((a1, t), jnp.int32),
             pltpu.VMEM((1, t), jnp.int32),
             pltpu.SMEM((1, t), jnp.int32),
-            pltpu.VMEM((2, t), jnp.int32),
-            pltpu.SMEM((2, t), jnp.int32),
-            pltpu.VMEM((2, t), jnp.uint32),
-            pltpu.VMEM((3, t), jnp.int32),
+            pltpu.VMEM((1, t), jnp.int32),
+            pltpu.SMEM((1, t), jnp.int32),
             pltpu.SemaphoreType.DMA((1,)),
-            pltpu.SemaphoreType.DMA((3, _DEPTH)),
-            pltpu.SemaphoreType.DMA((4, _DEPTH)),
+            pltpu.SemaphoreType.DMA((d,)),
+            pltpu.SemaphoreType.DMA((d,)),
         ],
         interpret=interpret,
     )(*args)
-    return out, cause[0], rows[0], slots[0]
+    return out, code[0], rows[0], slots[0], vhi[0], dig[0]
 
 
 def get_core(state, config: KVConfig, keys: jnp.ndarray,
              lean: bool = False, recovering: bool = False):
     """Fused twin of `kv._get_core`: same signature, same returns
     (state', out, found), bit-identical outputs/stats/cause lanes. Falls
-    back to the composed program for anything `supports()` excludes —
-    the zero-behavior-change contract behind PMDFC_FUSED=auto."""
+    back to the composed program for anything `supports()` excludes, and
+    for geometry the group DMAs cannot tile (row counts off the 8-row
+    group) — the zero-behavior-change contract behind PMDFC_FUSED=auto."""
     from pmdfc_tpu import kv as kv_mod
 
     tiered = isinstance(state.pool, tier_mod.TierState)
     flat = isinstance(state.pool, pagepool.PoolState)
-    if not supports(config) or not (tiered or flat):
+    if (not supports(config) or not (tiered or flat)
+            or state.index.table.shape[0] % _SUB
+            or state.pool.pages.shape[0] % _SUB):
         return kv_mod._get_core(state, config, keys, lean=lean,
                                 recovering=recovering)
 
     from pmdfc_tpu.models.base import get_index_ops
 
-    assert kv_mod._SKETCH_SEEDS == (_SK0, _SK1)
     assert kv_mod.EXTENT_TAG == _EXTENT_TAG
     ops = get_index_ops(config.index.kind)
     table = state.index.table
+    S = table.shape[1] // 4
     if config.index.kind == IndexKind.CCEH:
         family, dirr = "cceh", state.index.dirr
         smax = state.index.ld.shape[0]
-        S = table.shape[1] // 4
         W = table.shape[0] // smax
         Gmax = smax.bit_length() - 1
         msb = state.index.msb
     else:
         family, dirr = "linear", None
-        S = table.shape[1] // 4
         W, Gmax, msb = 1, 0, True
     pool = state.pool
-    if tiered:
-        H = pool.hfree.shape[0]
-        CC = pool.live.shape[0]
-        cgen = pool.cgen
-        live32 = pool.live.astype(jnp.int32)
-    else:
-        H, CC, cgen, live32 = 0, 0, None, None
-    sk32 = state.evicted_filter.astype(jnp.int32)
 
-    out, cause, rows, slots = _pallas_get(
-        keys, table, dirr, pool.pages, pool.sums, sk32, cgen, live32,
-        family=family, tiered=tiered, CL=table.shape[0], S=S, W=W,
-        Gmax=Gmax, msb=msb, H=H, CC=CC, nb=config.evicted_sketch_bits,
-        tile=tile_for(keys.shape[0]),
+    out, code, rows, slots, vhi, dig = _pallas_get(
+        keys, table, dirr, pool.pages, family=family, tiered=tiered, S=S,
+        W=W, Gmax=Gmax, msb=msb, tile=tile_for(keys.shape[0]),
     )
+    # -- XLA epilogue: the sidecar verdicts, as `_get_core` computes them
+    f1 = code == CAUSE_HIT
+    if tiered:
+        # generation gate, then liveness, then digest (`tier` helpers)
+        cur = tier_mod.entry_current(
+            pool, jnp.stack([vhi, rows.astype(jnp.uint32)], -1))
+        stale = f1 & ~cur
+        f2 = f1 & cur
+        rows = jnp.where(f2, rows, jnp.int32(-1))
+        live = tier_mod.row_live(pool, rows)
+        sums_ok = dig == tier_mod.stored_sums(pool, rows)
+        dead = f2 & ~live
+        corrupt = f2 & live & ~sums_ok
+    else:
+        stale = dead = jnp.zeros_like(f1)
+        corrupt = f1 & (dig != pool.sums[jnp.maximum(rows, 0)])
+    ev = (code == CAUSE_COLD) & kv_mod._sketch_query(state, config, keys)
+    cause = jnp.where(ev, CAUSE_EVICTED, code)
+    cause = jnp.where(stale, CAUSE_STALE, cause)
+    cause = jnp.where(dead, CAUSE_PARKED, cause)
+    cause = jnp.where(corrupt, CAUSE_DIGEST, cause)
     found = cause == CAUSE_HIT
+    out = jnp.where(found[:, None], out, jnp.uint32(0))
     valid = ~is_invalid(keys)
 
     if tiered and not lean:
@@ -509,17 +444,16 @@ def get_core(state, config: KVConfig, keys: jnp.ndarray,
     def cnt(m):
         return m.sum(dtype=jnp.int32)
 
-    corrupt = cause == CAUSE_DIGEST
     bumps = jnp.zeros((kv_mod.NSTATS,), jnp.int32)
     bumps = bumps.at[kv_mod.GETS].add(cnt(valid))
     bumps = bumps.at[kv_mod.HITS].add(cnt(found))
     bumps = bumps.at[kv_mod.MISSES].add(cnt(valid & ~found))
     bumps = bumps.at[kv_mod.CORRUPT_PAGES].add(cnt(corrupt))
-    bumps = bumps.at[kv_mod.MISS_EVICTED].add(cnt(cause == CAUSE_EVICTED))
+    bumps = bumps.at[kv_mod.MISS_EVICTED].add(cnt(ev))
     bumps = bumps.at[kv_mod.MISS_COLD].add(
         cnt((cause == CAUSE_COLD) | (cause == CAUSE_EXT)))
     bumps = bumps.at[kv_mod.MISS_PARKED].add(cnt(cause == CAUSE_PARKED))
-    bumps = bumps.at[kv_mod.MISS_STALE].add(cnt(cause == CAUSE_STALE))
+    bumps = bumps.at[kv_mod.MISS_STALE].add(cnt(stale))
     bumps = bumps.at[kv_mod.MISS_DIGEST].add(cnt(corrupt))
     if recovering:
         bumps = kv_mod._reattribute_recovering(bumps)

@@ -83,17 +83,10 @@ RAXIS = pt.REPLICA_MESH_AXIS
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map: `jax.shard_map(check_vma=False)` on
-    new jax, `jax.experimental.shard_map.shard_map(check_rep=False)` on
-    0.4.x — the replication check is off in both (bodies use collectives
-    whose replication the checker cannot prove)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """`jax.shard_map` with the replication check off (bodies use
+    collectives whose replication the checker cannot prove)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_donate() -> bool:

@@ -3,8 +3,7 @@
 wire schema (`pmdfc-telemetry-v1`/`-v2`/`-v3`) or a flight-recorder
 dump against the flight schema (`pmdfc-flight-v1`/`-v2`).
 
-The CI `telemetry_smoke` step (tools/tpu_agenda.sh) runs the net smoke
-with telemetry on, pulls a snapshot via `tools/teledump.py --out`, and
+A telemetry smoke runs the net smoke with telemetry on, pulls a snapshot via `tools/teledump.py --out`, and
 diffs it against this schema: counters are ints, gauges numeric,
 histograms carry the full quantile block, and the sections a monitoring
 consumer depends on are all present. Exit 0 = conformant.
