@@ -17,10 +17,9 @@ only in the `kernel` lane knob — `pallas_fused` vs `xla_composed` —
 plus identity knobs (`tile`, `batch`, `zipf`, `family`, ...), so
 `tools/check_bench.py` tracks them as separate lanes that can never
 collapse into one. When the tracing tier is live the combo also emits
-a paired `device_us` lane per kernel side: mean on-chip µs per GET
-verb from the device-time profiler's timed-fetch attribution
-(`runtime/profiler.py`) — the split of each wall row the host timer
-cannot see.
+a paired `fetch_us` lane per kernel side: mean µs per GET verb the
+host spent blocked in the fetch, from the profiler's timed-fetch
+attribution (`runtime/profiler.py`).
 
 Honesty rules (the acceptance bar's "no fake speedup rows"):
 - off-chip, the fused side runs in Pallas INTERPRET mode — a
@@ -58,9 +57,9 @@ def _mk_kv(kind, cap, page_words, fused: str):
 def _stream_pair(kv_f, kv_c, skeys, batch, check: bool, h_dev=None):
     """Drive the SAME stream through both KVs, batch-interleaved so the
     two sides see the same machine weather. Returns (sec_fused,
-    sec_composed, hits, device_us_fused, device_us_composed) and asserts
+    sec_composed, hits, fetch_us_fused, fetch_us_composed) and asserts
     bit-identical serving when `check`. `h_dev` is the profiler's
-    `prof.kv.get.device_us` histogram: both sides attribute into the
+    `prof.kv.get.fetch_us` histogram: both sides attribute into the
     SAME program name, so the per-side split comes from deltaing its
     cumulative sum around each side's call (the loop is single-threaded
     — nothing else observes into it between the reads)."""
@@ -111,11 +110,11 @@ def run(args) -> dict:
     from pmdfc_tpu.runtime import profiler
     from pmdfc_tpu.runtime import telemetry as tele
 
-    # device-time lanes: the profiler attributes each GET's blocking
-    # fetch (kv.py's timed-fetch seam) into `prof.kv.get.device_us`;
+    # fetch-time lanes: the profiler attributes each GET's blocking
+    # fetch (kv.py's timed-fetch seam) into `prof.kv.get.fetch_us`;
     # the paired rows below split that by kernel side
     profiler.install()
-    h_dev = (tele.get().scope("prof", unique=False).hist("kv.get.device_us")
+    h_dev = (tele.get().scope("prof", unique=False).hist("kv.get.fetch_us")
              if tele.enabled() else None)
 
     on_chip = jax.default_backend() == "tpu"
@@ -170,16 +169,16 @@ def run(args) -> dict:
                 rows = [row_f, row_c]
                 calls = -(-args.gets // batch)
                 if h_dev is not None and (d_f > 0 or d_c > 0):
-                    # paired device-time lanes: mean on-chip µs per GET
-                    # verb from the profiler's timed-fetch attribution —
-                    # `device_us` is a latency unit in check_bench, so
+                    # paired fetch-time lanes: mean µs per GET verb from
+                    # the profiler's timed-fetch attribution —
+                    # `fetch_us` is a latency unit in check_bench, so
                     # these gate lower-is-better alongside the Mops/s
                     # throughput lanes
                     rows.append({**base, "kernel": "pallas_fused",
-                                 "unit": "device_us",
+                                 "unit": "fetch_us",
                                  "value": round(d_f / calls, 2)})
                     rows.append({**base, "kernel": "xla_composed",
-                                 "unit": "device_us",
+                                 "unit": "fetch_us",
                                  "value": round(d_c / calls, 2)})
                 for row in rows:
                     stamp_live_device(row, "direct")
@@ -189,8 +188,8 @@ def run(args) -> dict:
                 sweeps.append({**base, "speedup_fused_vs_composed": speedup,
                                "mops_fused": row_f["value"],
                                "mops_composed": row_c["value"],
-                               "device_us_fused": round(d_f / calls, 2),
-                               "device_us_composed": round(d_c / calls, 2),
+                               "fetch_us_fused": round(d_f / calls, 2),
+                               "fetch_us_composed": round(d_c / calls, 2),
                                "parity": "ok"})
 
     out = {"metric": "fused_get_sweep", "on_chip": on_chip,

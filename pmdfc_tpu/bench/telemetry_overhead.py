@@ -14,7 +14,7 @@ boxes swings far more run-to-run than the 3% being measured.
 Acceptance: the ON lanes' summed wall stays within 3% of OFF
 (`on/off <= 1.03`); both lanes append `telemetry=on|off` rows to
 BENCH_HISTORY via the shared evidence logger (`host_evidence` rows —
-the subject is the instrumentation, not the chip). The device-time
+the subject is the instrumentation, not the chip). The fetch-time
 profiler (`runtime/profiler.py`) is installed for the measurement, so
 the rows carry matching `profiler=on|off` lanes: `profiler.fetch`
 gates on the same live `set_enabled` flip, which makes the ON leg
@@ -77,7 +77,7 @@ def _measure(*, verb: int, gets: int, pairs: int, page_words: int,
     from pmdfc_tpu.runtime.net import NetServer, TcpBackend
 
     tele.configure(TelemetryConfig(enabled=True))
-    # the device-time profiler rides the ON lane too: `profiler.fetch`
+    # the fetch-time profiler rides the ON lane too: `profiler.fetch`
     # passes through when the tracing tier is off, so the live
     # `set_enabled` flip that prices the spans prices the timed-fetch
     # seam with them — one paired measurement, whole sensor array

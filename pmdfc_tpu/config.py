@@ -500,8 +500,8 @@ class TelemetryConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ProfilerConfig:
-    """Device-time X-ray (`runtime/profiler.py`): per-program on-chip
-    cost attribution threaded through the async-fetch seams.
+    """Fetch-time attribution (`runtime/profiler.py`): per-program time
+    blocked on device results, threaded through the async-fetch seams.
 
     The profiler is OPT-IN (`PMDFC_PROF=on` or an explicit
     `profiler.install()`): with it off nothing attaches to the registry
@@ -512,13 +512,9 @@ class ProfilerConfig:
 
     enabled: bool = True
     # launches accumulated per `shard_imbalance` gauge window (max/mean
-    # device time across shards, recomputed every `imbalance_window`
+    # fetch time across shards, recomputed every `imbalance_window`
     # attributed launches)
     imbalance_window: int = 8
-    # capture `compiled.cost_analysis()` FLOPs/bytes per program
-    # signature at the recompile-tracker seam (one extra lowering per
-    # signature; the persistent compile cache dedupes the XLA work)
-    cost_capture: bool = True
     # MSG_PROFILE bounded-trace discipline: duration cap, cooldown
     # between captures, and retained `prof_*` capture-dir count under
     # the flight recorder's dump dir (oldest-first deletion, like

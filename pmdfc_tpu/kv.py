@@ -1458,19 +1458,10 @@ class KV:
         tile rung is a new Pallas kernel compile."""
         from pmdfc_tpu.runtime import telemetry as tele
 
-        first = tele.track_program(f"kv.{name}", (w, vw, *extra, self.config),
-                                   detail=f"w={w}" + (f",vw={vw}" if vw else "")
-                                   + "".join(f",{k}={v}" for k, v in extra))
-        fn = _fn(name)
-        if first:
-            # static cost capture rides the recompile-tracker seam: the
-            # first dispatch of a fresh signature lowers once for the
-            # `cost.*` FLOPs/bytes gauges (runtime/profiler.py; no-op
-            # unless a profiler is attached)
-            from pmdfc_tpu.runtime import profiler
-
-            fn = profiler.cost_probe(f"kv.{name}", fn)
-        return fn
+        tele.track_program(f"kv.{name}", (w, vw, *extra, self.config),
+                           detail=f"w={w}" + (f",vw={vw}" if vw else "")
+                           + "".join(f",{k}={v}" for k, v in extra))
+        return _fn(name)
 
     @_locked
     def insert(self, keys: np.ndarray, values: np.ndarray):

@@ -49,8 +49,6 @@ can delegate its fan-out to the fused plane.
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from pmdfc_tpu.config import (ContainmentConfig, KVConfig, MeshConfig,
@@ -127,7 +125,7 @@ class PlaneBackend:
     # -- per-shard attribution helpers --
 
     def _note(self, phase: str, counts, dur_us: float,
-              t0_ns: int = 0, t1_ns: int = 0) -> None:
+              t0_ns: int, t1_ns: int) -> None:
         if counts is None:
             # broadcast phase (extents): every shard ran the program
             counts = np.ones(self.n_shards, np.int64)
@@ -138,7 +136,7 @@ class PlaneBackend:
             self._c_shard[s].inc(int(counts[s]))
             if on and s < len(hists):
                 hists[s].observe(dur_us)
-            if on and t0_ns:
+            if on:
                 # one shard-program tree node per involved shard: the
                 # fetch window, attributed with the shard's routed op
                 # count. Parent comes off the calling thread's ambient
@@ -147,15 +145,17 @@ class PlaneBackend:
                 sp = tele.span_begin("server", "shard_program",
                                      t0_ns=t0_ns, shard=s, phase=phase,
                                      ops=int(counts[s]))
-                tele.span_end(sp, t1_ns=t1_ns or None)
+                tele.span_end(sp, t1_ns=t1_ns)
 
     def _run(self, phase: str, handle):
         """Fetch one launched phase under its telemetry envelope; a
-        failure rung names the shards whose routed ops were aboard."""
+        failure rung names the shards whose routed ops were aboard.
+        The fetch is a stage of its own (`telemetry.stage`): its two
+        readings time the shard spans, the `phase_*_us_s{i}` families
+        and the profiler, and the caller's reply assembly starts at the
+        fetch's end."""
         prof = profiler.active() if tele.enabled() else None
-        t0 = time.perf_counter()
-        t0_ns = (time.monotonic_ns()
-                 if (tele.enabled() or prof is not None) else 0)
+        t0 = tele.stage(phase, "fetch")
         try:
             out = handle.fetch()
         except Exception as e:  # noqa: BLE001 — attribution, then re-raise
@@ -166,20 +166,17 @@ class PlaneBackend:
             tele.rung("phase_failure", tier="mesh", phase=phase,
                       shards=shards, ops=handle.b, error=repr(e))
             raise
-        dur_us = (time.perf_counter() - t0) * 1e6
-        t1_ns = time.monotonic_ns() if t0_ns else 0
-        self._note(phase, handle.counts, dur_us, t0_ns, t1_ns)
+        t1 = tele.stage(phase, "reply")
+        fetch_us = (t1 - t0) / 1e3
+        self._note(phase, handle.counts, fetch_us, t0, t1)
         if prof is not None:
-            # device-time X-ray: the fetch window IS the device window
-            # (async dispatch pays compute+transfer here); the launch
-            # stamp on the handle gives the dispatch-vs-device split,
-            # and the routed counts vector splits device time across
-            # shards in the SAME proportions that fed shard{i}_ops
-            t_l = getattr(handle, "t_launch_ns", 0)
+            # the routed counts vector splits the fetch across shards in
+            # the SAME proportions that fed shard{i}_ops; the launch
+            # stage's reading gives the launch-to-fetch gap
+            t_l = handle.t_launch_ns
             prof.note_launch(
-                f"plane.{phase}", phase, dur_us,
-                dispatch_us=(max(0.0, (t0_ns - t_l) / 1e3)
-                             if t_l and t0_ns else 0.0),
+                f"plane.{phase}", phase, fetch_us,
+                launch_gap_us=(t0 - t_l) / 1e3 if t_l else 0.0,
                 n_ops=handle.b, counts=handle.counts,
                 n_shards=self.n_shards)
         return out
@@ -326,12 +323,11 @@ class PlaneBackend:
 
     def insert_extent(self, key, value, length: int) -> int:
         prof = profiler.active() if tele.enabled() else None
-        t0 = time.perf_counter()
-        t0_ns = time.monotonic_ns() if tele.enabled() else 0
+        t0 = tele.stage("ins_ext", "launch")
         _, uncovered = self.skv.insert_extent(key, value, length)
-        dur_us = (time.perf_counter() - t0) * 1e6
-        self._note("ins_ext", None, dur_us,
-                   t0_ns, time.monotonic_ns() if t0_ns else 0)
+        t1 = tele.stage("ins_ext", "reply")
+        dur_us = (t1 - t0) / 1e3
+        self._note("ins_ext", None, dur_us, t0, t1)
         if prof is not None:
             # broadcast phase: every shard ran the program — the same
             # ones-vector `_note` uses, so per-shard op attribution

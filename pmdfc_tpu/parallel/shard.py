@@ -48,7 +48,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-import time
 from functools import partial
 
 import jax
@@ -73,6 +72,7 @@ from pmdfc_tpu.ops import pagepool
 from pmdfc_tpu.ops import bloom as bloom_ops
 from pmdfc_tpu.parallel import partitioning as pt
 from pmdfc_tpu.runtime import profiler
+from pmdfc_tpu.runtime import telemetry as tele
 from pmdfc_tpu.utils.hashing import shard_of
 from pmdfc_tpu.utils.keys import INVALID_WORD, is_invalid
 
@@ -678,17 +678,17 @@ class PlaneHandle:
     the serving drivers use to overlap flush N+1's dispatch with flush
     N's results. `counts` is the per-shard routed-op vector (telemetry
     attribution: which shards this phase actually touched).
-    `t_launch_ns` stamps the dispatch so the device-time profiler can
-    split launch-to-fetch dispatch gap from time blocked in the fetch
-    (`runtime/profiler.py`)."""
+    `t_launch_ns` is the launch stage's clock reading (0 when nothing
+    launched), so the profiler can split the launch-to-fetch gap from
+    the time blocked in the fetch (`runtime/profiler.py`)."""
 
     __slots__ = ("_fetch", "b", "counts", "t_launch_ns")
 
-    def __init__(self, fetch, b: int, counts=None):
+    def __init__(self, fetch, b: int, counts=None, t_launch_ns: int = 0):
         self._fetch = fetch
         self.b = b
         self.counts = counts
-        self.t_launch_ns = time.monotonic_ns()
+        self.t_launch_ns = t_launch_ns
 
     def fetch(self):
         return self._fetch()
@@ -876,9 +876,11 @@ class ShardedKV:
         # recompile tracker (runtime/telemetry.py): a miss here IS a
         # program build the process pays — a cold pad-ladder rung or a
         # drifting shape surfaces as a named `recompile.plane.*` storm
-        from pmdfc_tpu.runtime import telemetry as tele
-
-        first = tele.track_program(f"plane.{name}", key, detail=key)
+        tele.track_program(f"plane.{name}", key, detail=key)
+        # the XLA module is named after `name` (`jit_plane_get_ro`), not
+        # the body: trace reductions find programs by this name
+        fn_body = partial(body, self.config, self.n_shards, *static)
+        fn_body.__name__ = name
         ds = data_spec if data_spec is not None else P()
         # partitioning rules -> specs: the same vocabulary init/restore
         # placement uses, so a 2-D-mesh rules change reshapes every
@@ -890,17 +892,14 @@ class ShardedKV:
         if not state_out:
             fn = jax.jit(
                 _shard_map(
-                    partial(body, self.config, self.n_shards, *static),
+                    fn_body,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     out_specs=tuple(out_data_specs),
                 ),
             )
             self._jits[key] = fn
-            # static cost capture rides the recompile-tracker seam: the
-            # first call of a fresh signature lowers once for FLOPs /
-            # bytes gauges; the cached entry stays the bare jit fn
-            return profiler.cost_probe(f"plane.{name}", fn) if first else fn
+            return fn
         # bare state out (no tuple) when the body returns only state
         out_specs = (
             spec_state if n_out == 0 and not out_data_specs
@@ -923,7 +922,7 @@ class ShardedKV:
         donate = shard_donate()
         fn = jax.jit(
             _shard_map(
-                partial(body, self.config, self.n_shards, *static),
+                fn_body,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -931,7 +930,7 @@ class ShardedKV:
             donate_argnums=(0,) if donate else (),
         )
         self._jits[key] = fn
-        return profiler.cost_probe(f"plane.{name}", fn) if first else fn
+        return fn
 
     def _data_call(self, name, body_a2a, body_bcast, n_in, n_out, w):
         """Pick the dispatch mode's body + specs for a data batch of width w."""
@@ -1038,6 +1037,7 @@ class ShardedKV:
 
     @_locked
     def insert_extent(self, key, value, length: int):
+        tele.stage("ins_ext", "launch")
         fn = self._wrap("insert_extent", _insert_extent_body, 3, 2)
         # plain numpy inputs, NOT jnp.asarray: the body's in_specs are
         # replicated (P()), and an uncommitted host array satisfies that
@@ -1050,6 +1050,7 @@ class ShardedKV:
             np.uint32(length),
         )
         self._mut_seq += 1
+        tele.stage("ins_ext", "fetch")
         return (jax.tree.map(lambda x: self._fetch(x), res),
                 int(self._fetch(uncovered)))
 
@@ -1068,14 +1069,18 @@ class ShardedKV:
     # ladder, and each launch returns a `PlaneHandle` whose fetch()
     # blocks on the device (JAX async dispatch: compute+transfer are
     # paid at fetch, not launch — the overlap the serving drivers use).
+    # Each verb enters the `route` then the `launch` stage of its phase
+    # (`telemetry.stage`); `PlaneBackend._run` enters `fetch`.
 
     @_locked
     def plane_insert(self, keys: np.ndarray,
                      values: np.ndarray) -> PlaneHandle:
+        tele.stage("put", "route")
         self._lrfu_touch(keys)
         rb = self._router.build(keys, values)
         if rb.b == 0:
             return PlaneHandle(lambda: None, 0, rb.counts)
+        t_launch = tele.stage("put", "launch")
         if self.n_replicas > 1:
             # one launch writes every replica lane (vs rf host loops)
             fn = self._wrap("plane_insert2", _plane_insert2_body, 2, 1,
@@ -1089,10 +1094,11 @@ class ShardedKV:
         def fetch():
             return jax.tree.map(lambda x: rb.scatter(self._fetch(x)), res)
 
-        return PlaneHandle(fetch, rb.b, rb.counts)
+        return PlaneHandle(fetch, rb.b, rb.counts, t_launch)
 
     @_locked
     def plane_get(self, keys: np.ndarray) -> PlaneHandle:
+        tele.stage("get", "route")
         self._lrfu_touch(keys)
         rb = self._router.build(keys)
         if rb.b == 0:
@@ -1100,6 +1106,7 @@ class ShardedKV:
             empty = PlaneGets(rb, np.zeros((0, vw), np.uint32),
                               np.zeros(0, bool))
             return PlaneHandle(lambda: empty, 0, rb.counts)
+        t_launch = tele.stage("get", "launch")
         lane = None
         if self.n_replicas > 1:
             # hedged replica-shard read: every lane probes its copy, the
@@ -1151,7 +1158,7 @@ class ShardedKV:
             return PlaneGets(rb, self._fetch(out), rb.scatter(f_routed),
                              ls, lr)
 
-        return PlaneHandle(fetch, rb.b, rb.counts)
+        return PlaneHandle(fetch, rb.b, rb.counts, t_launch)
 
     @_locked
     def plane_warm_get(self, keys: np.ndarray) -> None:
@@ -1194,10 +1201,12 @@ class ShardedKV:
 
     @_locked
     def plane_delete(self, keys: np.ndarray) -> PlaneHandle:
+        tele.stage("del", "route")
         self._lrfu_touch(keys)
         rb = self._router.build(keys)
         if rb.b == 0:
             return PlaneHandle(lambda: np.zeros(0, bool), 0, rb.counts)
+        t_launch = tele.stage("del", "launch")
         if self.n_replicas > 1:
             # one launch deletes on every replica lane (loss-free: no
             # lane can keep a value the tombstone missed)
@@ -1213,13 +1222,14 @@ class ShardedKV:
         def fetch():
             return rb.scatter(self._fetch(hit))
 
-        return PlaneHandle(fetch, rb.b, rb.counts)
+        return PlaneHandle(fetch, rb.b, rb.counts, t_launch)
 
     @_locked
     def plane_get_extent(self, keys: np.ndarray) -> PlaneHandle:
         """Extent covers are deterministically replicated, so this phase
         is the broadcast body launched async (counts=None: every shard
         probes the full batch — there is no per-shard attribution)."""
+        t_launch = tele.stage("get_ext", "launch")
         keys_p, _, b, w = self._pad(keys)
         fn = self._wrap("get_extent", _get_extent_body, 1, 2)
         self.state, out, found = fn(self.state, keys_p)
@@ -1227,7 +1237,7 @@ class ShardedKV:
         def fetch():
             return self._fetch(out)[:b], self._fetch(found)[:b]
 
-        return PlaneHandle(fetch, b, None)
+        return PlaneHandle(fetch, b, None, t_launch)
 
     def _plane_note_get(self, delta: np.ndarray) -> None:
         """Fold one read-only GET's device-computed per-shard stats

@@ -236,6 +236,12 @@ _OP_NAMES = {
     MSG_NACK: "nack", MSG_PROFILE: "profile",
 }
 
+# the coalesced flush's phases in serving order, and the phase of each
+# staged verb (any other verb is served in `aux`)
+_PHASES = ("put", "ins_ext", "del", "get_ext", "get", "aux")
+_PHASE_OF = {MSG_PUTPAGE: 0, MSG_HANDOFF: 0, MSG_INSEXT: 1,
+             MSG_INVALIDATE: 2, MSG_GETEXT: 3, MSG_GETPAGE: 4}
+
 # magic, msg_type, status, count, words, stamp, data_len, crc32
 # The CRC covers the header (with the crc field zeroed) AND the payload —
 # the wire integrity layer: TCP's 16-bit checksum misses ~1/65k corrupted
@@ -561,7 +567,8 @@ class _StagedOp:
         self.b = b
         # server op span (tracing on): opened at staging by the reader
         # thread, closed by the flush loop when the op's phase completes
-        # — queue wait is measured explicitly as its first child
+        # — queue wait is measured explicitly as its first child.
+        # `t_ns` is the staging stamp, taken whether tracing or not
         self.span = None
         self.t_ns = 0
         # QoS tenant id, resolved ONCE at decode time from the key
@@ -701,11 +708,14 @@ class NetServer(_BaseServer):
         self._h_dwell = self.stats.hist("flush_dwell_us")
         # queue wait measured explicitly (staging -> phase start): the
         # stage a bare phase_*_us histogram can't see — the one that
-        # grows first when the flush loop falls behind fan-in
+        # grows first when the flush loop falls behind fan-in. Its sum
+        # over ops is also counted always (`stage_queue_wait_us`/`_n`),
+        # beside the flush thread's own stage counters (`_flush_loop`)
         self._h_qwait = self.stats.hist("queue_wait_us")
+        self._c_qwait = (self.stats.counter("stage_queue_wait_us"),
+                         self.stats.counter("stage_queue_wait_n"))
         self._h_phase = {ph: self.stats.hist(f"phase_{ph}_us")
-                         for ph in ("put", "ins_ext", "del", "get_ext",
-                                    "get", "aux")}
+                         for ph in _PHASES}
         # workload characterization (`runtime/workload.py`): working-set
         # KMV + keyspace heat count-min, folded in on the host routing
         # path this loop already walks (gated on the tracing tier —
@@ -1516,12 +1526,14 @@ class NetServer(_BaseServer):
                         self._shed_op(op, ladder=False)
                         continue
                     self._qos.note_arrival(op.tid, staged=True)
+                # staging stamp (always: the flush loop counts queue
+                # wait from it into `stage_queue_wait_us`)
+                op.t_ns = time.monotonic_ns()
                 if tele.enabled():
                     # the server op span opens HERE (staging): queue wait
                     # is inside it, measured explicitly as a child when
                     # the flush loop picks the op up. Cross-thread close
                     # => explicit root parent, no ambient push.
-                    op.t_ns = time.monotonic_ns()
                     op.span = tele.span_begin(
                         "server", _OP_NAMES.get(mt, f"op{mt}"),
                         trace=op.trace, parent=0, ambient=False,
@@ -1611,9 +1623,24 @@ class NetServer(_BaseServer):
         engine coalescer's knobs, applied to the wire tier. Dwell and
         settle are re-read from the live knob fields every cycle so the
         autotune controller's sets land within one flush (with no
-        controller they hold the NetConfig values verbatim)."""
+        controller they hold the NetConfig values verbatim).
+
+        The thread's time is split into back-to-back stages
+        (`telemetry.stage`), counted into `stats` as
+        `stage_<phase>_<stage>_us`/`_n`: `idle` (loop top until a drain
+        returns ops), `dwell` (gathering batch mates), then per phase
+        `prep` (here and `_serve_coalesced`), `route`/`launch` (the
+        plane's verbs), `fetch` (`PlaneBackend._run`) and `reply`."""
+        tele.bind_stages(self.stats)
+        try:
+            self._flush_cycles()
+        finally:
+            tele.bind_stages(None)
+
+    def _flush_cycles(self) -> None:
         cfg = self.net
         while True:
+            tele.stage(None, "idle")
             dwell_us_live, settle_us_live = self.flush_knobs()
             dwell_s = dwell_us_live / 1e6
             settle_s = max(settle_us_live / 1e6, 1e-4)
@@ -1625,9 +1652,9 @@ class NetServer(_BaseServer):
                         and not self._staged_depth_locked():
                     return
                 batch = self._drain_locked(cfg.flush_ops)
-            t0 = time.monotonic()
+            t0 = tele.stage(None, "dwell")
             while len(batch) < cfg.flush_ops and not self._stop.is_set():
-                left = dwell_s - (time.monotonic() - t0)
+                left = dwell_s - (time.monotonic_ns() - t0) / 1e9
                 if left <= 0:
                     break
                 with self._flush_cv:
@@ -1638,8 +1665,10 @@ class NetServer(_BaseServer):
                     break  # settle cutoff: the queue went quiet
                 batch.extend(more)
             # dwell = first-drain to serve-start: how long ops sat in the
-            # staging queue accumulating batch mates
-            dwell_us = (time.monotonic() - t0) * 1e6
+            # staging queue accumulating batch mates; the first phase's
+            # prep starts at the same reading
+            dwell_us = (tele.stage(self._first_phase(batch), "prep")
+                        - t0) / 1e3
             self._h_dwell.observe(dwell_us)
             # cadence-sampled continuous-profiling gauges (one flush =
             # one sample): queue depth at serve start + last dwell —
@@ -1669,6 +1698,12 @@ class NetServer(_BaseServer):
                                       err="serve_error")
                         o.span = None
                     self._kill_op_conn(o)
+
+    @staticmethod
+    def _first_phase(batch: list) -> str:
+        """The phase `_serve_coalesced` serves first for this batch."""
+        return _PHASES[min(_PHASE_OF.get(o.mt, len(_PHASES) - 1)
+                           for o in batch)]
 
     def _pad_fused(self, keys: np.ndarray, pages: np.ndarray | None = None):
         """Pow2 pad ladder for fused widths (floor `pad_floor`): padded
@@ -1923,7 +1958,7 @@ class NetServer(_BaseServer):
         bounded ≤⌈log₂ b⌉ FAILED relaunches per culprit — until the
         culpable op(s) are isolated; healthy ops complete normally on
         live connections."""
-        t0, t0_ns, fs = begin(phase, len(ops))
+        t0, fs = begin(phase, len(ops))
         try:
             serve(ops)
         except Exception as e:  # noqa: BLE001 — contain, never unwind
@@ -1939,7 +1974,7 @@ class NetServer(_BaseServer):
                     self._bump("bisect_launches")
                     self._phase_guard(half, phase, serve, begin, spans)
         else:
-            spans(ops, phase, t0, t0_ns, fs)
+            spans(ops, phase, t0, fs)
 
     def _isolated(self, ops: list, phase: str,
                   exc: BaseException) -> None:
@@ -2005,40 +2040,46 @@ class NetServer(_BaseServer):
                     np.concatenate(kk) if len(kk) > 1 else kk[0])
 
         def _phase_begin(phase: str, n_ops: int):
-            """(perf t0, monotonic t0_ns, ambient flush-phase span).
-            The flush span stays open across the backend call so the
-            mesh plane's per-shard program spans nest under it."""
-            return (time.perf_counter(), time.monotonic_ns(),
-                    tele.span_begin("server", f"flush:{phase}",
-                                    flush=fseq, phase=phase, ops=n_ops))
+            """(phase start, ambient flush-phase span). The phase starts
+            where its `prep` stage does — one reading for the stage, the
+            span, the phase histogram and queue wait. The flush span
+            stays open across the backend call so the mesh plane's
+            per-shard program spans nest under it."""
+            t0 = tele.stage(phase, "prep")
+            return t0, tele.span_begin("server", f"flush:{phase}", t0_ns=t0,
+                                       flush=fseq, phase=phase, ops=n_ops)
 
-        def _spans(ops: list, phase: str, t0: float, t0_ns: int,
-                   fs) -> None:
+        def _spans(ops: list, phase: str, t0: int, fs) -> None:
             """Close this phase's span tree for every involved op: the
             op span (opened at staging) gets its queue-wait child
             (staging → phase start, measured explicitly) and its phase
             child (cross-linked to the flush span by flush seq) — the
             flush-side half of the client→wire→queue→phase→shard
-            trace."""
+            trace. The phase ends here, its replies enqueued; the
+            `reply` stage runs on through this bookkeeping."""
+            t1 = time.monotonic_ns()
+            # queue wait, summed over the ops whether tracing or not
+            self._c_qwait[0].inc((t0 * len(ops) - sum(o.t_ns for o in ops)
+                                  + 500) // 1000)
+            self._c_qwait[1].inc(len(ops))
             if not tele.enabled():
                 tele.span_end(fs)  # unwind ambient even if toggled off
                 return
-            dur = (time.perf_counter() - t0) * 1e6
+            dur = (t1 - t0) / 1e3
             self._h_phase[phase].observe(dur)
-            tele.span_end(fs, ok=True)
-            t1_ns = time.monotonic_ns()
+            tele.span_end(fs, ok=True, t1_ns=t1)
             for o in ops:
                 if o.span is not None:
                     # lean completed-node records (no Span alloc, no
                     # ambient traffic): this runs per op per flush
                     tele.record_tree_span(
                         "server", "queue_wait", o.trace, o.span.sid,
-                        o.t_ns, t0_ns)
-                    self._h_qwait.observe((t0_ns - o.t_ns) / 1e3)
+                        o.t_ns, t0)
+                    self._h_qwait.observe((t0 - o.t_ns) / 1e3)
                     tele.record_tree_span(
                         "server", "phase", o.trace, o.span.sid,
-                        t0_ns, t1_ns, phase=phase, flush=fseq)
-                    tele.span_end(o.span, ok=True, t1_ns=t1_ns,
+                        t0, t1, phase=phase, flush=fseq)
+                    tele.span_end(o.span, ok=True, t1_ns=t1,
                                   phase=phase, flush=fseq)
                     o.span = None
                 else:
@@ -2061,6 +2102,7 @@ class NetServer(_BaseServer):
             if len(keys):
                 pk, pp = self._pad_fused(keys, pages)
                 be.put(pk, pp)
+            tele.stage("put", "reply")
             for o in ops:
                 # applied-stamp AFTER the fused put returns: this
                 # put is provably inside any filter packed later
@@ -2077,6 +2119,7 @@ class NetServer(_BaseServer):
         def _serve_ins(ops: list) -> None:
             for o in ops:
                 uncovered = be.insert_extent(o.keys, o.a, o.b)
+                tele.stage("ins_ext", "reply")
                 self._reply(o, MSG_SUCCESS, count=int(uncovered))
 
         for o in (o for o in batch if o.mt == MSG_INSEXT):
@@ -2088,6 +2131,7 @@ class NetServer(_BaseServer):
             hit = (np.asarray(be.invalidate(self._pad_fused(keys)),
                               bool)[:len(keys)]
                    if len(keys) else np.zeros(0, bool))
+            tele.stage("del", "reply")
             lo = 0
             for o in ops:
                 h = hit[lo:lo + o.count]
@@ -2103,6 +2147,7 @@ class NetServer(_BaseServer):
         def _serve_gext(ops: list) -> None:
             keys = np.concatenate([o.keys for o in ops])
             vals, ef = be.get_extent(self._pad_fused(keys))
+            tele.stage("get_ext", "reply")
             vals = np.asarray(vals, np.uint32)
             ef = np.asarray(ef, bool)
             lo = 0
@@ -2138,6 +2183,7 @@ class NetServer(_BaseServer):
             else:
                 pages = np.zeros((0, W), np.uint32)
                 found = np.zeros(0, bool)
+            tele.stage("get", "reply")
             lo = 0
             for o in ops:
                 f = found[lo:lo + o.count]
@@ -2160,7 +2206,7 @@ class NetServer(_BaseServer):
         for o in (o for o in batch
                   if o.mt in (MSG_STATS, MSG_BFPULL, MSG_RREPAIR,
                               MSG_RECOVERY, MSG_PROFILE)):
-            t0, t0_ns, fs = _phase_begin("aux", 1)
+            t0, fs = _phase_begin("aux", 1)
             try:
                 if o.mt == MSG_RECOVERY:
                     body, cnt = self._serve_recovery(be, o.count, None)
@@ -2215,7 +2261,8 @@ class NetServer(_BaseServer):
                 else:
                     self._phase_failed([o], "aux", exc=e)
             else:
-                _spans([o], "aux", t0, t0_ns, fs)
+                tele.stage("aux", "reply")
+                _spans([o], "aux", t0, fs)
 
     # -- server→client bloom push (`rdpma_bf_sender` analog) --
 

@@ -1,27 +1,27 @@
-"""Device-time X-ray: per-program on-chip cost attribution.
+"""Fetch-time attribution: per-program time blocked on device results.
 
 The host-side telemetry stack (spans, SLO watchdog, series rings)
-stops at the dispatch boundary: JAX launches are async, so the wall
-time a program actually spends on the device is only observable at the
-FETCH — the first host access that blocks on the result. This module
-owns that seam:
+stops at the dispatch boundary: JAX launches are async, so on the
+host clock a program's cost shows only at the FETCH — the first host
+access that blocks on the result. This module owns that seam:
 
 - `fetch(program, phase, thunk, ...)` is the ONE sanctioned sync
-  point. It times the blocking fetch (device compute + transfer =
-  `device_us`), derives the dispatch-vs-device split from the launch
-  stamp when the caller has one (`PlaneHandle.t_launch_ns`), and feeds
-  the per-program `device_us`/`dispatch_us` histogram families, the
-  per-shard device-time lanes, and the windowed `shard_imbalance`
-  gauge (max/mean device time across shards per window). Serving
+  point. It times the blocking fetch on the host clock (`fetch_us`:
+  the wait for device compute plus the copy back — not device busy
+  time, which only a profiler trace gives), takes the gap from the
+  launch when the caller has its stamp (`launch_gap_us`), and feeds
+  the per-program `fetch_us`/`launch_gap_us` histogram families, the
+  per-shard fetch-time lanes, and the windowed `shard_imbalance`
+  gauge (max/mean fetch time across shards per window). The serving
+  plane times its fetch with the `fetch`/`launch` stage readings
+  (`PlaneBackend._run`) and calls `note_launch` itself. Serving
   modules must not call `block_until_ready` themselves (the
   `profiler-seam` analyze rule); warmup paths use `block_ready`.
-- `cost_probe(program, fn)` wraps the FIRST call of a freshly-tracked
-  program signature (the recompile-tracker seam) and captures
-  `lowered.cost_analysis()` FLOPs / bytes-accessed into `cost.*`
-  gauges, so BENCH_HISTORY rows can carry roofline context.
 - `Profiler.start_capture(ms)` runs one bounded `jax.profiler` trace
   under the flight recorder's dump dir with the recorder's own
   cooldown + rotation discipline — the server half of `MSG_PROFILE`.
+  It runs without the Python tracer, so the host runs at its real
+  speed; the flush loop's stages show as `pmdfc.*` host events.
 
 The profiler is opt-in (`PMDFC_PROF=on` or an explicit `install()`);
 when nothing attaches, every seam is a passthrough and telemetry
@@ -30,7 +30,7 @@ registry snapshot gains a `profile` block (schema `pmdfc-telemetry-v3`)
 carrying the phase x program x shard attribution table that
 `tools/proftool.py` rolls into breakdown tables and Perfetto lanes.
 Recording rides the TRACING tier: `PMDFC_TELEMETRY=off` silences the
-device lanes too, so overhead has exactly two states.
+fetch lanes too, so overhead has exactly two states.
 """
 from __future__ import annotations
 
@@ -59,12 +59,11 @@ class Profiler:
         self.config = config or ProfilerConfig()
         self._reg = registry if registry is not None else tele.get()
         self._sc = self._reg.scope("prof", unique=False)
-        self._cost_sc = self._reg.scope("cost", unique=False)
         # guarded-by: _launches, _n_shards, _shard_us, _shard_ops,
         # guarded-by: _win_us, _win_n, _imbalance, _h_shard, _g_shard,
-        # guarded-by: _table, _rows_dropped, _cost
+        # guarded-by: _table, _rows_dropped
         self._lock = threading.Lock()
-        # program -> (device_us hist, dispatch_us hist): the per-launch
+        # program -> (fetch_us hist, launch_gap_us hist): the per-launch
         # path runs on the serving tier's serialized reply drain, so it
         # indexes a plain dict instead of paying the scope name->metric
         # lookup (registry lock + f-string) twice per launch. Benign
@@ -73,18 +72,17 @@ class Profiler:
         self._h_prog: dict = {}
         self._launches = 0
         self._n_shards = 0
-        self._shard_us: list[float] = []   # cumulative device µs
+        self._shard_us: list[float] = []   # cumulative fetch µs
         self._shard_ops: list[int] = []    # ops attributed (== mesh lanes)
         self._win_us: list[float] = []     # current imbalance window
         self._win_n = 0
         self._imbalance = 0.0              # 0 until one window completes
-        self._h_shard: tuple = ()          # device_us_s{i} hist family
-        self._g_shard: tuple = ()          # shard{i}_device_us gauges
-        # (phase, program, shard) -> [ops, device_us]; shard -1 = host
+        self._h_shard: tuple = ()          # fetch_us_s{i} hist family
+        self._g_shard: tuple = ()          # shard{i}_fetch_us gauges
+        # (phase, program, shard) -> [ops, fetch_us]; shard -1 = host
         # path with no per-shard routing (engine/kv transports)
         self._table: dict = {}
         self._rows_dropped = 0
-        self._cost: dict = {}
         self._g_imb = self._sc.gauge("shard_imbalance")
         # guarded-by: _trace_active, _last_trace_t, _trace_seq
         self._trace_lock = threading.Lock()
@@ -104,9 +102,9 @@ class Profiler:
             self._win_us.append(0.0)
         if n > self._n_shards:
             self._n_shards = n
-            self._h_shard = self._sc.hist_family("device_us", n)
+            self._h_shard = self._sc.hist_family("fetch_us", n)
             self._g_shard = tuple(
-                self._sc.gauge(f"shard{i}_device_us") for i in range(n))
+                self._sc.gauge(f"shard{i}_fetch_us") for i in range(n))
 
     # caller-holds: _lock
     def _bump_row(self, phase: str, program: str, shard: int,
@@ -121,28 +119,28 @@ class Profiler:
         row[0] += ops
         row[1] += us
 
-    def note_launch(self, program: str, phase: str, device_us: float,
-                    dispatch_us: float = 0.0, n_ops: int = 0,
+    def note_launch(self, program: str, phase: str, fetch_us: float,
+                    launch_gap_us: float = 0.0, n_ops: int = 0,
                     counts=None, n_shards: int = 0) -> None:
-        """Attribute one blocking fetch: `device_us` is the wall time
+        """Attribute one blocking fetch: `fetch_us` is the wall time
         the host spent blocked in the fetch (compute + transfer),
-        `dispatch_us` the launch-to-fetch-begin gap when the caller
+        `launch_gap_us` the launch-to-fetch-begin gap when the caller
         stamped the launch. `counts` (the plane's per-shard routed-op
-        vector) splits the device time across shards proportionally —
+        vector) splits the fetch time across shards proportionally —
         the SAME vector that feeds `mesh.shard{i}_ops`, so per-shard
         sums reconcile with the span attribution by construction."""
         if not tele.enabled():
             return
         hp = self._h_prog.get(program)
         if hp is None:
-            hp = (self._sc.hist(f"{program}.device_us"),
-                  self._sc.hist(f"{program}.dispatch_us"))
+            hp = (self._sc.hist(f"{program}.fetch_us"),
+                  self._sc.hist(f"{program}.launch_gap_us"))
             self._h_prog[program] = hp
-        hp[0].observe(device_us)
-        if dispatch_us:
-            # only launches with a real stamp feed the dispatch family —
-            # a sync verb's structural 0.0 would just bury the signal
-            hp[1].observe(dispatch_us)
+        hp[0].observe(fetch_us)
+        if launch_gap_us:
+            # only launches with a real stamp feed the gap family — a
+            # sync verb's structural 0.0 would just bury the signal
+            hp[1].observe(launch_gap_us)
         c = None
         if counts is not None:
             c = np.asarray(counts)
@@ -152,14 +150,14 @@ class Profiler:
             self._launches += 1
             if c is None:
                 self._bump_row(phase, program, -1, int(n_ops),
-                               float(device_us))
+                               float(fetch_us))
                 return
             self._grow(max(len(c), int(n_shards)))
             total = int(c.sum())
             hot = np.flatnonzero(c)
             for s in hot:
                 s = int(s)
-                share = float(device_us) * (int(c[s]) / total)
+                share = float(fetch_us) * (int(c[s]) / total)
                 self._shard_us[s] += share
                 self._shard_ops[s] += int(c[s])
                 self._win_us[s] += share
@@ -182,32 +180,6 @@ class Profiler:
                 for i in range(len(self._win_us)):
                     self._win_us[i] = 0.0
 
-    # -- static cost capture ---------------------------------------
-
-    def capture_cost(self, program: str, fn, args, kwargs) -> None:
-        """`lowered.cost_analysis()` FLOPs/bytes for one program
-        signature -> `cost.<program>.{flops,bytes}` gauges. Lowering
-        only traces avals (no execution, no donation), so it is safe to
-        run before the real dispatch; everything is best-effort — the
-        stages API has drifted across jax releases and a cost miss must
-        never fail serving."""
-        try:
-            lowered = fn.lower(*args, **kwargs)
-            try:
-                ca = lowered.cost_analysis()
-            except Exception:  # noqa: BLE001 — older stages API
-                ca = lowered.compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            flops = float(ca.get("flops", 0.0) or 0.0)
-            byts = float(ca.get("bytes accessed", 0.0) or 0.0)
-        except Exception:  # noqa: BLE001 — cost capture is advisory
-            return
-        with self._lock:
-            self._cost[program] = {"flops": flops, "bytes": byts}
-        self._cost_sc.set(f"{program}.flops", flops)
-        self._cost_sc.set(f"{program}.bytes", byts)
-
     # -- bounded on-demand trace (MSG_PROFILE server half) ---------
 
     def start_capture(self, duration_ms: int) -> dict | None:
@@ -217,7 +189,9 @@ class Profiler:
         live, or the cooldown has not elapsed — the recorder's "a rung
         firing in a tight loop must not write a dump per op"
         discipline, applied to traces. A daemon timer stops the trace;
-        the caller never blocks for the capture window."""
+        the caller never blocks for the capture window. The Python
+        tracer stays off (`python_tracer_level=0`): it traces every
+        Python call and would slow the very host path being captured."""
         dump_dir = getattr(self._reg, "dump_dir", None)
         if not dump_dir:
             return None
@@ -236,7 +210,9 @@ class Profiler:
         try:
             os.makedirs(path, exist_ok=True)
             import jax
-            jax.profiler.start_trace(path)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(path, profiler_options=opts)
         except Exception:  # noqa: BLE001 — capture is advisory
             with self._trace_lock:
                 self._trace_active = False
@@ -282,19 +258,18 @@ class Profiler:
                 self._g_shard[i].set(round(self._shard_us[i], 1))
             rows = [
                 {"phase": ph, "program": pr, "shard": s,
-                 "ops": row[0], "device_us": round(row[1], 1)}
+                 "ops": row[0], "fetch_us": round(row[1], 1)}
                 for (ph, pr, s), row in sorted(self._table.items())
             ]
             doc = {
-                "schema": "pmdfc-prof-v1",
+                "schema": "pmdfc-prof-v2",
                 "launches": self._launches,
                 "n_shards": self._n_shards,
                 "rows": rows,
                 "rows_dropped": self._rows_dropped,
-                "shard_device_us": [round(v, 1) for v in self._shard_us],
+                "shard_fetch_us": [round(v, 1) for v in self._shard_us],
                 "shard_ops": list(self._shard_ops),
                 "imbalance": round(self._imbalance, 3),
-                "cost": {k: dict(v) for k, v in sorted(self._cost.items())},
             }
         return doc
 
@@ -345,7 +320,7 @@ def fetch(program: str, phase: str, thunk, *, n_ops: int = 0,
           counts=None, n_shards: int = 0, t_launch_ns: int = 0,
           ring: bool = False):
     """THE sanctioned sync point: run `thunk` (the blocking fetch),
-    time it as device_us, and attribute. Passthrough when no profiler
+    time it as fetch_us, and attribute. Passthrough when no profiler
     is attached or the tracing tier is off. `ring=True` additionally
     rings a `device` span record (src=prof) so SLO stage attribution
     and tracetool timelines see the device window — plane launches skip
@@ -357,7 +332,7 @@ def fetch(program: str, phase: str, thunk, *, n_ops: int = 0,
     out = thunk()
     t1 = time.monotonic_ns()
     p.note_launch(program, phase, (t1 - t0) / 1e3,
-                  dispatch_us=max(0.0, (t0 - t_launch_ns) / 1e3)
+                  launch_gap_us=max(0.0, (t0 - t_launch_ns) / 1e3)
                   if t_launch_ns else 0.0,
                   n_ops=n_ops, counts=counts, n_shards=n_shards)
     if ring:
@@ -374,23 +349,6 @@ def block_ready(x):
     the `profiler-seam` analyze rule flags stray sync points."""
     import jax
     return jax.block_until_ready(x)
-
-
-def cost_probe(program: str, fn):
-    """Wrap the FIRST call of a freshly-tracked program signature (the
-    `track_program` seam returns True exactly once per signature) so
-    the next dispatch captures static cost before running. Returns
-    `fn` unwrapped when capture is off — the cached jit function the
-    caller stores stays clean either way."""
-    p = active()
-    if p is None or not p.config.cost_capture:
-        return fn
-
-    def probe(*args, **kwargs):
-        p.capture_cost(program, fn, args, kwargs)
-        return fn(*args, **kwargs)
-
-    return probe
 
 
 def capture(duration_ms: int) -> dict | None:

@@ -470,8 +470,8 @@ class KVServer:
                 out, found, nb = self.kv.get_async(keys[gets],
                                                    pad_floor=floor)
                 handles["gets"] = (gets, (out, None, found, None), nb)
-        # launch stamp for the dispatch-vs-device split: _finalize
-        # charges the launch-to-first-fetch gap as dispatch_us
+        # launch stamp: _finalize charges the launch-to-first-fetch gap
+        # as the profiler's launch_gap_us
         handles["t_ns"] = time.monotonic_ns()
         return handles
 
@@ -483,8 +483,8 @@ class KVServer:
         # The blocking fetches below are where device compute + transfer
         # time is actually paid (dispatch in _launch is async), so the
         # reference's TIME_CHECK-style write/read accumulators
-        # (`server/rdma_svr.cpp:64-76`) live here — and the device-time
-        # profiler's timed-fetch seam with them. `t_l` (the launch
+        # (`server/rdma_svr.cpp:64-76`) live here — and the profiler's
+        # timed-fetch seam with them. `t_l` (the launch
         # stamp) charges the dispatch gap to the FIRST blocking phase;
         # plane handles carry their own per-launch stamps.
         t_l = handles.pop("t_ns", 0)

@@ -41,6 +41,16 @@ share:
   dumps (clock offset estimated from the HOLA exchange, see
   `clock_event`) into a Chrome-trace/Perfetto timeline.
 
+- **Stage clock.** `stage(phase, name)` splits one thread's time into
+  back-to-back stages at one clock reading per boundary. A thread that
+  `bind_stages(scope)` (the NetServer flush loop binds its `stats`)
+  counts each closed stage into `stage_<phase>_<name>_us` / `_n` there
+  — plain counters, live with the tracing tier off — mirrors it into
+  any profiler trace as `jax.profiler.TraceAnnotation("pmdfc.<phase>.
+  <name>")`, and, with tracing on, rings it as a span record under the
+  open `flush:<phase>` span. On an unbound thread a stage is only the
+  clock reading its caller times with.
+
 - **Continuous profiling.** `track_program()` is the jit program-cache
   miss tracker: every dispatch seam (kv.py's padded verbs, the sharded
   plane's `_wrap` cache) reports its program signature; the first
@@ -715,6 +725,14 @@ class _SpanTls(threading.local):
 
     def __init__(self):
         self.stack: list = []
+        # the stage clock (`bind_stages`): the counter scope bound to
+        # this thread (None = stages are bare clock readings), the
+        # stage open on it, its (us, n) counters by stage, and the
+        # profiler annotation class (None without jax)
+        self.stages: Scope | None = None
+        self.stage: _Stage | None = None
+        self.ctrs: dict = {}
+        self.annotate = None
 
 
 _SPAN_TLS = _SpanTls()
@@ -928,6 +946,89 @@ def clock_event(conn: int, offset_ns: int, rtt_ns: int) -> None:
     get().record({"kind": "clock", "conn": conn,
                   "offset_ns": int(offset_ns), "rtt_ns": int(rtt_ns),
                   "t": time.time()})
+
+
+# -- stage clock ------------------------------------------------------------
+
+class _Stage:
+    """The stage open on a bound thread: its name, the reading it opened
+    at, its profiler annotation and its ring parent (None until an open
+    `flush:<phase>` span is seen, at open or at close)."""
+
+    __slots__ = ("phase", "name", "t0", "ann", "parent")
+
+    def __init__(self, phase, name, t0, ann, parent):
+        self.phase = phase
+        self.name = name
+        self.t0 = t0
+        self.ann = ann
+        self.parent = parent
+
+
+def bind_stages(scope: Scope | None) -> None:
+    """Bind `scope` as the calling thread's stage counters (None
+    unbinds, closing the open stage). Only a bound thread tracks an open
+    stage, counts, annotates and rings; the NetServer flush loop binds
+    its `stats` for its lifetime."""
+    tls = _SPAN_TLS
+    if tls.stage is not None:
+        _stage_close(tls, tls.stage, time.monotonic_ns())
+        tls.stage = None
+    tls.stages = scope
+    tls.ctrs = {}
+    if scope is not None and tls.annotate is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # a jax-less process: stages only count
+            TraceAnnotation = None
+        tls.annotate = TraceAnnotation
+
+
+def stage(phase: str | None, name: str) -> int:
+    """Enter stage `<phase>.<name>` (phase None = the flush loop's own
+    `idle`/`dwell`) on the calling thread at ONE clock reading, closing
+    the stage open before it at the same reading; returns that reading.
+    Entering the stage already open is a no-op that returns its start.
+    On an unbound thread it is just the reading."""
+    tls = _SPAN_TLS
+    if tls.stages is None:
+        return time.monotonic_ns()
+    cur = tls.stage
+    if cur is not None and cur.name == name and cur.phase == phase:
+        return cur.t0
+    t = time.monotonic_ns()
+    if cur is not None:
+        _stage_close(tls, cur, t)
+    ann = None
+    if tls.annotate is not None:
+        ann = tls.annotate(f"pmdfc.{phase or 'flush'}.{name}")
+        ann.__enter__()
+    stack = tls.stack
+    tls.stage = _Stage(phase, name, t, ann,
+                       stack[-1].sid if stack else None)
+    return t
+
+
+def _stage_close(tls: _SpanTls, st: _Stage, t1: int) -> None:
+    """Count, un-annotate and ring one closed stage."""
+    key = (st.phase, st.name)
+    c = tls.ctrs.get(key)
+    if c is None:
+        base = f"stage_{st.phase}_{st.name}" if st.phase \
+            else f"stage_{st.name}"
+        c = tls.ctrs[key] = (tls.stages.counter(f"{base}_us"),
+                             tls.stages.counter(f"{base}_n"))
+    c[0].inc((t1 - st.t0 + 500) // 1000)
+    c[1].inc()
+    if st.ann is not None:
+        st.ann.__exit__(None, None, None)
+    if _STATE.tracing:
+        parent = st.parent
+        if parent is None:
+            parent = tls.stack[-1].sid if tls.stack else 0
+        record_tree_span("server", f"pmdfc.{st.phase or 'flush'}."
+                         f"{st.name}", 0, parent, st.t0, t1,
+                         phase=st.phase or "flush")
 
 
 # -- continuous profiling ---------------------------------------------------

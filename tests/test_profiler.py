@@ -1,10 +1,10 @@
-"""Device-time X-ray suite — profiler seams, lanes, wire verb, tooling.
+"""Fetch-time attribution suite — profiler seams, lanes, wire verb, tooling.
 
 Covers `runtime/profiler.py` end to end:
 
-- the timed-fetch seam: `profiler.fetch` splits dispatch vs device
-  time at the blocking fetch, feeds per-program `device_us` /
-  `dispatch_us` histograms and the phase x program x shard table.
+- the timed-fetch seam: `profiler.fetch` splits the launch gap from the
+  blocking fetch, feeds per-program `fetch_us` / `launch_gap_us`
+  histograms and the phase x program x shard table.
 - per-shard lane reconciliation: driving the 4-shard coalesced plane,
   the profiler's `shard_ops` lanes equal the mesh scope's
   `shard{i}_ops` counters EXACTLY (both split on the same routed-op
@@ -13,9 +13,11 @@ Covers `runtime/profiler.py` end to end:
   [1, n_shards].
 - `MSG_PROFILE` negotiation: HOLASI-acked captures land under the
   flight recorder's dump dir with cooldown; an old peer (no ack)
-  degrades `server_profile` to None without touching the wire.
+  degrades `server_profile` to None without touching the wire. A
+  capture runs without the Python tracer and holds the flush loop's
+  `pmdfc.*` stage events.
 - `tools/proftool.py`: breakdown table schema + reconciliation column,
-  Perfetto export rehomes device spans onto per-program lanes.
+  Perfetto export rehomes fetch spans onto per-program lanes.
 - kill-switch conformance: with `PMDFC_PROF` off nothing attaches,
   snapshots stay `pmdfc-telemetry-v2` with no `profile` key, and every
   seam is a passthrough.
@@ -74,7 +76,7 @@ def fresh_registry(tmp_path):
 # --- 1. the timed-fetch seam ----------------------------------------------
 
 
-def test_fetch_splits_device_and_dispatch(fresh_registry):
+def test_fetch_splits_fetch_and_launch_gap(fresh_registry):
     p = prof_mod.install()
     t_launch = time.monotonic_ns()
 
@@ -85,21 +87,23 @@ def test_fetch_splits_device_and_dispatch(fresh_registry):
     assert prof_mod.fetch("kv.get", "get", thunk, n_ops=8,
                           t_launch_ns=t_launch, ring=True) == 41
     snap = p.snapshot()
-    assert snap["schema"] == "pmdfc-prof-v1"
+    assert snap["schema"] == "pmdfc-prof-v2"
     assert snap["launches"] == 1
+    assert "cost" not in snap
     (row,) = snap["rows"]
     assert (row["phase"], row["program"], row["shard"]) == ("get", "kv.get", -1)
     assert row["ops"] == 8
-    assert row["device_us"] >= 2000  # the 2ms sleep is device time
+    assert row["fetch_us"] >= 2000  # the 2ms sleep is blocked-fetch time
     hists = tele.get().snapshot()["histograms"]
-    assert hists["prof.kv.get.device_us"]["count"] == 1
-    # the launch stamp preceded the fetch: a real dispatch gap recorded
-    assert hists["prof.kv.get.dispatch_us"]["count"] == 1
-    # device time is monotone with the blocked window
+    assert hists["prof.kv.get.fetch_us"]["count"] == 1
+    # the launch stamp preceded the fetch: a real launch gap recorded
+    assert hists["prof.kv.get.launch_gap_us"]["count"] == 1
+    assert not any("device_us" in k or "dispatch_us" in k for k in hists)
+    # fetch time is monotone with the blocked window
     def longer():
         time.sleep(0.004)
     prof_mod.fetch("kv.get", "get", longer, n_ops=8)
-    h = tele.get().snapshot()["histograms"]["prof.kv.get.device_us"]
+    h = tele.get().snapshot()["histograms"]["prof.kv.get.fetch_us"]
     assert h["max"] >= 4000 and h["count"] == 2
     # the registry snapshot carries the v3 profile block when attached
     doc = tele.get().snapshot()
@@ -148,7 +152,7 @@ def test_shard_lanes_reconcile_with_mesh_ops(fresh_registry):
     # feeds the mesh counters — the acceptance reconciliation pin
     assert snap["shard_ops"] == mesh_ops, (snap["shard_ops"], mesh_ops)
     assert sum(mesh_ops) == 800  # 400 puts + 400 gets, fully routed
-    assert all(us > 0 for us in snap["shard_device_us"])
+    assert all(us > 0 for us in snap["shard_fetch_us"])
     # the table's per-shard rows roll up to the same ops
     per_shard = [0] * 4
     for r in snap["rows"]:
@@ -239,6 +243,44 @@ def test_msg_profile_refused_without_dump_dir(monkeypatch):
         tele.configure()
 
 
+def test_capture_holds_flush_stages_without_python_tracer(fresh_registry):
+    """A capture taken across one flush holds the flush loop's stage
+    annotations (`pmdfc.<phase>.<stage>`, `pmdfc.flush.idle`) and no
+    Python-tracer events (named `$<file>:<line> <function>`)."""
+    import glob
+
+    import jax
+
+    from pmdfc_tpu.config import MeshConfig, NetConfig
+    from pmdfc_tpu.parallel.plane import make_serving_backend
+    from pmdfc_tpu.runtime.net import NetServer, TcpBackend
+
+    p = prof_mod.install()
+    be = make_serving_backend(_cfg(), MeshConfig(n_shards=1))
+    keys = _keys(64, seed=5)
+    srv = NetServer(lambda: be, net=NetConfig()).start()
+    with srv, TcpBackend("127.0.0.1", srv.port, page_words=W) as c:
+        c.put(keys, _pages(keys))  # compile outside the capture
+        c.get(keys)
+        res = p.start_capture(400)
+        assert res is not None
+        c.put(keys, _pages(keys))
+        _, found = c.get(keys)
+        assert found.all()
+        deadline = time.monotonic() + 20
+        while p._trace_active and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert not p._trace_active
+    (pb,) = glob.glob(f"{res['path']}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in jax.profiler.ProfileData.from_file(pb).planes
+             for line in plane.lines for e in line.events}
+    for ph in ("put", "get"):
+        for st in ("prep", "route", "launch", "fetch", "reply"):
+            assert f"pmdfc.{ph}.{st}" in names, (ph, st)
+    assert {"pmdfc.flush.idle", "pmdfc.flush.dwell"} <= names
+    assert not any(n.startswith("$") for n in names)
+
+
 # --- 5. proftool: breakdown table + Perfetto lanes ------------------------
 
 
@@ -265,7 +307,7 @@ def test_proftool_breakdown_and_perfetto(fresh_registry, tmp_path):
 
     agg = proftool._merge(proftool.load_docs([str(path)]))
     table = proftool.breakdown(agg)
-    assert table["schema"] == "pmdfc-proftable-v1"
+    assert table["schema"] == "pmdfc-proftable-v2"
     assert table["launches"] > 0 and table["rows"]
     # every shard lane reconciles against the dump's mesh counters
     assert len(table["shards"]) == 4

@@ -45,9 +45,10 @@ MEASURED_INT_KEYS = frozenset({"failed_search", "gather_bytes_per_s",
 # float-typed fields that are KNOBS (zipf exponents and the like)
 FLOAT_KNOB_KEYS = frozenset({"zipf", "theta", "alpha", "hedge_ms"})
 # units where smaller is better; anything else is treated as throughput
-# (`device_us` is the profiler's blocked-fetch device-time lane — wall
-# microseconds on the chip, so lower is better like any latency)
-LATENCY_UNITS = frozenset({"ns", "us", "ms", "s", "device_us"})
+# (`fetch_us` is the profiler's blocked-fetch lane — host microseconds
+# waiting on the chip, so lower is better like any latency; history rows
+# from before its rename carry it as `device_us`)
+LATENCY_UNITS = frozenset({"ns", "us", "ms", "s", "fetch_us", "device_us"})
 
 
 def lane_key(row: dict) -> str:

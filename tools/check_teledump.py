@@ -29,11 +29,11 @@ v2 documents additionally pin the workload-X-ray surfaces:
 Old v1 documents (no series/workload/causes) still parse: the v2
 requirements bind only documents that declare v2 / carry the sections.
 
-v3 documents additionally carry the device-time PROFILE block
+v3 documents additionally carry the fetch-time PROFILE block
 (`runtime/profiler.py`): the phase x program x shard attribution
-table, per-shard device-time lanes agreeing with `n_shards`, the
+table, per-shard fetch-time lanes agreeing with `n_shards`, and the
 windowed imbalance gauge pinned to [1, n_shards] (or 0 before a
-window completes), and the static `cost.*` captures. The block and
+window completes). The block and
 the v3 declaration travel together — additive over v2, so v2 docs
 (profiler off) still parse unchanged.
 
@@ -568,17 +568,16 @@ def check_containment(snap: dict) -> list[str]:
 
 
 def check_profile(snap: dict) -> list[str]:
-    """Device-time profiler pins (`runtime/profiler.py`), bound when
+    """Fetch-time profiler pins (`runtime/profiler.py`), bound when
     the snapshot carries a `profile` block — which is ALSO the v3
     declaration gate: a profile block rides only on documents declaring
     `pmdfc-telemetry-v3`, and a v3 declaration without the block means
     the sink detached mid-snapshot. Inside the block: the attribution
     rows carry (phase, program, shard >= -1, non-negative ops /
-    device_us), the per-shard lane vectors agree with the advertised
-    `n_shards`, the windowed imbalance gauge is either 0 (no window
+    fetch_us), the per-shard lane vectors agree with the advertised
+    `n_shards`, and the windowed imbalance gauge is either 0 (no window
     completed yet) or inside its algebraic range [1, n_shards] —
-    max/mean over n non-negative lanes can land nowhere else — and any
-    captured `cost.*` entries ship numeric flops/bytes pairs."""
+    max/mean over n non-negative lanes can land nowhere else."""
     errs: list[str] = []
     prof = snap.get("profile")
     declared_v3 = snap.get("schema") == "pmdfc-telemetry-v3"
@@ -591,9 +590,9 @@ def check_profile(snap: dict) -> list[str]:
                     "(v3 declares the profiler sink)")
     if not isinstance(prof, dict):
         return errs + ["'profile' is not an object"]
-    if prof.get("schema") != "pmdfc-prof-v1":
+    if prof.get("schema") != "pmdfc-prof-v2":
         errs.append(f"profile.schema is {prof.get('schema')!r}, "
-                    "expected 'pmdfc-prof-v1'")
+                    "expected 'pmdfc-prof-v2'")
     for k in ("launches", "rows_dropped", "n_shards"):
         v = prof.get(k)
         if not isinstance(v, numbers.Integral) or isinstance(v, bool) \
@@ -617,11 +616,11 @@ def check_profile(snap: dict) -> list[str]:
                     or s < -1 or (n and s >= n):
                 errs.append(f"profile.rows[{i}].shard: {s!r} outside "
                             f"[-1, {n})")
-            for k in ("ops", "device_us"):
+            for k in ("ops", "fetch_us"):
                 v = r.get(k)
                 if not _num(v) or v < 0:
                     errs.append(f"profile.rows[{i}].{k}: {v!r}")
-    for k, want in (("shard_device_us", numbers.Real),
+    for k, want in (("shard_fetch_us", numbers.Real),
                     ("shard_ops", numbers.Integral)):
         lanes = prof.get(k)
         if not isinstance(lanes, list) or len(lanes) != n:
@@ -634,15 +633,6 @@ def check_profile(snap: dict) -> list[str]:
     if not _num(imb) or not (imb == 0 or (1.0 <= imb <= max(n, 1))):
         errs.append(f"profile.imbalance: {imb!r} not 0 or in "
                     f"[1, {max(n, 1)}]")
-    cost = prof.get("cost")
-    if not isinstance(cost, dict):
-        errs.append("profile.cost: missing or not an object")
-    else:
-        for prog, c in cost.items():
-            if not isinstance(c, dict) or not _num(c.get("flops")) \
-                    or not _num(c.get("bytes")) or c["flops"] < 0 \
-                    or c["bytes"] < 0:
-                errs.append(f"profile.cost.{prog}: {c!r}")
     return errs
 
 

@@ -1,4 +1,4 @@
-"""proftool — roll profiler teledumps into device-time breakdowns.
+"""proftool — roll profiler teledumps into fetch-time breakdowns.
 
 Consumes any document that embeds a telemetry snapshot with the
 profiler's `profile` block (schema `pmdfc-telemetry-v3`):
@@ -10,13 +10,13 @@ profiler's `profile` block (schema `pmdfc-telemetry-v3`):
 Surfaces:
 
     python -m tools.proftool dump.json --table
-        The phase x program x shard device-time breakdown (ops,
-        device_us, share of the shard axis), followed by the per-shard
-        lane totals RECONCILED against the `mesh.shard{i}_ops` span
-        counters — the cross-check that the profiler's proportional
-        split and the plane's routed-op accounting agree — plus the
-        windowed imbalance gauge and any captured `cost.*` roofline
-        context (FLOPs / bytes per program signature).
+        The phase x program x shard fetch-time breakdown (ops,
+        fetch_us — host time blocked on the program's results — and
+        share of the shard axis), followed by the per-shard lane totals
+        RECONCILED against the `mesh.shard{i}_ops` span counters — the
+        cross-check that the profiler's proportional split and the
+        plane's routed-op accounting agree — plus the windowed
+        imbalance gauge.
 
     python -m tools.proftool dump.json --json
         The same aggregation as a machine-readable document.
@@ -40,8 +40,8 @@ import sys
 
 from tools import tracetool
 
-_ROW_COLS = ("phase", "program", "shard", "ops", "device_us", "share")
-_SHARD_COLS = ("shard", "device_us", "prof_ops", "mesh_ops", "match")
+_ROW_COLS = ("phase", "program", "shard", "ops", "fetch_us", "share")
+_SHARD_COLS = ("shard", "fetch_us", "prof_ops", "mesh_ops", "match")
 
 
 def load_docs(paths) -> list[dict]:
@@ -71,7 +71,6 @@ def _merge(docs: list[dict]) -> dict:
     shard_us: list[float] = []
     shard_ops: list[int] = []
     mesh_ops: dict[int, int] = {}
-    cost: dict = {}
     launches = 0
     dropped = 0
     imbalance = 0.0
@@ -89,8 +88,8 @@ def _merge(docs: list[dict]) -> dict:
                    int(r.get("shard", -1)))
             row = table.setdefault(key, [0, 0.0])
             row[0] = max(row[0], int(r.get("ops", 0)))
-            row[1] = max(row[1], float(r.get("device_us", 0.0)))
-        us = prof.get("shard_device_us", ())
+            row[1] = max(row[1], float(r.get("fetch_us", 0.0)))
+        us = prof.get("shard_fetch_us", ())
         ops = prof.get("shard_ops", ())
         while len(shard_us) < len(us):
             shard_us.append(0.0)
@@ -99,8 +98,6 @@ def _merge(docs: list[dict]) -> dict:
             shard_us[i] = max(shard_us[i], float(v))
         for i, v in enumerate(ops):
             shard_ops[i] = max(shard_ops[i], int(v))
-        for prog, c in prof.get("cost", {}).items():
-            cost[prog] = dict(c)
         for name, v in d["snap"].get("counters", {}).items():
             if name.startswith("mesh.shard") and name.endswith("_ops"):
                 try:
@@ -109,7 +106,7 @@ def _merge(docs: list[dict]) -> dict:
                     continue
                 mesh_ops[i] = max(mesh_ops.get(i, 0), int(v))
     return {"table": table, "shard_us": shard_us, "shard_ops": shard_ops,
-            "mesh_ops": mesh_ops, "cost": cost, "launches": launches,
+            "mesh_ops": mesh_ops, "launches": launches,
             "rows_dropped": dropped, "imbalance": imbalance,
             "docs_with_profile": n_docs}
 
@@ -119,7 +116,7 @@ def breakdown(agg: dict) -> dict:
     total_us = sum(us for _ops, us in agg["table"].values()) or 1.0
     rows = [
         {"phase": ph, "program": pr, "shard": s, "ops": ops,
-         "device_us": round(us, 1), "share": round(us / total_us, 4)}
+         "fetch_us": round(us, 1), "share": round(us / total_us, 4)}
         for (ph, pr, s), (ops, us) in sorted(
             agg["table"].items(), key=lambda kv: -kv[1][1])
     ]
@@ -128,7 +125,7 @@ def breakdown(agg: dict) -> dict:
         mesh = agg["mesh_ops"].get(i)
         prof = agg["shard_ops"][i]
         shards.append({
-            "shard": i, "device_us": round(us, 1), "prof_ops": prof,
+            "shard": i, "fetch_us": round(us, 1), "prof_ops": prof,
             "mesh_ops": mesh,
             # mesh counters cover EVERY routed launch since process
             # start; the profiler only attributes while attached AND
@@ -138,13 +135,12 @@ def breakdown(agg: dict) -> dict:
                      or ("yes" if prof == mesh else "no"),
         })
     return {
-        "schema": "pmdfc-proftable-v1",
+        "schema": "pmdfc-proftable-v2",
         "launches": agg["launches"],
         "rows_dropped": agg["rows_dropped"],
         "imbalance": agg["imbalance"],
         "rows": rows,
         "shards": shards,
-        "cost": agg["cost"],
     }
 
 
@@ -169,14 +165,6 @@ def render_report(rep: dict) -> str:
     out.append(f"launches={rep['launches']} "
                f"rows_dropped={rep['rows_dropped']} "
                f"imbalance={rep['imbalance']}")
-    if rep["cost"]:
-        out.append("")
-        out.append("static cost (lowered.cost_analysis):")
-        out.append(_render(
-            [{"program": k, "flops": v.get("flops", 0.0),
-              "bytes": v.get("bytes", 0.0)}
-             for k, v in sorted(rep["cost"].items())],
-            ("program", "flops", "bytes")))
     return "\n".join(out)
 
 

@@ -44,10 +44,10 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 _SHARD_HIST = re.compile(r"\.phase_get_us_s(\d+)$")
-# the profiler's per-shard device-time lanes (`runtime/profiler.py`
+# the profiler's per-shard fetch-time lanes (`runtime/profiler.py`
 # hist family on the shared `prof` scope — present IFF a profiler is
 # attached, the v3 teledump pin)
-_PROF_SHARD_HIST = re.compile(r"^prof\.device_us_s(\d+)$")
+_PROF_SHARD_HIST = re.compile(r"^prof\.fetch_us_s(\d+)$")
 # per-tenant QoS lanes (`runtime/qos.py` scope families): the lane
 # counters and the declared-policy gauges share one `.qos.t<tid>.`
 # namespace under the server's stats prefix
@@ -139,9 +139,8 @@ def summarize(endpoint: str, doc: dict) -> dict:
     fg = (tele_snap.get("gauges") or {}).get("serving.fused_get")
     row["kernel"] = (None if fg is None
                      else ("pallas_fused" if fg else "xla_composed"))
-    # device-time lanes (profiler attached ⇒ v3 snapshot): per-shard
-    # blocked-fetch p95s + the windowed imbalance gauge — the on-chip
-    # complement to the host-side phase histograms above
+    # fetch-time lanes (profiler attached ⇒ v3 snapshot): per-shard
+    # blocked-fetch p95s + the windowed imbalance gauge
     prof_p95 = {}
     for name, h in (tele_snap.get("histograms") or {}).items():
         m = _PROF_SHARD_HIST.match(name)
