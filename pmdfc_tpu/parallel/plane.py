@@ -23,8 +23,8 @@ the plane verbs (`ShardedKV.plane_*`):
   O(batch), not O(table), per flush. Donating state-mutating phases
   stay platform-keyed in `shard._wrap`.
 - **Results gather back to host once per phase**, and GET replies ship
-  straight out of the routed buffer (`PlaneGets.hit_rows`): only hit
-  rows are ever copied.
+  straight out of the routed buffer (`PlaneGets.hit_rows`), framed by
+  the NetServer's connection writers: only hit rows are ever copied.
 
 Telemetry stays per-shard attributable: `shard{i}_ops` counters and
 `phase_*_us_s{i}` histogram families on the shared `mesh` scope, and a

@@ -700,10 +700,10 @@ class PlaneGets:
 
     The full request-order page matrix is never materialized unless a
     caller asks (`dense()`): the wire tier only ever ships HIT rows per
-    connection slice, so `hit_rows(lo, hi)` gathers exactly those rows
-    straight out of the routed buffer — one fancy-index per reply frame
-    instead of an O(batch × page) scatter per flush plus a second gather
-    per frame."""
+    connection slice, straight out of the routed buffer (`hit_rows`: a
+    zero-copy slice where a reply's hit lanes are one ascending run,
+    else one fancy-index), instead of an O(batch × page) scatter per
+    flush plus a second gather per frame."""
 
     __slots__ = ("found", "_rb", "_routed", "lane_served", "lane_refused")
 
@@ -719,11 +719,17 @@ class PlaneGets:
         self.lane_refused = lane_refused  # int64[R] | None
 
     def hit_rows(self, lo: int = 0, hi: int | None = None) -> np.ndarray:
-        """Contiguous page rows for the HIT requests in [lo, hi)."""
+        """Contiguous page rows for the HIT requests in [lo, hi): a
+        zero-copy slice of the routed buffer where they are one
+        ascending run of it (or none) — every all-hit slice where
+        routing kept request order — else one gather."""
         hi = len(self.found) if hi is None else hi
+        rows = np.asarray(self._routed)
         sel = self._rb.pos[lo:hi][self.found[lo:hi]]
-        return np.ascontiguousarray(np.asarray(self._routed)[sel],
-                                    np.uint32)
+        if len(sel) == 0 or (np.diff(sel) == 1).all():
+            s = int(sel[0]) if len(sel) else 0
+            return np.ascontiguousarray(rows[s:s + len(sel)], np.uint32)
+        return np.ascontiguousarray(rows[sel], np.uint32)
 
     def dense(self) -> np.ndarray:
         """Full request-order [b, W] matrix (`kv.KV.get` out semantics:

@@ -294,17 +294,26 @@ def _sendmsg_all(sock: socket.socket, views: list) -> None:
         sent = sock.sendmsg(views)
 
 
+# zlib.crc32 drops the GIL for a buffer over 5 KiB, and a thread that
+# drops it among the coalesced server's ~70 busy threads may wait up to
+# a switch interval (5 ms) to get it back. Fed slices under that size,
+# the CRC keeps the GIL: a frame of many pages is checksummed in one turn.
+_CRC_SLICE = 4096
+
+
 def _frame_views(msg_type: int, parts=(), status: int = 0, count: int = 0,
                  words: int = 0, stamp: int = 0) -> list:
     """Build one frame as an iovec [header, *payload_views]: the CRC runs
-    incrementally across the parts, so multi-part payloads (keys + pages,
-    found + hit rows) are never concatenated host-side."""
+    incrementally across the parts (in `_CRC_SLICE` slices), so
+    multi-part payloads (keys + pages, found + hit rows) are never
+    concatenated host-side."""
     views = [v for v in map(_as_view, parts) if v.nbytes]
     dlen = sum(v.nbytes for v in views)
     hdr0 = _HDR.pack(MAGIC, msg_type, status, count, words, stamp, dlen, 0)
     crc = zlib.crc32(hdr0)
     for v in views:
-        crc = zlib.crc32(v, crc)
+        for off in range(0, v.nbytes, _CRC_SLICE):
+            crc = zlib.crc32(v[off:off + _CRC_SLICE], crc)
     hdr = hdr0[:_CRC_OFF] + struct.pack("<I", crc)
     return [memoryview(hdr), *views]
 
@@ -524,23 +533,87 @@ class _ConnState:
     the flush thread): a peer that stops reading blocks only its own
     writer — the shared flush loop must never stall behind one slow
     socket. `out_bytes` caps the undrained backlog; a peer holding more
-    than the cap in unread replies is treated as dead."""
+    than the cap in unread replies is treated as dead. `out_held` counts
+    the queued `_GetReplies` items that hold a fetched buffer (at most
+    one: the flush loop detaches any later one)."""
 
-    __slots__ = ("sock", "cl", "outq", "out_cv", "out_bytes", "alive",
-                 "contain")
+    __slots__ = ("sock", "cl", "outq", "out_cv", "out_bytes", "out_held",
+                 "alive", "contain")
 
     def __init__(self, sock: socket.socket, cl: dict,
                  contain: bool = False):
         self.sock = sock
         self.cl = cl
         self.outq: collections.deque = collections.deque()
-        # guarded-by: outq, out_bytes, alive
+        # guarded-by: outq, out_bytes, out_held, alive
         self.out_cv = san.condition("_ConnState.out_cv")
         self.out_bytes = 0
+        self.out_held = 0
         self.alive = True
         # this connection negotiated CONTAIN_FLAG: it accepts MSG_NACK
         # and may stamp deadline budgets (HOLASI count bit 5)
         self.contain = contain
+
+
+class _Rows:
+    """Host page rows as a GET reply row source, with `PlaneGets`'
+    `found` and `hit_rows`: request i is answered by `rows[pos[i]]`.
+    `be.get`'s dense request-order pages (identity `pos`), or a queued
+    item's own copy of its hit rows (`_GetReplies.detach`)."""
+
+    __slots__ = ("rows", "pos", "found")
+
+    def __init__(self, rows: np.ndarray, pos: np.ndarray,
+                 found: np.ndarray):
+        self.rows = rows
+        self.pos = pos
+        self.found = found
+
+    def hit_rows(self, lo: int, hi: int) -> np.ndarray:
+        """The HIT rows of [lo, hi): a zero-copy slice of `rows` where
+        they are one ascending run of it (or none), else one gather."""
+        sel = self.pos[lo:hi][self.found[lo:hi]]
+        if len(sel) == 0 or (np.diff(sel) == 1).all():
+            s = int(sel[0]) if len(sel) else 0
+            return np.ascontiguousarray(self.rows[s:s + len(sel)],
+                                        np.uint32)
+        return np.ascontiguousarray(self.rows[sel], np.uint32)
+
+
+class _GetReplies:
+    """One GET phase's replies for one connection, queued UNFRAMED: the
+    fetched row source (a `PlaneGets` or `_Rows`), each verb's
+    `(seq, lo, hi)` request slice in the connection's op order, and its
+    size for the backlog cap. The connection's writer frames them
+    (`NetServer._frame_gets`), so the flush thread never gathers, casts
+    or CRCs a page. The item holds the fetched buffer (fresh each fetch)
+    until its frames are sent, unless `detach`ed."""
+
+    __slots__ = ("src", "words", "verbs", "nbytes", "held")
+
+    def __init__(self, src, words: int):
+        self.src = src
+        self.words = words
+        self.verbs: list = []
+        self.nbytes = 0     # the frames' wire size (+ a copy's `pos`)
+        self.held = True    # `src` is the phase's fetched buffer
+
+    def detach(self) -> None:
+        """Copy this item's found masks and hit rows out of the fetched
+        buffer, its verbs re-based onto the copy, so that the item holds
+        only what its size counts."""
+        found, rows, verbs, n = [], [], [], 0
+        for seq, lo, hi in self.verbs:
+            found.append(np.asarray(self.src.found[lo:hi], bool))
+            rows.append(self.src.hit_rows(lo, hi))
+            verbs.append((seq, n, n + hi - lo))
+            n += hi - lo
+        found = np.concatenate(found)
+        pos = np.cumsum(found) - 1  # hit lanes: consecutive rows
+        self.src = _Rows(np.concatenate(rows), pos, found)
+        self.verbs = verbs
+        self.nbytes += pos.nbytes
+        self.held = False
 
 
 class _StagedOp:
@@ -716,6 +789,14 @@ class NetServer(_BaseServer):
                          self.stats.counter("stage_queue_wait_n"))
         self._h_phase = {ph: self.stats.hist(f"phase_{ph}_us")
                          for ph in _PHASES}
+        # GET replies framed on the connection writers (`_frame_gets`):
+        # frames built, those sent as a slice of their row source (or
+        # with no rows), the writers' build time on the wall clock and
+        # on their own CPU clocks, and items whose build raised (each
+        # drops its connection)
+        self._c_reply = tuple(self.stats.counter(k) for k in (
+            "reply_deferred_frames", "reply_view_frames", "reply_build_us",
+            "reply_build_cpu_us", "reply_build_errors"))
         # workload characterization (`runtime/workload.py`): working-set
         # KMV + keyspace heat count-min, folded in on the host routing
         # path this loop already walks (gated on the tracing tier —
@@ -1733,13 +1814,16 @@ class NetServer(_BaseServer):
         pp[:n] = pages
         return pk, pp
 
-    def _enqueue_reply(self, cs: _ConnState, frame: list) -> bool:
-        """Queue one reply frame for the connection's writer. Returns
-        False (and kills the connection) when the peer's undrained
-        backlog exceeds the cap — a peer that stopped reading must cost
-        only itself, never the shared flush thread (which is why no
-        reply is ever SENT from the flush loop)."""
-        nbytes = sum(v.nbytes for v in frame)
+    def _enqueue_reply(self, cs: _ConnState, frame,
+                       nbytes: int | None = None) -> bool:
+        """Queue one reply frame (or a `_GetReplies` item and its size)
+        for the connection's writer. Returns False (and kills the
+        connection) when the peer's undrained backlog exceeds the cap —
+        a peer that stopped reading must cost only itself, never the
+        shared flush thread (which is why no reply is ever SENT from the
+        flush loop)."""
+        if nbytes is None:
+            nbytes = sum(v.nbytes for v in frame)
         cap = 2 * self.max_frame_bytes + (1 << 20)
         with cs.out_cv:
             if not cs.alive:
@@ -1747,18 +1831,39 @@ class NetServer(_BaseServer):
             if cs.out_bytes + nbytes > cap:
                 cs.alive = False
             else:
-                cs.outq.append(frame)
+                cs.outq.append((frame, nbytes))
                 cs.out_bytes += nbytes
+                cs.out_held += isinstance(frame, _GetReplies) and frame.held
                 cs.out_cv.notify()
                 return True
         self._drop_conn(cs.sock)
         return False
 
+    def _frame_gets(self, it: _GetReplies) -> tuple[list, int]:
+        """Frame one connection's GET replies (writer side): (frames,
+        how many went out as a zero-copy slice of their row source or
+        with no rows). The source's `hit_rows` picks: a slice where a
+        verb's hit rows are one ascending run of the fetched buffer —
+        every all-hit verb where routing kept request order — else one
+        gather. The bytes are the same either way."""
+        src, frames, n_view = it.src, [], 0
+        for seq, lo, hi in it.verbs:
+            f = np.asarray(src.found[lo:hi], bool)
+            hit = src.hit_rows(lo, hi)
+            n_view += hit.base is not None
+            frames.append(_frame_views(
+                MSG_SENDPAGE if f.any() else MSG_NOTEXIST,
+                (f.astype(np.uint8), hit), status=seq, count=hi - lo,
+                words=it.words))
+        return frames, n_view
+
     def _conn_writer(self, cs: _ConnState) -> None:
         """Per-connection reply writer: the only thread that sends on
         this socket in coalesced mode (reader keepalives and flush-loop
         results both arrive through the queue, so frames never
-        interleave)."""
+        interleave). It frames the flush loop's `_GetReplies` items
+        itself, while the flush thread runs the next flush."""
+        c_frames, c_views, c_us, c_cpu, c_err = self._c_reply
         while True:
             with cs.out_cv:
                 while not cs.outq and cs.alive \
@@ -1766,10 +1871,37 @@ class NetServer(_BaseServer):
                     cs.out_cv.wait(0.2)
                 if not cs.outq:
                     return  # dead or stopping, nothing left to drain
-                frames = [cs.outq.popleft()
-                          for _ in range(len(cs.outq))]
-                cs.out_bytes -= sum(sum(v.nbytes for v in fr)
-                                    for fr in frames)
+                items = [cs.outq.popleft() for _ in range(len(cs.outq))]
+                cs.out_bytes -= sum(n for _, n in items)
+                cs.out_held = 0
+            if cs.sock.fileno() < 0:
+                return  # dropped: nothing can be sent, so frame nothing
+            frames: list = []
+            t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+            n_built = n_view = 0
+            try:
+                for fr, _ in items:
+                    if isinstance(fr, _GetReplies):
+                        built, nv = self._frame_gets(fr)
+                        frames += built
+                        n_built += len(built)
+                        n_view += nv
+                    else:
+                        frames.append(fr)
+            except Exception:  # noqa: BLE001 — costs this connection only
+                import traceback
+
+                traceback.print_exc()
+                c_err.inc()
+                with cs.out_cv:
+                    cs.alive = False
+                self._drop_conn(cs.sock)
+                return
+            if n_built:
+                c_frames.inc(n_built)
+                c_views.inc(n_view)
+                c_us.inc((time.perf_counter_ns() - t0 + 500) // 1000)
+                c_cpu.inc((time.thread_time_ns() - c0 + 500) // 1000)
             try:
                 views: list = []
                 for fr in frames:
@@ -2167,36 +2299,45 @@ class NetServer(_BaseServer):
         fused_fn = getattr(be, "get_fused", None)
 
         def _serve_get(ops: list) -> None:
-            fused = None
             keys = np.concatenate([o.keys for o in ops])
             if len(keys) and fused_fn is not None:
-                # mesh plane: reply rows gather straight out of the
-                # ROUTED buffer per connection slice (hit rows only,
-                # one fancy-index per frame) — the full request-order
-                # page matrix is never materialized
-                fused = fused_fn(keys)
-                found = np.asarray(fused.found, bool)
+                # mesh plane: replies ship straight out of the ROUTED
+                # buffer — the full request-order page matrix is never
+                # materialized
+                src = fused_fn(keys)
             elif len(keys):
                 pages, found = be.get(self._pad_fused(keys))
-                pages = np.asarray(pages)
-                found = np.asarray(found, bool)
+                src = _Rows(np.asarray(pages), np.arange(len(found)),
+                            np.asarray(found, bool))
             else:
-                pages = np.zeros((0, W), np.uint32)
-                found = np.zeros(0, bool)
+                src = _Rows(np.zeros((0, W), np.uint32),
+                            np.zeros(0, np.int64), np.zeros(0, bool))
             tele.stage("get", "reply")
+            # hand-off: one unframed item per connection, sized from the
+            # hit counts; its writer gathers, casts and CRCs
+            hits = np.concatenate(
+                ([0], np.cumsum(np.asarray(src.found, bool),
+                                dtype=np.int64)))
+            items: dict = {}
             lo = 0
             for o in ops:
-                f = found[lo:lo + o.count]
-                if fused is not None:
-                    hitrows = fused.hit_rows(lo, lo + o.count)
-                else:
-                    hitrows = np.ascontiguousarray(
-                        pages[lo:lo + o.count][f], np.uint32)
-                lo += o.count
-                self._reply(o,
-                            MSG_SENDPAGE if f.any() else MSG_NOTEXIST,
-                            (f.astype(np.uint8), hitrows),
-                            count=o.count, words=W)
+                hi = lo + o.count
+                it = items.get(o.cs)
+                if it is None:
+                    it = items[o.cs] = _GetReplies(src, W)
+                it.verbs.append((o.seq, lo, hi))
+                it.nbytes += (_HDR.size + o.count
+                              + int(hits[hi] - hits[lo]) * W * 4)
+                lo = hi
+            for cs, it in items.items():
+                if cs.out_held:
+                    # its writer has not taken the last item yet: copy
+                    # this one's rows out, so that a peer that stops
+                    # reading holds one fetched buffer queued (and one
+                    # in its writer's hands), not one a flush. Only this
+                    # thread raises `out_held`, so no lock is needed.
+                    it.detach()
+                self._enqueue_reply(cs, it, it.nbytes)
 
         gets = [o for o in batch if o.mt == MSG_GETPAGE]
         if gets:
