@@ -333,6 +333,27 @@ def insert(state: KVState, config: KVConfig, keys: jnp.ndarray,
     reference stores the page's buffer address the same way), so index
     mutations that MOVE entries (CCEH splits, cuckoo kicks) never copy pages.
     """
+    state, res, _ = _insert_impl(state, config, keys, values)
+    return state, res
+
+
+# `insert_counted`'s work vector, in order: segments split, attempt rounds
+# run, keys evicted, and 1 when the batch held a key (a call that did work)
+INDEX_WORK_NAMES = ("index_splits", "index_insert_rounds",
+                    "index_evictions", "index_insert_calls")
+
+
+@partial(jax.jit, static_argnames=("config",))
+def insert_counted(state: KVState, config: KVConfig, keys: jnp.ndarray,
+                   values: jnp.ndarray):
+    """`insert` plus the index's work on the batch: (state, res,
+    int32[4] in `INDEX_WORK_NAMES` order). A family that places in one
+    pass reports 0 splits and one round per call."""
+    return _insert_impl(state, config, keys, values)
+
+
+def _insert_impl(state: KVState, config: KVConfig, keys: jnp.ndarray,
+                 values: jnp.ndarray):
     ops = get_index_ops(config.index.kind)
     valid = ~is_invalid(keys)
 
@@ -486,7 +507,14 @@ def insert(state: KVState, config: KVConfig, keys: jnp.ndarray,
         # tiered pool-exhaustion drops (flat: structurally zero)
         bumps = bumps.at[DROPS].add(shortfall.sum(dtype=jnp.int32))
     state = dataclasses.replace(state, stats=state.stats + bumps)
-    return state, res
+    called = valid.any().astype(jnp.int32)
+    work = jnp.stack([
+        jnp.int32(0) if res.splits is None else res.splits,
+        called if res.rounds is None else res.rounds,
+        evicted_mask.sum(dtype=jnp.int32),
+        called,
+    ]).astype(jnp.int32)
+    return state, res._replace(splits=None, rounds=None), work
 
 
 def _reattribute_recovering(bumps: jnp.ndarray) -> jnp.ndarray:
@@ -945,7 +973,8 @@ def _insert_extent_impl(state: KVState, config: KVConfig, key: jnp.ndarray,
                 pool, freed_c, rows_c, nothing)
         state = dataclasses.replace(state, pool=pool)
     bumps = jnp.zeros((NSTATS,), jnp.int32).at[EXTENT_PUTS].add(bump)
-    return dataclasses.replace(state, stats=state.stats + bumps), res, uncovered
+    return (dataclasses.replace(state, stats=state.stats + bumps),
+            res._replace(splits=None, rounds=None), uncovered)
 
 
 @partial(jax.jit, static_argnames=("config",))

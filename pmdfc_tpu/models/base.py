@@ -36,6 +36,12 @@ class InsertResult(NamedTuple):
                           # (INVALID where none). The KV façade reclaims pool
                           # rows from these — the analog of the reference
                           # reusing the evicted entry's page slot.
+    # int32[] work counts of a family whose insert loops (CCEH): segments
+    # split and attempt rounds run. None = one pass, nothing split. The
+    # KV façade folds them into its per-batch work vector
+    # (`kv.insert_counted`) and strips them from the result it returns.
+    splits: jnp.ndarray | None = None
+    rounds: jnp.ndarray | None = None
 
 
 @dataclasses.dataclass(frozen=True)

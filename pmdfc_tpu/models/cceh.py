@@ -336,9 +336,10 @@ def insert_batch(state: CCEHState, keys: jnp.ndarray, values: jnp.ndarray):
             want = jnp.zeros((g.Smax,), bool).at[
                 jnp.where(overflow, seg, jnp.int32(g.Smax))
             ].set(True, mode="drop")
-            table, ld, dirr, gdepth, nseg = _split_round(
-                g, table, ld, dirr, gdepth, nseg, want
-            )
+            with jax.named_scope("cceh_split_round"):
+                table, ld, dirr, gdepth, nseg = _split_round(
+                    g, table, ld, dirr, gdepth, nseg, want
+                )
             # placed entries may have moved (lane is split-invariant;
             # row is not)
             row2 = _locate(g, dirr, hdir, hwin)
@@ -353,7 +354,7 @@ def insert_batch(state: CCEHState, keys: jnp.ndarray, values: jnp.ndarray):
 
     slots0 = jnp.full((b,), -1, jnp.int32)
     fresh0 = jnp.zeros((b,), bool)
-    table, ld, dirr, gdepth, nseg, slots, fresh, _ = jax.lax.while_loop(
+    table, ld, dirr, gdepth, nseg, slots, fresh, rounds = jax.lax.while_loop(
         cond, body,
         (state.table, state.ld, state.dirr, state.gdepth, state.nseg,
          slots0, fresh0, jnp.int32(0)),
@@ -366,6 +367,7 @@ def insert_batch(state: CCEHState, keys: jnp.ndarray, values: jnp.ndarray):
     # gather+rank+extraction) is a no-op not worth its passes.
     inv2 = jnp.full((b, 2), INVALID_WORD, jnp.uint32)
 
+    @jax.named_scope("cceh_evict_tail")
     def tail_evict(op):
         table, slots, fresh = op
         pending = winner & (slots < 0)
@@ -413,9 +415,10 @@ def insert_batch(state: CCEHState, keys: jnp.ndarray, values: jnp.ndarray):
     new_state = dataclasses.replace(
         state, table=table, ld=ld, dirr=dirr, gdepth=gdepth, nseg=nseg
     )
+    # every split allocates one segment, so the count is nseg's growth
     res = InsertResult(
         slots=slots, evicted=evicted, dropped=dropped, fresh=fresh,
-        evicted_vals=evicted_vals,
+        evicted_vals=evicted_vals, splits=nseg - state.nseg, rounds=rounds,
     )
     return new_state, res
 

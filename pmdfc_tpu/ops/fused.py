@@ -39,8 +39,9 @@ returns, bit-identical outputs and stats deltas); the counting tiered
 epilogue (`tier.on_get`) and the recovering reattribution stay composed
 XLA *inside the same jitted program* — they are scatter-heavy state
 updates, not row traffic. Unsupported configurations (index families
-other than linear/cceh, unpaged pools, non-pow2 page widths, row counts
-off the 8-row group) silently ride the composed program.
+other than linear/cceh, unpaged pools, non-pow2 page widths, bucket rows
+narrower than 128 lanes, row counts off the 8-row group) silently ride the
+composed program.
 
 Platform gate: off-TPU the kernel runs in Pallas interpret mode
 (conformance/parity only; `resolve()` never *selects* fused off-chip
@@ -77,6 +78,11 @@ _EXTENT_TAG = 0x80000000  # kv.EXTENT_TAG
 
 _DEPTH = 8  # in-flight DMAs per stream (each stream has its own sem ring)
 _SUB = 8    # rows per (8, 128) HBM tile: the unit one DMA may move
+_LANES = 128  # lanes per HBM tile: a bucket row's width must be a multiple
+
+# the kernel's `pallas_call` name: its op in a compiled program (and so in
+# the device trace's op line) is named after it
+KERNEL_NAME = "pmdfc_fused_get"
 
 FUSED_FAMILIES = (IndexKind.LINEAR, IndexKind.CCEH)
 
@@ -88,6 +94,13 @@ def supports(config: KVConfig) -> bool:
     if config.index.kind not in FUSED_FAMILIES:
         return False
     if not config.paged:
+        return False
+    # whole 128-lane bucket rows: Mosaic refuses the 8-row group DMA of a
+    # slice narrower than a tile (linear with 16-slot clusters: 64 lanes)
+    idx = config.index
+    slots = (idx.probe_window if idx.kind == IndexKind.CCEH
+             else idx.cluster_slots)
+    if (4 * slots) % _LANES:
         return False
     # pow2 page width: the kernel's xor tree-fold digest requires it
     # (composed uses a ufunc reduce, equal on pow2)
@@ -364,6 +377,7 @@ def _pallas_get(keys, table, dirr, pages, *, family, tiered, S, W, Gmax,
             pltpu.SemaphoreType.DMA((d,)),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(*args)
     return out, code[0], rows[0], slots[0], vhi[0], dig[0]
 

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from pmdfc_tpu import kv as kv_mod
 from pmdfc_tpu.config import (ContainmentConfig, KVConfig, MeshConfig,
                               containment_enabled, mesh_enabled)
 from pmdfc_tpu.runtime import profiler
@@ -169,6 +170,11 @@ class PlaneBackend:
         t1 = tele.stage(phase, "reply")
         fetch_us = (t1 - t0) / 1e3
         self._note(phase, handle.counts, fetch_us, t0, t1)
+        if handle.work is not None:
+            # an insert's index work (splits, rounds, evictions, calls)
+            # onto the serving flush's counters
+            for name, n in zip(kv_mod.INDEX_WORK_NAMES, handle.work):
+                tele.stage_count(name, int(n))
         if prof is not None:
             # the routed counts vector splits the fetch across shards in
             # the SAME proportions that fed shard{i}_ops; the launch

@@ -476,9 +476,11 @@ def _packed_bloom_body(config: KVConfig, n: int, state):
 
 
 def _plane_insert_body(config: KVConfig, n: int, state, keys, values):
+    # the index's work counts ride out beside the result, one row per
+    # shard (`kv.INDEX_WORK_NAMES`), in the same fetch
     st = _unstack(state)
-    st2, res = kv_mod.insert(st, config, keys, values)
-    return _restack(st2), res
+    st2, res, work = kv_mod.insert_counted(st, config, keys, values)
+    return _restack(st2), (res, work[None])
 
 
 def _plane_get_body(config: KVConfig, n: int, fused: bool, state, keys):
@@ -584,9 +586,9 @@ def _plane_insert2_body(config: KVConfig, n: int, nrep: int, state, keys,
     # lane-identical by the control-purity invariant; lane-0 arbitration
     # is belt-and-braces so a damaged lane can never speak for the plane.
     st = _unstack(state)
-    st2, res = kv_mod.insert(st, config, keys, values)
+    st2, res, work = kv_mod.insert_counted(st, config, keys, values)
     r = jax.lax.axis_index(RAXIS).astype(jnp.int32)
-    res = jax.tree.map(lambda x: _replica_pick0(x, r), res)
+    res = jax.tree.map(lambda x: _replica_pick0(x, r), (res, work[None]))
     return _restack(st2), res
 
 
@@ -680,17 +682,29 @@ class PlaneHandle:
     attribution: which shards this phase actually touched).
     `t_launch_ns` is the launch stage's clock reading (0 when nothing
     launched), so the profiler can split the launch-to-fetch gap from
-    the time blocked in the fetch (`runtime/profiler.py`)."""
+    the time blocked in the fetch (`runtime/profiler.py`). `work` is
+    set by `fetch()` where the launch passed its reader (an insert): the
+    index's work summed over the shards, int64[4] in
+    `kv.INDEX_WORK_NAMES` order (None for other phases). The handle sets
+    it itself: a fetch closure that wrote it onto the handle would close
+    a reference cycle, and the flush's routed buffers and device outputs
+    would then wait for the cyclic collector instead of dying with the
+    handle."""
 
-    __slots__ = ("_fetch", "b", "counts", "t_launch_ns")
+    __slots__ = ("_fetch", "_work", "b", "counts", "t_launch_ns", "work")
 
-    def __init__(self, fetch, b: int, counts=None, t_launch_ns: int = 0):
+    def __init__(self, fetch, b: int, counts=None, t_launch_ns: int = 0,
+                 work=None):
         self._fetch = fetch
+        self._work = work
         self.b = b
         self.counts = counts
         self.t_launch_ns = t_launch_ns
+        self.work = None
 
     def fetch(self):
+        if self._work is not None:
+            self.work = self._work()
         return self._fetch()
 
 
@@ -1094,13 +1108,16 @@ class ShardedKV:
         else:
             fn = self._wrap("plane_insert", _plane_insert_body, 2, 1,
                             data_spec=P(AXIS))
-        self.state, res = fn(self.state, rb.keys, rb.values)
+        self.state, (res, work) = fn(self.state, rb.keys, rb.values)
         self._mut_seq += 1
 
         def fetch():
             return jax.tree.map(lambda x: rb.scatter(self._fetch(x)), res)
 
-        return PlaneHandle(fetch, rb.b, rb.counts, t_launch)
+        def fetch_work():
+            return np.asarray(self._fetch(work), np.int64).sum(axis=0)
+
+        return PlaneHandle(fetch, rb.b, rb.counts, t_launch, work=fetch_work)
 
     @_locked
     def plane_get(self, keys: np.ndarray) -> PlaneHandle:

@@ -44,6 +44,7 @@ Redesign notes (not a translation):
 from __future__ import annotations
 
 import collections
+import gc
 import math
 import os
 import socket
@@ -67,6 +68,9 @@ from pmdfc_tpu.runtime import workload as workload_mod
 # INVALID-key sentinel (utils.keys.INVALID_WORD without the jax import):
 # pow2 pad rows for fused wire batches — match nothing, place nothing.
 _INVALID = 0xFFFFFFFF
+
+# set once the process's warm heap has been frozen (`_freeze_warm_heap`)
+_HEAP_FROZEN = False
 
 MAGIC = 0xFC13
 # Reference vocabulary (`client/tcp_style/tcp.h:36-44`) + push extensions.
@@ -665,6 +669,22 @@ class _Waiter:
         self.error = None
 
 
+def _freeze_warm_heap() -> None:
+    """Take what the process holds before its first server starts (JAX,
+    the compiled programs, the plane) out of the cyclic collector's reach,
+    once. A full collection walks every tracked object with the GIL held:
+    over a warm serving heap (~460k objects on a v5e host) that is a
+    0.15-0.33 s pause of the flush thread, the readers and the writers at
+    once. After the freeze it walks only what serving allocates. Garbage
+    is collected first, so nothing dead is kept."""
+    global _HEAP_FROZEN
+    if _HEAP_FROZEN:
+        return
+    _HEAP_FROZEN = True
+    gc.collect()
+    gc.freeze()
+
+
 class NetServer(_BaseServer):
     """Serves a Backend (put/get/invalidate/packed_bloom) over TCP.
 
@@ -915,6 +935,7 @@ class NetServer(_BaseServer):
         # sampler armed (a v2 serving snapshot without its series block
         # would fail check_teledump).
         timeseries.ensure_collector()
+        _freeze_warm_heap()
         if self._coalesce and self._co_backend is None:
             # ONE serving backend for every connection: the whole point is
             # fusing verbs from all clients into one device batch per phase

@@ -1009,6 +1009,22 @@ def stage(phase: str | None, name: str) -> int:
     return t
 
 
+def stage_count(name: str, n: int) -> None:
+    """Add `n` to counter `name` on the calling thread's bound stage
+    scope (the NetServer flush loop's `stats`): per-flush work the
+    serving backend reports from inside a stage, counted like the stage
+    clock (plain counters, live with the tracing tier off), each
+    resolved once a binding like the stage counters. On an unbound
+    thread it counts nothing."""
+    tls = _SPAN_TLS
+    if tls.stages is None:
+        return
+    c = tls.ctrs.get(name)
+    if c is None:
+        c = tls.ctrs[name] = tls.stages.counter(name)
+    c.inc(n)
+
+
 def _stage_close(tls: _SpanTls, st: _Stage, t1: int) -> None:
     """Count, un-annotate and ring one closed stage."""
     key = (st.phase, st.name)

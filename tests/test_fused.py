@@ -238,6 +238,29 @@ def test_unsupported_configs_ride_composed(monkeypatch):
         assert not fused.resolve(_cfg())
 
 
+@pytest.mark.parametrize("kind,slots,ok", [
+    (IndexKind.LINEAR, 16, False),   # 64-lane rows: Mosaic refuses them
+    (IndexKind.LINEAR, 32, True),
+    (IndexKind.LINEAR, 64, True),
+    (IndexKind.CCEH, 16, False),
+    (IndexKind.CCEH, 32, True),
+])
+def test_rows_narrower_than_a_tile_ride_composed(monkeypatch, kind, slots,
+                                                 ok):
+    """A bucket row narrower than 128 lanes cannot take the kernel's 8-row
+    group DMA on the chip, so `supports()` leaves it to the composed
+    program even when forced, and a KV built on it never fuses."""
+    idx = (IndexConfig(kind=kind, capacity=2048, cluster_slots=slots)
+           if kind == IndexKind.LINEAR else
+           IndexConfig(kind=kind, capacity=2048, segment_slots=256,
+                       probe_window=slots))
+    cfg = KVConfig(index=idx, paged=True, page_words=W, fused_get="on")
+    monkeypatch.delenv("PMDFC_FUSED", raising=False)
+    assert fused.supports(cfg) is ok
+    assert fused.resolve(cfg) is ok
+    assert kv_mod.KV(cfg)._fused_on() is ok
+
+
 # --- 4/5. recompile signatures + the plane kill switch --------------------
 
 

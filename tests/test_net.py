@@ -8,6 +8,7 @@ boundary under the client stack — including a subprocess client, the
 multi-node analog of the reference's VM-driven runs (SURVEY §4.6).
 """
 
+import gc
 import subprocess
 import sys
 import threading
@@ -77,6 +78,27 @@ def test_roundtrip_put_get_invalidate():
         assert hit.all()
         _, found4 = be.get(keys[:8])
         assert not found4.any()
+
+
+def test_first_server_start_freezes_the_warm_heap(monkeypatch):
+    """What the process holds when its first server starts leaves the
+    cyclic collector's reach (a full collection then walks only what
+    serving allocates); a later start freezes nothing more."""
+    from pmdfc_tpu.runtime import net
+
+    monkeypatch.setattr(net, "_HEAP_FROZEN", False)
+    gc.unfreeze()
+    warm = [[i] for i in range(1000)]
+    srv, _ = _local_server()
+    with srv:
+        frozen = gc.get_freeze_count()
+        assert frozen >= len(warm)
+    later = [[i] for i in range(1000)]
+    srv, _ = _local_server()
+    with srv:
+        # frozen objects still die by refcount; none of `later` is frozen
+        assert gc.get_freeze_count() < frozen + len(later)
+    assert len(later) == len(warm)
 
 
 @pytest.mark.slow  # fast-tier 300 s contract: extent verbs stay covered
