@@ -310,14 +310,19 @@ def shard_of_np(keys: np.ndarray, n_shards: int) -> np.ndarray:
 @dataclasses.dataclass
 class RoutedBatch:
     """One host-routed batch: shard-major padded lanes + the scatter
-    map back to request order."""
+    map back to request order. `values` is a prefix view of the
+    router's held page buffer when `held` (hand it back with
+    `ShardRouter.release` once no launch reads it), else an array of
+    its own; `fresh` says the pages needed a newly allocated array."""
 
     keys: np.ndarray          # uint32[n*wl, 2] shard-major, INVALID pads
-    values: np.ndarray | None  # uint32[n*wl, V] aligned with keys
+    values: np.ndarray | None  # uint32[n*wl, V] aligned with keys, 0 pads
     pos: np.ndarray           # int64[b] routed lane of request i
     counts: np.ndarray        # int64[n] requests routed per shard
     wl: int                   # per-shard padded width (pow2)
     b: int                    # live request count
+    held: bool = False        # values is the router's held buffer
+    fresh: bool = False       # values needed a new allocation
 
     def scatter(self, routed: np.ndarray) -> np.ndarray:
         """Routed-lane result array → request order ([b, ...]). Each
@@ -337,7 +342,20 @@ class ShardRouter:
     set stays one ladder per shard count, and a skewed flush pays only
     its own shard's pad waste. Requests keep their in-batch order
     within each shard (stable binning), which is what makes cross-shard
-    dedupe-last-wins match the single-device ground truth.
+    dedupe-last-wins match the single-device ground truth. One shard
+    is the identity route: no owner hash, no binning, contiguous
+    copies.
+
+    Routed pages go into ONE page buffer the router holds and reuses
+    from batch to batch, grown (never shrunk) to the widest rung seen.
+    A fresh array per PUT batch would be, at the served width (8,192
+    rows of 4 KiB: 32 MiB, glibc's mmap threshold ceiling), a new
+    anonymous mapping that the copy page-faults in and the launch's end
+    unmaps, every flush. `build` marks the buffer busy; `release` frees
+    it once the launch that reads it has been fetched. A `build` that
+    finds it busy allocates an array of its own instead of waiting.
+    Callers serialize `build` (`ShardedKV`'s lock); `release` is one
+    store by the batch's holder, so it may run on any thread.
     """
 
     def __init__(self, n_shards: int, pad_floor: int = 8):
@@ -347,6 +365,8 @@ class ShardRouter:
             raise ValueError("pad_floor must be a positive power of two")
         self.n = n_shards
         self.pad_floor = pad_floor
+        self._buf: np.ndarray | None = None  # uint32[rows, V], held
+        self._busy = False
 
     def owners(self, keys: np.ndarray) -> np.ndarray:
         return shard_of_np(keys, self.n)
@@ -361,23 +381,51 @@ class ShardRouter:
               values: np.ndarray | None = None) -> RoutedBatch:
         keys = np.asarray(keys, np.uint32).reshape(-1, 2)
         b = len(keys)
-        own = self.owners(keys)
-        order = np.argsort(own, kind="stable")
-        counts = np.bincount(own, minlength=self.n).astype(np.int64)
-        wl = self.width(int(counts.max()) if b else 0)
-        starts = np.zeros(self.n, np.int64)
-        np.cumsum(counts[:-1], out=starts[1:])
-        own_sorted = own[order]
-        rank = np.arange(b, dtype=np.int64) - starts[own_sorted]
-        pos_sorted = own_sorted.astype(np.int64) * wl + rank
-        pos = np.empty(b, np.int64)
-        pos[order] = pos_sorted
+        if self.n == 1:
+            counts = np.array([b], np.int64)
+            pos = np.arange(b, dtype=np.int64)
+            wl = self.width(b)
+            lanes = slice(0, b)
+        else:
+            own = self.owners(keys)
+            order = np.argsort(own, kind="stable")
+            counts = np.bincount(own, minlength=self.n).astype(np.int64)
+            wl = self.width(int(counts.max()) if b else 0)
+            starts = np.zeros(self.n, np.int64)
+            np.cumsum(counts[:-1], out=starts[1:])
+            own_sorted = own[order]
+            rank = np.arange(b, dtype=np.int64) - starts[own_sorted]
+            pos_sorted = own_sorted.astype(np.int64) * wl + rank
+            pos = np.empty(b, np.int64)
+            pos[order] = pos_sorted
+            lanes = pos
         kp = np.full((self.n * wl, 2), INVALID_WORD, np.uint32)
-        kp[pos] = keys
-        vp = None
+        kp[lanes] = keys
+        rb = RoutedBatch(keys=kp, values=None, pos=pos, counts=counts,
+                         wl=wl, b=b)
         if values is not None:
             values = np.asarray(values, np.uint32)
-            vp = np.zeros((self.n * wl, values.shape[-1]), np.uint32)
-            vp[pos] = values
-        return RoutedBatch(keys=kp, values=vp, pos=pos, counts=counts,
-                           wl=wl, b=b)
+            vp = rb.values = self._page_buffer(rb, values.shape[-1])
+            vp[lanes] = values
+            for s in range(self.n):  # the pad lanes read 0
+                vp[s * wl + int(counts[s]):(s + 1) * wl] = 0
+        return rb
+
+    def _page_buffer(self, rb: RoutedBatch, width: int) -> np.ndarray:
+        """`rb`'s page rows: a prefix of the held buffer (grown to fit),
+        marked busy, or a new array where a launch still reads it."""
+        rows = self.n * rb.wl
+        buf = self._buf
+        if self._busy:
+            rb.fresh = True
+            return np.empty((rows, width), np.uint32)
+        if buf is None or buf.shape[0] < rows or buf.shape[1] != width:
+            rb.fresh = True
+            buf = self._buf = np.empty((rows, width), np.uint32)
+        self._busy = rb.held = True
+        return buf[:rows]
+
+    def release(self, rb: RoutedBatch) -> None:
+        """Hand `rb`'s page rows back: no launch reads them any more."""
+        if rb.held:
+            rb.held = self._busy = False

@@ -689,23 +689,30 @@ class PlaneHandle:
     it itself: a fetch closure that wrote it onto the handle would close
     a reference cycle, and the flush's routed buffers and device outputs
     would then wait for the cyclic collector instead of dying with the
-    handle."""
+    handle. `release` runs once the fetch returns or raises: it hands
+    the router's held page buffer back (an insert)."""
 
-    __slots__ = ("_fetch", "_work", "b", "counts", "t_launch_ns", "work")
+    __slots__ = ("_fetch", "_work", "_release", "b", "counts",
+                 "t_launch_ns", "work")
 
     def __init__(self, fetch, b: int, counts=None, t_launch_ns: int = 0,
-                 work=None):
+                 work=None, release=None):
         self._fetch = fetch
         self._work = work
+        self._release = release
         self.b = b
         self.counts = counts
         self.t_launch_ns = t_launch_ns
         self.work = None
 
     def fetch(self):
-        if self._work is not None:
-            self.work = self._work()
-        return self._fetch()
+        try:
+            if self._work is not None:
+                self.work = self._work()
+            return self._fetch()
+        finally:
+            if self._release is not None:
+                self._release()
 
 
 class PlaneGets:
@@ -1090,7 +1097,10 @@ class ShardedKV:
     # blocks on the device (JAX async dispatch: compute+transfer are
     # paid at fetch, not launch — the overlap the serving drivers use).
     # Each verb enters the `route` then the `launch` stage of its phase
-    # (`telemetry.stage`); `PlaneBackend._run` enters `fetch`.
+    # (`telemetry.stage`); `PlaneBackend._run` enters `fetch`. An
+    # insert's pages ride the router's held buffer, busy until the
+    # handle is fetched (`route_buf_reused` / `route_buf_alloc` count
+    # the batches that reused it / needed a new array).
 
     @_locked
     def plane_insert(self, keys: np.ndarray,
@@ -1098,7 +1108,11 @@ class ShardedKV:
         tele.stage("put", "route")
         self._lrfu_touch(keys)
         rb = self._router.build(keys, values)
+        tele.stage_count(
+            "route_buf_alloc" if rb.fresh else "route_buf_reused", 1)
+        release = partial(self._router.release, rb)
         if rb.b == 0:
+            release()
             return PlaneHandle(lambda: None, 0, rb.counts)
         t_launch = tele.stage("put", "launch")
         if self.n_replicas > 1:
@@ -1108,7 +1122,11 @@ class ShardedKV:
         else:
             fn = self._wrap("plane_insert", _plane_insert_body, 2, 1,
                             data_spec=P(AXIS))
-        self.state, (res, work) = fn(self.state, rb.keys, rb.values)
+        try:
+            self.state, (res, work) = fn(self.state, rb.keys, rb.values)
+        except BaseException:
+            release()
+            raise
         self._mut_seq += 1
 
         def fetch():
@@ -1117,7 +1135,8 @@ class ShardedKV:
         def fetch_work():
             return np.asarray(self._fetch(work), np.int64).sum(axis=0)
 
-        return PlaneHandle(fetch, rb.b, rb.counts, t_launch, work=fetch_work)
+        return PlaneHandle(fetch, rb.b, rb.counts, t_launch, work=fetch_work,
+                           release=release)
 
     @_locked
     def plane_get(self, keys: np.ndarray) -> PlaneHandle:
