@@ -236,13 +236,9 @@ def main() -> int:
     from pmdfc_tpu.bench.common import (
         append_history, build_backend, enable_compile_cache,
         stamp_live_device)
-    from pmdfc_tpu.config import autotune_enabled, net_pipe_enabled
+    from pmdfc_tpu.config import autotune_enabled
 
     enable_compile_cache()
-    if not net_pipe_enabled():
-        print("[autotune_sweep] PMDFC_NET_PIPE=off — the coalesced "
-              "tier is disabled; nothing to sweep")
-        return 2
     if not autotune_enabled():
         print("[autotune_sweep] PMDFC_AUTOTUNE=off — nothing to sweep")
         return 2

@@ -396,13 +396,9 @@ def main() -> int:
     from pmdfc_tpu.bench.common import (append_history,
                                         enable_compile_cache,
                                         stamp_live_device)
-    from pmdfc_tpu.config import containment_enabled, net_pipe_enabled
+    from pmdfc_tpu.config import containment_enabled
 
     enable_compile_cache()
-    if not net_pipe_enabled():
-        print("[containment_soak] PMDFC_NET_PIPE=off — the coalesced "
-              "tier is disabled; nothing to soak")
-        return 2
     if not containment_enabled():
         print("[containment_soak] PMDFC_CONTAINMENT=off — nothing to "
               "soak")

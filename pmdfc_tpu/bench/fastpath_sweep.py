@@ -158,15 +158,10 @@ def main() -> int:
     from pmdfc_tpu.bench.common import (
         append_history, build_backend, enable_compile_cache,
         stamp_live_device)
-    from pmdfc_tpu.config import NetConfig, fastpath_enabled, \
-        net_pipe_enabled
+    from pmdfc_tpu.config import NetConfig, fastpath_enabled
     from pmdfc_tpu.runtime.net import NetServer
 
     enable_compile_cache()
-    if not net_pipe_enabled():
-        print("[fastpath_sweep] PMDFC_NET_PIPE=off — the coalesced tier "
-              "is disabled; nothing to sweep")
-        return 2
     if not fastpath_enabled():
         print("[fastpath_sweep] PMDFC_FASTPATH=off — nothing to sweep")
         return 2

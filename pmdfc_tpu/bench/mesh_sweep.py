@@ -170,7 +170,7 @@ def main() -> int:
                     "127.0.0.1", srv.port, conns=args.connections,
                     window=args.window, verb=args.verb,
                     gets=max(4, args.gets // (2 if rnd == 0 else 1)),
-                    pipe=True, page_words=args.page_words, pool=live,
+                    page_words=args.page_words, pool=live,
                     verify=rnd == 0)
                 if res["misses"]:
                     raise RuntimeError(

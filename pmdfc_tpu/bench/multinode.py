@@ -32,12 +32,8 @@ def run_child(args) -> None:
     from pmdfc_tpu.runtime.net import TcpBackend
 
     def factory():
-        # --transport lockstep must pin BOTH halves of the wire: the
-        # server's serialized loop AND non-pipelined clients (a windowed
-        # client against a lockstep server is not the legacy baseline)
         return TcpBackend("127.0.0.1", args.port,
-                          page_words=args.page_words,
-                          pipeline=args.transport == "coalesced")
+                          page_words=args.page_words)
 
     be = ReconnectingClient(factory, page_words=args.page_words,
                             retry_delay_s=0.1)
@@ -64,12 +60,6 @@ def main() -> None:
     p.add_argument("--capacity", type=int, default=1 << 16)
     p.add_argument("--device", default="cpu", choices=("cpu", "tpu"),
                    help="server-side index device (children are jax-free)")
-    p.add_argument("--transport", default="coalesced",
-                   choices=("coalesced", "lockstep"),
-                   help="coalesced = cross-connection batch scheduler + "
-                        "pipelined clients (the serving tier); lockstep = "
-                        "the serialized legacy wire (PMDFC_NET_PIPE=off "
-                        "forces it regardless)")
     p.add_argument("--out-dir", default=None,
                    help="write per-client out_client{N} files here")
     p.add_argument("--child", type=int, default=None, help=argparse.SUPPRESS)
@@ -86,8 +76,7 @@ def main() -> None:
 
     shared, closer = build_backend("direct", args.page_words, args.capacity,
                                    device=args.device)
-    net = NetConfig() if args.transport == "coalesced" else None
-    srv = NetServer(lambda: shared, bf_push_s=1.0, net=net).start()
+    srv = NetServer(lambda: shared, bf_push_s=1.0, net=NetConfig()).start()
 
     t0 = time.perf_counter()
     procs = [
@@ -97,8 +86,7 @@ def main() -> None:
              "--job", args.job, "--file-pages", str(args.file_pages),
              "--ram-pages", str(args.ram_pages), "--ops", str(args.ops),
              "--page-words", str(args.page_words),
-             "--put-batch", str(args.put_batch),
-             "--transport", args.transport],
+             "--put-batch", str(args.put_batch)],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         )
         for i in range(args.clients)

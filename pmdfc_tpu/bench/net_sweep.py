@@ -1,29 +1,22 @@
 """Coalesced TCP serving tier sweep — connections × window × verb-size.
 
-The lockstep messenger pays one full device dispatch per verb per
-connection and serializes every connection behind the server's `op_lock`,
-so aggregate GET throughput flatlines at 1/RTT × 1 dispatch no matter how
-many clients attach. The coalesced tier (`NetConfig`: cross-connection
-batch scheduler + pipelined windowed clients) fuses ALL live connections'
-verbs into one device batch per flush — this sweep measures exactly that
-scaling curve, on the grid the reference's multi-queue design implies
-(clients × queue depth × verb size):
+`NetServer`'s cross-connection batch scheduler fuses ALL live
+connections' verbs into one device batch per flush, and pipelined
+clients keep a window of verbs outstanding per connection — this sweep
+measures that scaling curve on the grid the reference's multi-queue
+design implies (clients × queue depth × verb size), one
+``tcp_coalesced`` row per grid point (`NetConfig(...)` NetServer +
+pipelined clients with a per-connection outstanding window).
 
-- ``tcp_lockstep``  — `serialize_ops=True` NetServer + `pipeline=False`
-  clients (the seed tier, the baseline row).
-- ``tcp_coalesced`` — `NetConfig(...)` NetServer + pipelined clients
-  with a per-connection outstanding window.
-
-Both transports serve the SAME live KV, and rounds are interleaved
-(lockstep/coalesced alternating within each round) with the reported
-number per config the BEST round — min-of-rounds timing, so host drift
-cancels instead of biasing whichever transport ran last.
+Rounds repeat the whole grid and the reported number per config is the
+BEST round — min-of-rounds timing, so host drift cancels instead of
+biasing whichever config ran last.
 
 Every GET's `found` mask is checked and round 0 content-verifies pages
 against the key-derived fill (a transport bench that can mis-deliver
-pages is not evidence). The headline is `ratio_8c`: coalesced aggregate
-GET throughput at 8 connections / the single-connection lockstep
-baseline (acceptance floor: ≥ 3 on the same host).
+pages is not evidence). The headline is `ratio_8c`: aggregate GET
+throughput at 8 connections (best window) / one connection at the
+smallest window.
 
 Run: `python -m pmdfc_tpu.bench.net_sweep --smoke` (CI hook, asserts
 machinery + records nothing heavy) or full; `--history` appends
@@ -55,7 +48,7 @@ def _key_pool(n: int, seed: int = 7) -> np.ndarray:
 
 
 def _run_config(host: str, port: int, *, conns: int, window: int,
-                verb: int, gets: int, pipe: bool, page_words: int,
+                verb: int, gets: int, page_words: int,
                 pool: np.ndarray, verify: bool) -> dict:
     """One measured round: `conns` connections × `window` worker threads
     each issuing `gets` GET verbs of `verb` keys. Returns aggregate
@@ -68,7 +61,7 @@ def _run_config(host: str, port: int, *, conns: int, window: int,
         for attempt in (0, 1):
             try:
                 return TcpBackend(host, port, page_words=page_words,
-                                  keepalive_s=None, pipeline=pipe,
+                                  keepalive_s=None,
                                   window=max(window, 1),
                                   op_timeout_s=120.0)
             except (ConnectionError, OSError):
@@ -128,9 +121,7 @@ def main() -> int:
     p.add_argument("--device", default="cpu")
     p.add_argument("--connections", default="1,2,4,8")
     p.add_argument("--windows", default="1,8",
-                   help="per-connection outstanding windows for the "
-                        "coalesced transport (lockstep is window=1 by "
-                        "construction)")
+                   help="per-connection outstanding windows")
     p.add_argument("--verbs", default="16,64",
                    help="keys per GET verb (comma grid; the headline "
                         "ratio reads the FIRST entry)")
@@ -161,14 +152,10 @@ def main() -> int:
     from pmdfc_tpu.bench.common import (
         append_history, build_backend, enable_compile_cache,
         stamp_live_device)
-    from pmdfc_tpu.config import NetConfig, net_pipe_enabled
+    from pmdfc_tpu.config import NetConfig
     from pmdfc_tpu.runtime.net import NetServer
 
     enable_compile_cache()
-    if not net_pipe_enabled():
-        print("[net_sweep] PMDFC_NET_PIPE=off — the coalesced transport "
-              "is disabled; nothing to sweep")
-        return 2
 
     shared, closer = build_backend("direct", args.page_words,
                                    args.capacity, device=args.device)
@@ -180,30 +167,23 @@ def main() -> int:
     pool = pool[np.asarray(landed, bool)]
     print(f"[net_sweep] pool: {len(pool)} resident keys")
 
-    srv_lock = NetServer(lambda: shared, serialize_ops=True).start()
-    srv_coal = NetServer(
+    srv = NetServer(
         lambda: shared,
         net=NetConfig(flush_timeout_us=args.flush_timeout_us,
                       settle_us=args.settle_us)).start()
 
-    # (transport, conns, window, verb) grid; lockstep rides window=1
-    grid = []
-    for v in verb_grid:
-        for c in conns_grid:
-            grid.append(("tcp_lockstep", c, 1, v))
-            for w in win_grid:
-                grid.append(("tcp_coalesced", c, w, v))
+    # (transport, conns, window, verb) grid
+    grid = [("tcp_coalesced", c, w, v)
+            for v in verb_grid for c in conns_grid for w in win_grid]
 
     best: dict = {}
     try:
         for rnd in range(args.rounds + 1):  # round 0 = warmup + verify
             for transport, c, w, v in grid:
-                pipe = transport == "tcp_coalesced"
-                port = srv_coal.port if pipe else srv_lock.port
                 res = _run_config(
-                    "127.0.0.1", port, conns=c, window=w, verb=v,
+                    "127.0.0.1", srv.port, conns=c, window=w, verb=v,
                     gets=max(4, args.gets // (2 if rnd == 0 else 1)),
-                    pipe=pipe, page_words=args.page_words, pool=pool,
+                    page_words=args.page_words, pool=pool,
                     verify=rnd == 0)
                 if res["misses"]:
                     raise RuntimeError(
@@ -220,8 +200,7 @@ def main() -> int:
                       f"{res['pages_per_s'] / 1e3:.1f} Kpages/s "
                       f"({res['verbs_per_s']:.0f} verbs/s)")
     finally:
-        srv_lock.stop()
-        srv_coal.stop()
+        srv.stop()
         closer()
 
     rows = []
@@ -253,20 +232,18 @@ def main() -> int:
                    default=None)
 
     v0 = verb_grid[0]
-    base = _rate("tcp_lockstep", 1, 1, v0)
-    summary = {"rows": rows, "baseline_lockstep_1c": base}
-    cmax = max(conns_grid)
+    cmin, wmin, cmax = min(conns_grid), min(win_grid), max(conns_grid)
+    base = _rate("tcp_coalesced", cmin, wmin, v0)
+    summary = {"rows": rows, f"baseline_{cmin}c_w{wmin}": base}
     if base:
-        # the acceptance headline: aggregate coalesced GET throughput at
-        # 8 connections (best window) / single-connection lockstep
+        # the headline: aggregate GET throughput at the most connections
+        # (best window) / the fewest connections at the smallest window
         coal = _best_coal(cmax, v0)
-        lock = _rate("tcp_lockstep", cmax, 1, v0)
         if coal:
             summary[f"ratio_{cmax}c"] = round(coal / base, 2)
-        if lock:
-            summary[f"ratio_{cmax}c_lockstep"] = round(lock / base, 2)
         for v in verb_grid[1:]:
-            b2, c2 = _rate("tcp_lockstep", 1, 1, v), _best_coal(cmax, v)
+            b2 = _rate("tcp_coalesced", cmin, wmin, v)
+            c2 = _best_coal(cmax, v)
             if b2 and c2:
                 summary[f"ratio_{cmax}c_verb{v}"] = round(c2 / b2, 2)
     print(json.dumps(summary if not args.out else
@@ -275,9 +252,7 @@ def main() -> int:
         with open(args.out, "w") as f:
             json.dump(summary, f, indent=1)
     if args.smoke:
-        # machinery assertions: both transports served verified pages and
-        # the coalesced path actually coalesced (its server fused > 1 op
-        # per flush at the multi-connection point)
+        # machinery assertion: every grid point served verified pages
         ok = bool(best) and base
         print(f"[net_sweep] smoke {'OK' if ok else 'FAIL'}")
         return 0 if ok else 2

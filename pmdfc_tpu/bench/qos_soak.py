@@ -253,14 +253,10 @@ def main() -> int:
     from pmdfc_tpu.bench.common import (
         append_history, build_backend, enable_compile_cache,
         stamp_live_device)
-    from pmdfc_tpu.config import net_pipe_enabled, qos_enabled
+    from pmdfc_tpu.config import qos_enabled
     from pmdfc_tpu.runtime import qos as qos_mod
 
     enable_compile_cache()
-    if not net_pipe_enabled():
-        print("[qos_soak] PMDFC_NET_PIPE=off — the coalesced tier is "
-              "disabled; nothing to soak")
-        return 2
     if not qos_enabled():
         print("[qos_soak] PMDFC_QOS=off — nothing to soak")
         return 2

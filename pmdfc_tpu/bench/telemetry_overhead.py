@@ -100,14 +100,14 @@ def _measure(*, verb: int, gets: int, pairs: int, page_words: int,
                     net=NetConfig(flush_timeout_us=0, settle_us=0)).start()
     be = TcpBackend("127.0.0.1", srv.port, page_words=page_words,
                     keepalive_s=None, op_timeout_s=60.0)
-    if not (be.pipelined and be.traced):
-        raise RuntimeError("connection did not negotiate pipeline+trace")
+    if not be.traced:
+        raise RuntimeError("connection did not negotiate tracing")
     order = random.Random(seed)
 
     def segment() -> float:
         """`workers` threads share the pipelined backend so verbs FUSE
         into multi-op flushes — the coalesced tier's operating point
-        (a lone lockstep caller makes every verb a 1-op flush, charging
+        (a lone serial caller makes every verb a 1-op flush, charging
         the whole flush-level instrumentation to each verb: a shape the
         tier exists to avoid)."""
         import threading
@@ -216,13 +216,9 @@ def main() -> int:
         args.gets, args.pairs, args.preload = 30, 100, 2048
 
     from pmdfc_tpu.bench.common import append_history, stamp_live_device
-    from pmdfc_tpu.config import net_pipe_enabled, telemetry_enabled
+    from pmdfc_tpu.config import telemetry_enabled
     from pmdfc_tpu.runtime import telemetry as tele
 
-    if not net_pipe_enabled():
-        print("[telemetry_overhead] PMDFC_NET_PIPE=off — the instrumented "
-              "coalesced transport is disabled; nothing to measure")
-        return 2
     if not telemetry_enabled():
         print("[telemetry_overhead] PMDFC_TELEMETRY=off in the "
               "environment — the ON lane cannot run; unset it")

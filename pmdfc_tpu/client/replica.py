@@ -48,14 +48,13 @@ same batched Backend surface every other client layer speaks:
   never an exception, never wrong bytes: the PR-1 ladder invariant,
   extended with a fifth rung ("replica-set exhausted → legal miss").
 
-Pipelined endpoints: when the TCP tier runs the windowed protocol
-(`TcpBackend(pipeline=True)`, the default), the group's concurrent
-sub-batches to one endpoint — a hedge racing a fan-out PUT racing a
-repair GET — share that endpoint's connection window instead of
-convoying; an in-window failure fails them all at once, which the
-breaker sees as the SAME single-endpoint incident (one streak, not a
-per-op penalty), and every affected op degrades through its
-`ReconnectingClient` exactly as on the lockstep wire.
+Pipelined endpoints: the TCP tier's windowed protocol (`TcpBackend`)
+lets the group's concurrent sub-batches to one endpoint — a hedge
+racing a fan-out PUT racing a repair GET — share that endpoint's
+connection window instead of convoying; an in-window failure fails them
+all at once, which the breaker sees as the SAME single-endpoint
+incident (one streak, not a per-op penalty), and every affected op
+degrades through its `ReconnectingClient` like any dropped op.
 
 End-to-end integrity is group-owned: a bounded digest map (same
 discipline as `IntegrityBackend`) records every put's digest and

@@ -217,8 +217,8 @@ def ring_enabled(default: bool = True) -> bool:
     to the static murmur key→replica-set map — verb-for-verb identical
     to the pre-ring tree (the conformance escape hatch; membership is
     then immutable and the elastic wire capability is never requested
-    or acked). Resolved at construction time, like `PMDFC_NET_PIPE` — a
-    group never changes placement discipline mid-life."""
+    or acked). Resolved at construction time — a group never changes
+    placement discipline mid-life."""
     v = os.environ.get("PMDFC_RING", "").strip().lower()
     if v in ("off", "0", "false", "no"):
         return False
@@ -593,8 +593,8 @@ def mesh_enabled(default: bool = True) -> bool:
     plane back to the current single-device path (bit-identical results,
     the conformance escape hatch `tests/test_mesh.py` pins), `on` forces
     the mesh-sharded plane, and an unset/unknown value falls through to
-    `default`. Resolved at construction time, like `PMDFC_NET_PIPE` — a
-    serving plane never changes topology mid-life."""
+    `default`. Resolved at construction time — a serving plane never
+    changes topology mid-life."""
     v = os.environ.get("PMDFC_MESH", "").strip().lower()
     if v in ("off", "0", "false", "no"):
         return False
@@ -853,20 +853,6 @@ class AutotuneConfig:
                     f"[{lo}, {hi}]")
 
 
-def net_pipe_enabled(default: bool = True) -> bool:
-    """Resolve the `PMDFC_NET_PIPE` escape hatch: `off` forces the legacy
-    lockstep wire protocol + serialized server (the compatibility mode the
-    conformance test pins), `on` forces the pipelined/coalesced tier, and
-    an unset/unknown value falls through to `default`. Resolved at
-    construction time (a server/backend never changes mode mid-life)."""
-    v = os.environ.get("PMDFC_NET_PIPE", "").strip().lower()
-    if v in ("off", "0", "false", "no"):
-        return False
-    if v in ("on", "1", "true", "yes"):
-        return True
-    return default
-
-
 def fastpath_enabled(default: bool = True) -> bool:
     """Resolve the `PMDFC_FASTPATH` kill switch for the one-sided client
     fast path (client-mirrored directory + direct validated row reads,
@@ -875,7 +861,7 @@ def fastpath_enabled(default: bool = True) -> bool:
     capability ack and the client never builds a directory cache, so the
     wire transcript is verb-for-verb identical to a tree without the fast
     path (the PR 4/PR 7 conformance pattern). Resolved at construction
-    time, like `PMDFC_NET_PIPE`."""
+    time."""
     v = os.environ.get("PMDFC_FASTPATH", "").strip().lower()
     if v in ("off", "0", "false", "no"):
         return False
@@ -902,15 +888,11 @@ class NetConfig:
     INVALID-key rows (match nothing, place nothing) so the compiled-shape
     set stays bounded exactly like the engine driver's.
 
-    Client side (`TcpBackend(pipeline=..., window=...)`): sequence-tagged
-    frames with up to `window` verbs outstanding per connection and
-    per-verb deadlines (`op_timeout_s`) replacing the lockstep timeout.
+    Client side (`TcpBackend(window=...)`): sequence-tagged frames with
+    up to `window` verbs outstanding per connection and per-verb
+    deadlines (`op_timeout_s`)."""
 
-    `PMDFC_NET_PIPE=off` overrides everything back to lockstep."""
-
-    pipeline: bool = True
     window: int = 32
-    coalesce: bool = True
     flush_ops: int = 8192
     flush_timeout_us: int = 2000
     settle_us: int = 200

@@ -33,8 +33,8 @@ routed ops were in the failed program.
 
 `make_serving_backend` is the kill-switch seam: `PMDFC_MESH=off` (or
 `mesh_enabled()` false) returns the current single-device path
-(`DirectBackend` over `kv.KV`) — conformance-tested bit-identical, the
-`PMDFC_NET_PIPE` discipline applied to topology.
+(`DirectBackend` over `kv.KV`) — conformance-tested bit-identical to
+the sharded plane on a seeded workload served through `NetServer`.
 
 2-D planes (`MeshConfig.replica_axis > 1`, `PMDFC_MESH2D` kill switch):
 the mesh grows a `replica` axis carrying full per-shard state copies —

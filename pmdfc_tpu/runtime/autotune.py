@@ -397,7 +397,7 @@ class AutotuneController:
     # caller-holds: _lock
     def _resolve_balloon(self) -> None:
         """Lazy balloon-target resolution: the server's serving backend
-        exists only after `start()` (coalesced mode builds it then)."""
+        exists only after `start()`, which builds it."""
         if self._balloon is not None or self._server is None:
             return
         be = getattr(self._server, "_co_backend", None)

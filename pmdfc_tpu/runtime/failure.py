@@ -1114,10 +1114,6 @@ class ReconnectingClient:
         with self._lock:
             be = self._be
         out = dict(self._stats, connected=be is not None)
-        if be is not None and hasattr(be, "pipelined"):
-            # which wire protocol the LIVE connection negotiated —
-            # benches and monitors assert the mode they think they run
-            out["pipelined"] = bool(be.pipelined)
         if self.breaker is not None:
             out["breaker"] = self.breaker.state
         return out

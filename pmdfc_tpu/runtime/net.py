@@ -14,7 +14,9 @@ Redesign notes (not a translation):
 - Frames carry BATCHES (`keys[B,2]` + `pages[B,W]`), not one 4 KB page per
   message — the framework's deep-batch discipline applies to the wire too.
 - Two channels per client, associated by a client id in the HOLA: an **op
-  channel** (strict request/reply, serialized client-side) and a **push
+  channel** (sequence-tagged request/reply, up to a window of verbs in
+  flight; the server fuses all connections' verbs in one flush loop) and
+  a **push
   channel** (server→client stream for bloom pushes + heartbeats) — the
   structural analog of the reference's one-sided BF write riding a separate
   MR (`server/rdma_svr.cpp:157-251`).
@@ -23,9 +25,10 @@ Redesign notes (not a translation):
   `CleanCacheClient.receive_bloom_*` is kept by echoing CLIENT clock
   stamps: every op frame carries the client's `monotonic_ns` send stamp;
   the server samples, per client, the newest APPLIED put stamp *before*
-  packing the filter and echoes it in the push header. Because the op
-  channel serializes ops, any client put completed before that stamp is
-  provably inside the pushed filter (see `tests/test_net.py` race storm).
+  packing the filter and echoes it in the push header. A put's stamp is
+  recorded only once the put has been applied, so any client put
+  completed before that stamp is provably inside the pushed filter (see
+  `tests/test_net.py` race storm).
 - Delta sync: the server remembers the last packed filter it sent each
   push channel and ships only changed 8 KB blocks
   (`counting_bloom_filter.h:101-107` `GetUpdatedBlocks` analog).
@@ -57,8 +60,8 @@ import numpy as np
 
 from pmdfc_tpu.config import (ContainmentConfig, NetConfig, QosConfig,
                               containment_enabled, fastpath_enabled,
-                              mesh2d_enabled, net_pipe_enabled,
-                              profiler_enabled, qos_enabled, ring_enabled)
+                              mesh2d_enabled, profiler_enabled,
+                              qos_enabled, ring_enabled)
 from pmdfc_tpu.runtime import qos as qos_mod
 from pmdfc_tpu.runtime import sanitizer as san
 from pmdfc_tpu.runtime import telemetry as tele
@@ -168,9 +171,8 @@ CHAN_OP = 0
 CHAN_PUSH = 1
 # HOLA `status` carries the channel in its low byte; this flag bit on top
 # requests the PIPELINED protocol (sequence-tagged frames, windowed). The
-# server acks support via HOLASI `count=1`; a client whose request is not
-# acked falls back to lockstep on that connection, so mixed fleets and the
-# `PMDFC_NET_PIPE=off` compatibility mode interoperate frame-for-frame.
+# server always acks it via HOLASI `count` bit 0; a client refuses a
+# server that does not (`ConnectionError` at the handshake).
 PIPE_FLAG = 0x100
 # Second HOLA `status` flag bit: the client understands OP TRACING — when
 # the server acks (HOLASI `count` bit 1), every op REQUEST frame carries a
@@ -688,29 +690,23 @@ def _freeze_warm_heap() -> None:
 class NetServer(_BaseServer):
     """Serves a Backend (put/get/invalidate/packed_bloom) over TCP.
 
-    `backend_factory()` is called once per op connection — pass e.g.
-    `lambda: EngineBackend(kv_server)` for per-client arena isolation, or
-    a closure returning one shared `DirectBackend` (ops on a shared backend
-    are serialized by `op_lock`, the single-shared-KV discipline of
-    `server/rdma_svr.cpp:1161-1176`).
-
-    **Coalesced mode** (`net=NetConfig(...)`): the factory is called ONCE;
-    per-connection reader threads stage decoded verbs into one shared
-    queue, and a single flush loop drains puts/deletes/gets from ALL live
-    connections into one fused device batch per phase (adaptive timeout +
-    settle cutoff + pow2 pad ladder — `RuntimeConfig`'s engine-coalescer
-    knobs on the wire tier), then routes per-connection result slices back
-    to their sockets. N connections now share one device dispatch per
-    flush instead of paying N serialized dispatches — the reference's
-    multi-queue poller economics, which the lockstep `op_lock` path
-    forfeited. `PMDFC_NET_PIPE=off` forces the legacy path.
+    `backend_factory()` is called ONCE, in `start()`: every connection
+    shares the backend it returns. Per-connection reader threads stage
+    decoded verbs into one shared queue, and a single flush loop drains
+    puts/deletes/gets from ALL live connections into one fused device
+    batch per phase (adaptive dwell + settle cutoff + pow2 pad ladder —
+    `RuntimeConfig`'s engine-coalescer knobs on the wire tier, set by
+    `net`, which defaults to `NetConfig()`), then routes per-connection
+    results back through each connection's writer thread. N connections
+    share one device dispatch per flush — the reference's multi-queue
+    poller economics (`server/rdma_svr.h:16-19`). Every reply echoes its
+    request's seq, so clients always run the pipelined channel.
     """
 
     def __init__(self, backend_factory, host: str = "127.0.0.1",
                  port: int = 0, bf_push_s: float = 0.0,
                  bf_block_bytes: int = 8192,
                  idle_timeout_s: float = IDLE_TIMEOUT_S,
-                 serialize_ops: bool = True,
                  max_frame_bytes: int = 1 << 26,
                  net: NetConfig | None = None,
                  qos: QosConfig | None = None):
@@ -722,26 +718,11 @@ class NetServer(_BaseServer):
         self.backend_factory = backend_factory
         self.bf_push_s = bf_push_s
         self.bf_block_bytes = bf_block_bytes
-        # guarded-by: <none>  (pure critical section: serializes backend
-        # device programs on the legacy lockstep path)
-        self.op_lock = san.lock("NetServer.op_lock") if serialize_ops \
-            else None
-        # Cross-connection batch scheduler (the reference's multi-queue
-        # poller discipline on the wire tier): reader threads stage decoded
-        # verbs, ONE flush loop fuses them into per-phase device batches.
-        # `PMDFC_NET_PIPE=off` forces the legacy serialized path even when
-        # a NetConfig is supplied (the conformance escape hatch).
-        self.net = net
-        self._coalesce = bool(net is not None and net.coalesce
-                              and net_pipe_enabled())
-        # seq-echo/pipeline ack: any server mode can serve pipelined
-        # clients (echoing the request's seq costs nothing); only the
-        # env kill-switch withholds the ack so clients fall back too.
-        self._pipe_ok = net_pipe_enabled()
+        self.net = net if net is not None else NetConfig()
         # one-sided fast path (`PMDFC_FASTPATH`): resolved at
-        # construction like the pipe switch; `off` withholds the HOLA
-        # ack AND rejects the new verbs, so the wire transcript is
-        # verb-for-verb the pre-fast-path protocol
+        # construction; `off` withholds the HOLA ack AND rejects the new
+        # verbs, so the wire transcript is verb-for-verb the
+        # pre-fast-path protocol
         self._fast_ok = fastpath_enabled()
         # elastic membership verbs (`PMDFC_RING`): same contract — off
         # withholds the HOLASI ack and rejects RINGNOTE/HANDOFF, so the
@@ -834,12 +815,10 @@ class NetServer(_BaseServer):
         # switch — `PMDFC_QOS=off` (or no QosConfig) keeps `_qos` None
         # and the staging path below is byte-identical to the
         # single-FIFO tree: zero new wire bytes either way, tenancy is
-        # key-derived so there is no capability ack to withhold. Only
-        # meaningful in coalesced mode (the lockstep path has no
-        # staging queue to schedule).
+        # key-derived so there is no capability ack to withhold.
         self._qos = (qos_mod.QosPlane(qos, self.stats.prefix)
                      if qos is not None and qos.enabled and qos_enabled()
-                     and self._coalesce else None)
+                     else None)
         # blast-radius containment (`PMDFC_CONTAINMENT`): resolved at
         # construction like every switch. Off withholds the HOLASI ack
         # (no client sends deadlines or reads NACK) and disables
@@ -857,9 +836,6 @@ class NetServer(_BaseServer):
             collections.OrderedDict()
         self._co_backend = None
         self._flush_thread: threading.Thread | None = None
-        # dedicated backend for packing push filters — owned by the server,
-        # never borrowed from (and never dying with) a client connection
-        self._bloom_backend = None
         # guarded-by: <none>  (serializes push cycles: concurrent cycles
         # would interleave frames on a push socket)
         self._push_cycle_lock = san.lock("NetServer._push_cycle_lock")
@@ -879,10 +855,8 @@ class NetServer(_BaseServer):
         # exactly as the static config (the conformance contract).
         # guarded-by: _live_dwell_us, _live_settle_us
         self._knob_lock = san.lock("NetServer._knob_lock")
-        self._live_dwell_us = float(net.flush_timeout_us if net
-                                    else NetConfig.flush_timeout_us)
-        self._live_settle_us = float(net.settle_us if net
-                                     else NetConfig.settle_us)
+        self._live_dwell_us = float(self.net.flush_timeout_us)
+        self._live_settle_us = float(self.net.settle_us)
 
     # -- live flush knobs (autotune hooks) --
 
@@ -908,7 +882,7 @@ class NetServer(_BaseServer):
     # -- live QoS rate knobs (autotune hooks; plane self-locks) --
 
     def qos_plane(self):
-        """The live QosPlane, or None (plane off / lockstep mode) —
+        """The live QosPlane, or None (plane off) —
         the controller's probe for "are tenant knobs even available
         here", the `balloon_state` discipline."""
         return self._qos
@@ -936,7 +910,7 @@ class NetServer(_BaseServer):
         # would fail check_teledump).
         timeseries.ensure_collector()
         _freeze_warm_heap()
-        if self._coalesce and self._co_backend is None:
+        if self._co_backend is None:
             # ONE serving backend for every connection: the whole point is
             # fusing verbs from all clients into one device batch per phase
             self._co_backend = self.backend_factory()
@@ -965,10 +939,6 @@ class NetServer(_BaseServer):
                 and hasattr(self._co_backend, "close"):
             self._co_backend.close()
             self._co_backend = None
-        if self._bloom_backend is not None \
-                and hasattr(self._bloom_backend, "close"):
-            self._bloom_backend.close()
-            self._bloom_backend = None
 
     # -- dispatch --
 
@@ -995,7 +965,6 @@ class NetServer(_BaseServer):
                 del self._clients[cid]
 
     def _serve_conn(self, conn: socket.socket) -> None:
-        backend = None
         cid = None
         is_push = False
         op_registered = False
@@ -1032,10 +1001,8 @@ class NetServer(_BaseServer):
                 self._push_channel_hold(conn)
                 return
             # HOLASI count is a capability bitfield: bit 0 = seq-echo
-            # (pipelining) ack, bit 1 = trace-field ack. Old clients only
-            # ever requested PIPE_FLAG and test `count == 1`-equivalent
-            # truthiness on bit 0, so the bitfield stays interoperable.
-            pipe_ack = 1 if self._pipe_ok else 0
+            # (pipelining) ack, always set; bit 1 = trace-field ack.
+            pipe_ack = 1
             if (chan_raw & TRACE_FLAG) and tele.enabled():
                 pipe_ack |= 2
             if (chan_raw & ELASTIC_FLAG) and self._elastic_ok:
@@ -1057,42 +1024,22 @@ class NetServer(_BaseServer):
             # spans on the client timeline. Old clients never read the
             # (previously zero) field; the frame layout is unchanged.
             now_ns = time.monotonic_ns()
-            if self._coalesce:
-                if words and words != self._co_backend.page_words:
-                    _send_msg(conn, MSG_HOLASI, status=1,
-                              words=self._co_backend.page_words)
-                    return
-                if (chan_raw & FAST_FLAG) and self._fast_ok \
-                        and self._fast_capable(self._co_backend):
-                    pipe_ack |= 4
-                pipe_ack |= self._replica_ack(self._co_backend, chan_raw)
-                _send_msg(conn, MSG_HOLASI, status=0,
-                          words=self._co_backend.page_words,
-                          count=pipe_ack, stamp=now_ns)
-                self._bump("connects")
-                with self._lock:
-                    cl["ops"] += 1
-                op_registered = True
-                self._op_loop_coalesced(
-                    _ConnState(conn, cl, contain=bool(pipe_ack & 32)))
-                return
-            backend = self.backend_factory()
-            if words and words != backend.page_words:
-                _send_msg(conn, MSG_HOLASI, status=1,
-                          words=backend.page_words)
+            be = self._co_backend
+            if words and words != be.page_words:
+                _send_msg(conn, MSG_HOLASI, status=1, words=be.page_words)
                 return
             if (chan_raw & FAST_FLAG) and self._fast_ok \
-                    and self._fast_capable(backend):
+                    and self._fast_capable(be):
                 pipe_ack |= 4
-            pipe_ack |= self._replica_ack(backend, chan_raw)
-            _send_msg(conn, MSG_HOLASI, status=0,
-                      words=backend.page_words, count=pipe_ack,
-                      stamp=now_ns)
+            pipe_ack |= self._replica_ack(be, chan_raw)
+            _send_msg(conn, MSG_HOLASI, status=0, words=be.page_words,
+                      count=pipe_ack, stamp=now_ns)
             self._bump("connects")
             with self._lock:
                 cl["ops"] += 1
             op_registered = True
-            self._op_loop(conn, backend, cl)
+            self._reader_loop(
+                _ConnState(conn, cl, contain=bool(pipe_ack & 32)))
         except ProtocolError:
             # corrupted/desynced frame (bad magic, bad crc, unknown op):
             # count it and drop ONLY this connection — the peer's
@@ -1115,8 +1062,6 @@ class NetServer(_BaseServer):
                         elif op_registered:
                             cl["ops"] -= 1
                 self._release_client(cid)
-            if backend is not None and hasattr(backend, "close"):
-                backend.close()
 
     def _replica_ack(self, be, chan_raw: int) -> int:
         """HOLASI bits for the device-replica capability: bit 4 plus the
@@ -1235,41 +1180,30 @@ class NetServer(_BaseServer):
                  np.ascontiguousarray(tombs, np.uint32))
         return parts, (len(up) | full), len(tombs), cur["epoch"]
 
-    def _serve_recovery(self, be, subcmd: int, lock):
-        """MSG_RECOVERY body, shared by the lockstep loop (which passes
-        its backend lock) and the coalesced aux phase (which already
-        serializes with the flush loop — lock=None): subcmd 0 queries
-        `recovery_info()`, 1 flips `mark_recovered()` (idempotent).
-        Backends without the warm-restart surface answer
-        `{"recovering": false}` — the verb is unconditional, like
-        MSG_STATS."""
+    def _serve_recovery(self, be, subcmd: int):
+        """MSG_RECOVERY body, served in the aux phase (serialized with
+        the flush loop): subcmd 0 queries `recovery_info()`, 1 flips
+        `mark_recovered()` (idempotent). Backends without the
+        warm-restart surface answer `{"recovering": false}` — the verb
+        is unconditional, like MSG_STATS."""
         import json as _json
 
         if subcmd == 1:
             fn = getattr(be, "mark_recovered", None)
-            if lock is not None and fn is not None:
-                with lock:
-                    was = bool(fn())
-            else:
-                was = bool(fn()) if fn is not None else False
+            was = bool(fn()) if fn is not None else False
             body = {"recovering": False, "was_recovering": was}
             return _json.dumps(body).encode("utf-8"), int(was)
         fn = getattr(be, "recovery_info", None)
-        if lock is not None and fn is not None:
-            with lock:
-                info = fn()
-        else:
-            info = fn() if fn is not None else {"recovering": False}
+        info = fn() if fn is not None else {"recovering": False}
         return (_json.dumps(info).encode("utf-8"),
                 int(bool(info.get("recovering"))))
 
     def _serve_profile(self, duration_ms: int):
-        """MSG_PROFILE body, shared by the lockstep loop and the
-        coalesced aux phase (both already serialize with dispatch, so
-        the capture window brackets whole launches). Starts ONE bounded
-        `jax.profiler` trace under the flight recorder's dump dir via
-        the attached profiler — a daemon timer stops it, so the serving
-        loop never blocks for the capture window. Returns the reply
+        """MSG_PROFILE body, served in the aux phase (serialized with
+        dispatch, so the capture window brackets whole launches). Starts
+        ONE bounded `jax.profiler` trace under the flight recorder's dump
+        dir via the attached profiler — a daemon timer stops it, so the
+        serving loop never blocks for the capture window. Returns the reply
         payload (JSON bytes) or None = refuse (MSG_NOTEXIST): profiler
         not attached, no dump dir, capture live, or cooldown."""
         import json as _json
@@ -1316,194 +1250,9 @@ class NetServer(_BaseServer):
             if mt == MSG_ADIOS:
                 return
 
-    def _op_loop(self, conn: socket.socket, backend, cl: dict) -> None:
-        # every reply echoes the request's seq (the status field) so a
-        # pipelined client can match replies by sequence id; lockstep
-        # clients always send seq 0 and the echo is byte-identical to
-        # the legacy protocol
-        W = backend.page_words
-        while not self._stop.is_set():
-            try:
-                # on op requests the `words` field carries the client's
-                # 32-bit trace id (0 = untraced peer; see TRACE_FLAG)
-                mt, seq, count, words, stamp, payload = _recv_msg(
-                    conn, max_payload=self.max_frame_bytes)
-            except socket.timeout:
-                self._bump("idle_kills")
-                return
-            if mt == MSG_ADIOS:
-                return
-            self._bump("ops")
-            if mt == MSG_KEEPALIVE:
-                _send_msg(conn, MSG_KEEPALIVE, status=seq)
-                continue
-            t_op = time.perf_counter()
-            lock = self.op_lock
-            if mt == MSG_PUTPAGE or (mt == MSG_HANDOFF
-                                     and self._elastic_ok):
-                keys = _unpack_keys(payload, count)
-                self._observe_workload(keys)
-                pages = np.frombuffer(
-                    payload, np.uint32, count * W, offset=count * 8
-                ).reshape(count, W)
-                if lock:
-                    with lock:
-                        backend.put(keys, pages)
-                else:
-                    backend.put(keys, pages)
-                # applied-stamp AFTER the put returns: this put is now
-                # provably inside any filter packed later
-                with self._lock:
-                    cl["stamp"] = max(cl["stamp"], stamp)
-                if mt == MSG_HANDOFF:
-                    # migration traffic, attributed apart from organic
-                    # puts (the transition trajectory's server half)
-                    self._bump("handoff_pages", count)
-                _send_msg(conn, MSG_SUCCESS, count=count, status=seq)
-            elif mt == MSG_RINGNOTE and self._elastic_ok:
-                members = (int(np.frombuffer(payload, np.uint32, 1)[0])
-                           if len(payload) >= 4 else 0)
-                ne = self._serve_ringnote(backend, count, members,
-                                          cl["cid"])
-                _send_msg(conn, MSG_SUCCESS, count=count, status=seq,
-                          stamp=ne)
-            elif mt == MSG_GETPAGE:
-                keys = _unpack_keys(payload, count)
-                self._observe_workload(keys)
-                if lock:
-                    with lock:
-                        pages, found = backend.get(keys)
-                else:
-                    pages, found = backend.get(keys)
-                found = np.asarray(found, bool)
-                _send_frame(conn,
-                            MSG_SENDPAGE if found.any() else MSG_NOTEXIST,
-                            (found.astype(np.uint8),
-                             np.ascontiguousarray(pages[found], np.uint32)),
-                            count=count, words=W, status=seq)
-            elif mt == MSG_INVALIDATE:
-                keys = _unpack_keys(payload, count)
-                self._observe_workload(keys)
-                if lock:
-                    with lock:
-                        hit = backend.invalidate(keys)
-                else:
-                    hit = backend.invalidate(keys)
-                _send_frame(conn, MSG_SUCCESS,
-                            (np.asarray(hit, np.uint8),), count=count,
-                            status=seq)
-            elif mt == MSG_INSEXT:
-                # key[2] + value[2] + length, all u32; count echoes the
-                # server-reported uncovered tail (0 = fully indexed)
-                key = np.frombuffer(payload, np.uint32, 2)
-                val = np.frombuffer(payload, np.uint32, 2, offset=8)
-                length = int(np.frombuffer(payload, np.uint32, 1,
-                                           offset=16)[0])
-                if lock:
-                    with lock:
-                        uncovered = backend.insert_extent(key, val, length)
-                else:
-                    uncovered = backend.insert_extent(key, val, length)
-                _send_msg(conn, MSG_SUCCESS, count=int(uncovered),
-                          status=seq)
-            elif mt == MSG_GETEXT:
-                keys = _unpack_keys(payload, count)
-                if lock:
-                    with lock:
-                        vals, efound = backend.get_extent(keys)
-                else:
-                    vals, efound = backend.get_extent(keys)
-                efound = np.asarray(efound, bool)
-                _send_frame(conn, MSG_SENDPAGE,
-                            (efound.astype(np.uint8),
-                             np.ascontiguousarray(vals, np.uint32)),
-                            count=count, words=2, status=seq)
-            elif mt == MSG_STATS:
-                # counter snapshot (kv stats + tier counters when the
-                # backend exposes them); backends without a stats surface
-                # report an empty object, not an error
-                import json as _json
+    # -- cross-connection batch scheduler --
 
-                fn = getattr(backend, "stats", None)
-                if lock and fn is not None:
-                    with lock:
-                        snap = fn()
-                else:
-                    snap = fn() if fn is not None else {}
-                if tele.enabled():
-                    # the wire surface tools/teledump.py pulls: the whole
-                    # process registry + workload sketches ride the
-                    # backend snapshot (`pmdfc-telemetry-v2`)
-                    snap = dict(snap)
-                    snap["telemetry"] = tele.snapshot()
-                    snap["workload"] = self.workload.snapshot()
-                _send_msg(conn, MSG_SUCCESS,
-                          _json.dumps(snap).encode("utf-8"), status=seq)
-            elif mt == MSG_FASTREAD and self._fast_ok:
-                ok, hit, Wf, epoch = self._serve_fastread(
-                    backend, count, stamp, payload)
-                _send_frame(conn, MSG_SENDPAGE,
-                            (ok.astype(np.uint8), hit),
-                            count=count, words=Wf, status=seq, stamp=epoch)
-            elif mt == MSG_DIRPULL and self._fast_ok:
-                rep = self._serve_dirpull(backend, cl, count == 1)
-                if rep is None:
-                    _send_msg(conn, MSG_NOTEXIST, status=seq)
-                else:
-                    parts, cnt, nt, epoch = rep
-                    _send_frame(conn, MSG_DIRDELTA, parts, count=cnt,
-                                words=nt, status=seq, stamp=epoch)
-            elif mt == MSG_RREPAIR and self._replica_ok:
-                # device-side replica anti-entropy pass (one collective
-                # compare-and-copy over the plane's lane axis); count
-                # echoes the rows repaired. 1-D backends answer 0.
-                fn = getattr(backend, "replica_repair", None)
-                if lock and fn is not None:
-                    with lock:
-                        repaired = int(fn())
-                else:
-                    repaired = int(fn()) if fn is not None else 0
-                _send_msg(conn, MSG_SUCCESS, count=repaired, status=seq)
-            elif mt == MSG_RECOVERY:
-                # warm-restart surface: count 0 = query, 1 = mark
-                # recovered (idempotent; the replica tier calls it when
-                # a rejoined endpoint's repair queue drains)
-                body, cnt = self._serve_recovery(backend, count, lock)
-                _send_msg(conn, MSG_SUCCESS, body, count=cnt, status=seq)
-            elif mt == MSG_PROFILE and self._prof_ok:
-                # bounded on-demand device-time capture; refusal
-                # (cooldown/no dump dir) is a normal NOTEXIST answer
-                body = self._serve_profile(count)
-                if body is None:
-                    _send_msg(conn, MSG_NOTEXIST, status=seq)
-                else:
-                    _send_msg(conn, MSG_SUCCESS, body, status=seq)
-            elif mt == MSG_BFPULL:
-                # echo the client's newest APPLIED-put stamp, sampled
-                # BEFORE the pack (same safe retire bound as _push_cycle).
-                # It lives in the same clock domain as push-frame stamps;
-                # echoing the request stamp (client 'now') would make every
-                # later push look stale to the sink until a newer put
-                # out-stamped it — silently freezing the push path.
-                with self._lock:
-                    applied = cl["stamp"]
-                packed = backend.packed_bloom()
-                if packed is None:
-                    _send_msg(conn, MSG_NOTEXIST, stamp=applied, status=seq)
-                else:
-                    _send_frame(conn, MSG_BFPUSH,
-                                (np.ascontiguousarray(packed, np.uint32),),
-                                stamp=applied, status=seq)
-            else:
-                raise ProtocolError(f"unexpected op {mt}")
-            tele.record_span(
-                "server", _OP_NAMES.get(mt, f"op{mt}"), words, True,
-                dur_us=(time.perf_counter() - t_op) * 1e6,
-                conn=cl["cid"] & 0xFFFFFFFF, mode="lockstep")
-
-    # -- cross-connection batch scheduler (coalesced mode) --
-
-    def _op_loop_coalesced(self, cs: _ConnState) -> None:
+    def _reader_loop(self, cs: _ConnState) -> None:
         """Reader half of the scheduler: decode verbs off THIS connection
         into the shared staging queue; the flush loop executes and
         enqueues replies, which this connection's own writer thread
@@ -1880,7 +1629,7 @@ class NetServer(_BaseServer):
 
     def _conn_writer(self, cs: _ConnState) -> None:
         """Per-connection reply writer: the only thread that sends on
-        this socket in coalesced mode (reader keepalives and flush-loop
+        this socket (reader keepalives and flush-loop
         results both arrive through the queue, so frames never
         interleave). It frames the flush loop's `_GetReplies` items
         itself, while the flush thread runs the next flush."""
@@ -2371,7 +2120,7 @@ class NetServer(_BaseServer):
             t0, fs = _phase_begin("aux", 1)
             try:
                 if o.mt == MSG_RECOVERY:
-                    body, cnt = self._serve_recovery(be, o.count, None)
+                    body, cnt = self._serve_recovery(be, o.count)
                     self._reply(o, MSG_SUCCESS, (body,), count=cnt)
                 elif o.mt == MSG_PROFILE:
                     # bounded capture start, serialized with the flush
@@ -2401,8 +2150,10 @@ class NetServer(_BaseServer):
                     self._reply(o, MSG_SUCCESS,
                                 (_json.dumps(snap).encode("utf-8"),))
                 else:
-                    # same applied-stamp echo contract as the lockstep
-                    # BFPULL (sampled BEFORE the pack)
+                    # echo the client's newest APPLIED-put stamp,
+                    # sampled BEFORE the pack (the same safe retire bound
+                    # as _push_cycle): it lives in the push frames' clock
+                    # domain, so later pushes never look stale
                     with self._lock:
                         applied = o.cs.cl["stamp"]
                     packed = be.packed_bloom()
@@ -2449,10 +2200,10 @@ class NetServer(_BaseServer):
             ]
         if not targets:
             return out
-        # lazy dedicated backend — only built once a push channel exists
-        if self._bloom_backend is None:
-            self._bloom_backend = self.backend_factory()
-        packed = self._bloom_backend.packed_bloom()
+        be = self._co_backend
+        if be is None:
+            return out
+        packed = be.packed_bloom()
         if packed is None:
             return out
         packed = np.asarray(packed, np.uint32)
@@ -2577,18 +2328,16 @@ class TcpBackend:
     sink's snapshot-staleness logic works unchanged across the process
     boundary.
 
-    **Pipelined protocol** (default; `pipeline=False` or
-    `PMDFC_NET_PIPE=off` for lockstep): op frames carry a sequence id
-    (echoed in the reply header), up to `window` verbs may be
-    outstanding at once, and a writer/reader thread pair owns the
-    socket — concurrent threads sharing one backend overlap their
-    round trips instead of convoying behind a single lockstep verb.
-    Replies match by sequence id; an unmatched/duplicated/misshaped
-    reply, or a verb missing its per-verb deadline (`op_timeout_s`),
-    drops the connection and fails every in-window verb with
-    `ConnectionError` — `ReconnectingClient` degrades those to legal
-    misses/drops and journaled invalidates, exactly the lockstep
-    failure path.
+    **Pipelined protocol**: op frames carry a sequence id (echoed in the
+    reply header), up to `window` verbs may be outstanding at once, and
+    a writer/reader thread pair owns the socket — concurrent threads
+    sharing one backend overlap their round trips. Replies match by
+    sequence id; an unmatched/duplicated/misshaped reply, or a verb
+    missing its per-verb deadline (`op_timeout_s`), drops the
+    connection and fails every in-window verb with `ConnectionError` —
+    `ReconnectingClient` degrades those to legal misses/drops and
+    journaled invalidates. A server that does not ack the pipelined
+    channel at the handshake is refused with `ConnectionError`.
     """
 
     def __init__(self, host: str, port: int, page_words: int = 1024,
@@ -2596,7 +2345,7 @@ class TcpBackend:
                  keepalive_s: float | None = KEEPALIVE_DELAY_S,
                  client_id: int | None = None,
                  max_frame_bytes: int = 1 << 26,
-                 pipeline: bool | None = None, window: int = 32,
+                 window: int = 32,
                  directory: bool = False, dir_max_entries: int = 1 << 20,
                  deadline_ms: float = 0.0):
         self.page_words = page_words
@@ -2618,13 +2367,7 @@ class TcpBackend:
                   ^ int.from_bytes(os.urandom(8), "little"))
             & 0xFFFFFFFFFFFFFFFF
         )
-        # env overrides the param (the compatibility kill-switch), the
-        # param overrides the default; actual mode still needs the
-        # server's handshake ack (old/foreign servers get lockstep)
-        self._want_pipe = net_pipe_enabled(
-            default=True if pipeline is None else bool(pipeline))
         self.window = max(1, int(window))
-        self.pipelined = False
         # op tracing: request the TRACE_FLAG capability when the tracing
         # tier is live; `traced` holds the negotiated outcome. Per-verb
         # latency + window occupancy ride the process-shared client scope
@@ -2680,35 +2423,31 @@ class TcpBackend:
         self._last_op = time.monotonic()
         self._push_sock = None
         self._threads: list[threading.Thread] = []
-        if self.pipelined:
-            self._inflight: dict[int, _Waiter] = {}
-            # guarded-by: _inflight, _seq
-            self._infl_lock = san.lock("TcpBackend._infl_lock")
-            self._seq = 0
-            self._window_sem = _WindowGate(self.window)
-            self._outq: collections.deque = collections.deque()
-            # guarded-by: _outq
-            self._out_cv = san.condition("TcpBackend._out_cv")
-            # deadlines are per-verb (waiter waits); the reader blocks
-            # indefinitely — an idle pipelined channel must not die at
-            # op_timeout_s the way a pending lockstep read would
-            self._sock.settimeout(None)
-            r = threading.Thread(target=self._pipe_reader, daemon=True,
-                                 name="net-pipe-reader")
-            w = threading.Thread(target=self._pipe_writer, daemon=True,
-                                 name="net-pipe-writer")
-            r.start()
-            w.start()
-            self._threads += [r, w]
+        self._inflight: dict[int, _Waiter] = {}
+        # guarded-by: _inflight, _seq
+        self._infl_lock = san.lock("TcpBackend._infl_lock")
+        self._seq = 0
+        self._window_sem = _WindowGate(self.window)
+        self._outq: collections.deque = collections.deque()
+        # guarded-by: _outq
+        self._out_cv = san.condition("TcpBackend._out_cv")
+        # deadlines are per-verb (waiter waits); the reader blocks
+        # indefinitely — an idle channel must not die at op_timeout_s
+        self._sock.settimeout(None)
+        r = threading.Thread(target=self._pipe_reader, daemon=True,
+                             name="net-pipe-reader")
+        w = threading.Thread(target=self._pipe_writer, daemon=True,
+                             name="net-pipe-writer")
+        r.start()
+        w.start()
+        self._threads += [r, w]
         if bloom_sink is not None:
             try:
                 self._push_sock = self._handshake(host, port, CHAN_PUSH)
             except BaseException:
                 # don't leak the live op channel (and its server-side
                 # client record) when the second handshake fails
-                if self.pipelined:
-                    self._pipe_fail(ConnectionError("push handshake failed"))
-                self._sock.close()
+                self._pipe_fail(ConnectionError("push handshake failed"))
                 raise
             t = threading.Thread(target=self._push_reader,
                                  args=(bloom_sink,), daemon=True,
@@ -2726,7 +2465,7 @@ class TcpBackend:
         sock = socket.create_connection((host, port),
                                         timeout=self.op_timeout_s)
         sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-        want_pipe = self._want_pipe and chan == CHAN_OP
+        want_pipe = chan == CHAN_OP
         want_trace = chan == CHAN_OP and tele.enabled()
         want_fast = self._want_fast and chan == CHAN_OP
         want_elastic = self._want_elastic and chan == CHAN_OP
@@ -2753,12 +2492,14 @@ class TcpBackend:
                 f"handshake rejected (type={mt} status={status})"
             )
         # HOLASI count is a capability bitfield: bit 0 acks seq-echo
-        # (pipelining), bit 1 acks the trace field. No ack (an old
-        # server, or the respective kill switch) ⇒ the capability is off
-        # on this connection.
-        if want_pipe:
-            self.pipelined = bool(count & 1)
-        if want_trace and chan == CHAN_OP:
+        # (pipelining), bit 1 acks the trace field. The op channel is
+        # pipelined or refused; any other unacked capability (an old
+        # server, or the respective kill switch) is off on this
+        # connection.
+        if want_pipe and not count & 1:
+            sock.close()
+            raise ConnectionError("server did not ack the pipelined channel")
+        if want_trace:
             self.traced = bool(count & 2)
         if want_fast:
             self.fastpath = bool(count & 4)
@@ -2790,7 +2531,7 @@ class TcpBackend:
 
     def _roundtrip_parts(self, msg_type: int, parts, count: int,
                          stamp: int = 0):
-        """One verb, either wire mode, wrapped in its client span: a
+        """One verb, wrapped in its client span: a
         32-bit trace id is minted when the connection negotiated
         TRACE_FLAG (riding the request's words field), per-verb latency
         feeds the shared client histograms, and a verb that dies with
@@ -2809,12 +2550,8 @@ class TcpBackend:
                              conn=self.client_id & 0xFFFFFFFF)
         t0 = time.perf_counter()
         try:
-            if self.pipelined:
-                reply = self._pipe_roundtrip(msg_type, parts, count,
-                                             stamp, trace)
-            else:
-                reply = self._lockstep_roundtrip(msg_type, parts, count,
-                                                 stamp, trace)
+            reply = self._pipe_roundtrip(msg_type, parts, count, stamp,
+                                         trace)
         except Exception as e:
             # a verb that died with its connection closes its span as
             # FAILED (the chaos drills pin this: no dangling open spans)
@@ -2839,22 +2576,6 @@ class TcpBackend:
         else:
             tele.span_end(sp, ok=True)
         return reply
-
-    def _lockstep_roundtrip(self, msg_type: int, parts, count: int,
-                            stamp: int = 0, trace: int = 0):
-        with self._lock:
-            if self._closed:
-                raise ConnectionError("backend closed")
-            try:
-                _send_frame(self._sock, msg_type, parts, count=count,
-                            stamp=stamp, words=trace)
-                reply = _recv_msg(self._sock,
-                                  max_payload=self.max_frame_bytes)
-            except (ConnectionError, OSError, struct.error):
-                self._teardown_locked()
-                raise ConnectionError("transport failure") from None
-            self._last_op = time.monotonic()
-            return reply
 
     # -- pipelined op channel --
 
@@ -2921,14 +2642,10 @@ class TcpBackend:
     def set_window(self, n: int) -> int:
         """Live-set the pipeline window (the autotune controller's
         hook): verbs already in flight keep their slots; new verbs
-        admit under the new cap. A no-op cap change on a lockstep
-        connection (window applies only when pipelined). Returns the
-        applied value."""
+        admit under the new cap. Returns the applied value."""
         n = max(1, int(n))
         self.window = n
-        if self.pipelined:
-            return self._window_sem.set_limit(n)
-        return n
+        return self._window_sem.set_limit(n)
 
     def _pipe_reader(self) -> None:
         try:
@@ -3016,11 +2733,7 @@ class TcpBackend:
         best-effort data from a desynced stream would serve wrong pages.
         """
         exc = ProtocolError(msg)
-        if self.pipelined:
-            self._pipe_fail(exc)
-            raise exc
-        with self._lock:
-            self._teardown_locked()
+        self._pipe_fail(exc)
         raise exc
 
     def put(self, keys: np.ndarray, pages: np.ndarray) -> None:
@@ -3390,66 +3103,30 @@ class TcpBackend:
 
     def _keepalive_loop(self, interval: float) -> None:
         while not self._stop.wait(interval):
-            if self.pipelined:
-                if self._closed:
-                    return
-                if time.monotonic() - self._last_op < interval:
-                    continue
-                try:
-                    self._pipe_roundtrip(MSG_KEEPALIVE, (), 0)
-                except (ConnectionError, OSError, struct.error):
-                    return
+            if self._closed:
+                return
+            if time.monotonic() - self._last_op < interval:
                 continue
-            with self._lock:
-                if self._closed:
-                    return
-                idle = time.monotonic() - self._last_op
-                if idle < interval:
-                    continue
-                try:
-                    _send_msg(self._sock, MSG_KEEPALIVE)
-                    mt, *_ = _recv_msg(self._sock,
-                                       max_payload=self.max_frame_bytes)
-                    self._last_op = time.monotonic()
-                except (ConnectionError, OSError, struct.error):
-                    self._teardown_locked()
-                    return
+            try:
+                self._pipe_roundtrip(MSG_KEEPALIVE, (), 0)
+            except (ConnectionError, OSError, struct.error):
+                return
 
     # -- lifecycle --
 
-    def _teardown_locked(self) -> None:
-        self._closed = True
-        self._stop.set()
-        for s in (self._sock, self._push_sock):
-            if s is not None:
-                try:
-                    s.close()
-                except OSError:
-                    pass
-
     def close(self) -> None:
-        if self.pipelined:
-            with self._lock:
-                if self._closed:
-                    return
-            # graceful: queue ADIOS, give the writer a moment to drain,
-            # then tear down (failing any op still in the window)
-            with self._out_cv:
-                self._outq.append(_frame_views(MSG_ADIOS))
-                self._out_cv.notify()
-                deadline = time.monotonic() + 0.5
-                while self._outq and time.monotonic() < deadline:
-                    self._out_cv.wait(0.05)
-            self._pipe_fail(ConnectionError("backend closed"))
-            return
         with self._lock:
             if self._closed:
                 return
-            try:
-                _send_msg(self._sock, MSG_ADIOS)
-            except (ConnectionError, OSError):
-                pass
-            self._teardown_locked()
+        # graceful: queue ADIOS, give the writer a moment to drain, then
+        # tear down (failing any op still in the window)
+        with self._out_cv:
+            self._outq.append(_frame_views(MSG_ADIOS))
+            self._out_cv.notify()
+            deadline = time.monotonic() + 0.5
+            while self._outq and time.monotonic() < deadline:
+                self._out_cv.wait(0.05)
+        self._pipe_fail(ConnectionError("backend closed"))
 
     def __enter__(self) -> "TcpBackend":
         return self
@@ -3477,7 +3154,7 @@ class PoolServer(_BaseServer):
         self.max_frame_bytes = max_frame_bytes
         self.pool = pool
         # guarded-by: <none>  (serializes pool device programs)
-        self._op_lock = san.lock("PoolServer._op_lock")
+        self._pool_lock = san.lock("PoolServer._pool_lock")
         self.stats = tele.scope("pool", {
             "connects": 0, "ops": 0, "idle_kills": 0,
             "bad_rows": 0, "bad_frames": 0})
@@ -3537,7 +3214,7 @@ class PoolServer(_BaseServer):
                     _send_msg(conn, MSG_KEEPALIVE)
                 elif mt == MSG_GRANT:
                     try:
-                        with self._op_lock:
+                        with self._pool_lock:
                             lo, hi = self.pool.grant(count)
                     except Exception:  # noqa: BLE001 — exhausted pool
                         _send_msg(conn, MSG_GRANT, status=1)
@@ -3552,7 +3229,7 @@ class PoolServer(_BaseServer):
                     pages = np.frombuffer(
                         payload, np.uint32, count * W, offset=count * 4
                     ).reshape(count, W)
-                    with self._op_lock:
+                    with self._pool_lock:
                         self.pool.write_rows(rows, pages)
                     self._sync_pool_gauges()
                     _send_msg(conn, MSG_SUCCESS, count=count)
@@ -3560,7 +3237,7 @@ class PoolServer(_BaseServer):
                     rows = self._valid_rows(
                         np.frombuffer(payload, np.int32, count)
                     )
-                    with self._op_lock:
+                    with self._pool_lock:
                         out = self.pool.read_rows(rows)
                     self._sync_pool_gauges()
                     _send_frame(conn, MSG_SENDPAGE,
@@ -3572,7 +3249,7 @@ class PoolServer(_BaseServer):
                     # so teledump/teletop monitor a passive node too
                     import json as _json
 
-                    with self._op_lock:
+                    with self._pool_lock:
                         snap = dict(self.pool.stats())
                     self._sync_pool_gauges()
                     if tele.enabled():

@@ -79,7 +79,6 @@ HIERARCHY = {
     "SloWatchdog._lock": 15,
     "ReconnectingClient._lock": 20,
     # wire serving tier
-    "NetServer.op_lock": 30,
     "NetServer._push_cycle_lock": 32,
     "NetServer._flush_cv": 35,
     # per-tenant admission bucket (runtime/qos.py): refill/take and the
@@ -90,7 +89,7 @@ HIERARCHY = {
     "TokenBucket._lock": 37,
     "TcpBackend._lock": 40,
     "RemotePool._lock": 40,
-    "PoolServer._op_lock": 42,
+    "PoolServer._pool_lock": 42,
     # pipeline-window admission gate (live-resizable): acquired and
     # released within one gate call, never across another acquisition
     "_WindowGate._cv": 43,

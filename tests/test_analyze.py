@@ -354,7 +354,7 @@ def test_sanitizer_off_returns_plain_primitives():
 
 
 def test_sanitizer_detects_ab_ba_inversion(san_on):
-    a = san_on.lock("NetServer.op_lock")       # rank 30
+    a = san_on.lock("NetServer._push_cycle_lock")  # rank 32
     b = san_on.lock("KV._lock")                # rank 65 (inner)
     with a:
         with b:
@@ -365,12 +365,12 @@ def test_sanitizer_detects_ab_ba_inversion(san_on):
             pass
     v = san_on.violations()
     assert len(v) == 1 and v[0]["kind"] == "inversion"
-    assert v[0]["acquired"] == "NetServer.op_lock"
+    assert v[0]["acquired"] == "NetServer._push_cycle_lock"
     assert v[0]["while_holding"] == "KV._lock"
 
 
 def test_sanitizer_refuses_self_deadlock(san_on):
-    lk = san_on.lock("NetServer.op_lock")
+    lk = san_on.lock("NetServer._push_cycle_lock")
     with lk:
         with pytest.raises(RuntimeError, match="re-acquired"):
             lk.acquire()
@@ -453,7 +453,7 @@ def test_none_guard_with_justification_declares_no_fields(tmp_path):
 def test_sanitizer_nonblocking_self_probe_returns_false(san_on):
     # acquire(blocking=False) on a self-held lock cannot deadlock:
     # plain threading.Lock returns False there, so must the wrapper
-    lk = san_on.lock("NetServer.op_lock")
+    lk = san_on.lock("NetServer._push_cycle_lock")
     with lk:
         assert lk.acquire(blocking=False) is False
     assert san_on.violations() == []
@@ -491,7 +491,7 @@ def test_sanitizer_violations_reach_telemetry(san_on):
 
     tele.configure()
     b = san_on.lock("KV._lock")
-    a = san_on.lock("NetServer.op_lock")
+    a = san_on.lock("NetServer._push_cycle_lock")
     with b, a:
         # the violation is RECORDED immediately but its telemetry/rung
         # half (which can write a flight dump) must be deferred until

@@ -62,7 +62,7 @@ def _start_server(kv):
 
 
 def _soak(steps: int, seed: int, rates: dict, kill_at: tuple,
-          tmp_path, pipe: bool = False, window: int = 8) -> dict:
+          tmp_path, window: int = 1) -> dict:
     rng = np.random.default_rng(seed)
     keys = _keys(256, seed=seed)
     pages = _pages(keys)
@@ -90,7 +90,7 @@ def _soak(steps: int, seed: int, rates: dict, kill_at: tuple,
     def factory():
         return TcpBackend("127.0.0.1", port, page_words=W,
                           keepalive_s=None, op_timeout_s=1.0,
-                          pipeline=pipe, window=window)
+                          window=window)
 
     rc = ReconnectingClient(factory, page_words=W, retry_delay_s=0.005,
                             max_retry_delay_s=0.1, seed=seed)
@@ -188,7 +188,7 @@ def _soak(steps: int, seed: int, rates: dict, kill_at: tuple,
             port = px.port  # factory closes over `port` via nonlocal read
             rc._factory = lambda p=px.port: TcpBackend(
                 "127.0.0.1", p, page_words=W, keepalive_s=None,
-                op_timeout_s=1.0, pipeline=pipe, window=window)
+                op_timeout_s=1.0, window=window)
             stats["restores"] += 1
             # invariant 3b: before any new put lands, the server's hit set
             # is the durable snapshot's hit set (direct, chaos-free probe)
@@ -344,7 +344,7 @@ def test_chaos_soak_short_pipelined(tmp_path):
     degrades to a legal miss/drop; the soak finishing IS the
     no-exception invariant)."""
     s = _soak(steps=120, seed=5, rates=RATES, kill_at=(60,),
-              tmp_path=tmp_path, pipe=True, window=8)
+              tmp_path=tmp_path, window=8)
     assert s["wrong_bytes"] == 0
     assert s["restores"] == 1
     assert s["poisoned"] == 1
@@ -367,7 +367,7 @@ def test_chaos_pipelined_replies_match_seq_or_drop():
         def factory():
             return TcpBackend("127.0.0.1", px.port, page_words=W,
                               keepalive_s=None, op_timeout_s=1.0,
-                              pipeline=True, window=8)
+                              window=8)
 
         rc = ReconnectingClient(factory, page_words=W,
                                 retry_delay_s=0.005,
@@ -441,7 +441,7 @@ def test_chaos_soak_long_pipelined(tmp_path):
     cycles — the slow-tier twin of the pipelined acceptance soak."""
     rates = {k: v * 2 for k, v in RATES.items()}
     s = _soak(steps=600, seed=9, rates=rates, kill_at=(200, 420),
-              tmp_path=tmp_path, pipe=True, window=8)
+              tmp_path=tmp_path, window=8)
     assert s["wrong_bytes"] == 0
     assert s["restores"] == 2
     assert s["corrupt_detected"] > 0
@@ -464,7 +464,7 @@ def test_soak_leaves_attributable_trace(tmp_path):
     reg = tele.configure(TelemetryConfig(ring_capacity=1 << 15))
     try:
         s = _soak(steps=120, seed=5, rates=RATES, kill_at=(),
-                  tmp_path=tmp_path, pipe=True)
+                  tmp_path=tmp_path, window=8)
         assert s["wrong_bytes"] == 0
         spans = [r for r in reg.ring if r.get("kind") == "span"]
         client = [r for r in spans if r["src"] == "client"]

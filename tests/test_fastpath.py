@@ -49,11 +49,10 @@ def _cfg(capacity=1 << 10, tier=None):
                     paged=True, page_words=W, tier=tier)
 
 
-def _server(kv=None, coalesce=True, **kw):
+def _server(kv=None, **kw):
     kv = kv or KV(_cfg())
     shared = DirectBackend(kv)
-    net = NetConfig(flush_timeout_us=500, settle_us=50) if coalesce \
-        else None
+    net = NetConfig(flush_timeout_us=500, settle_us=50)
     return NetServer(lambda: shared, net=net, **kw).start(), kv
 
 

@@ -15,7 +15,7 @@ mesh. Four layers:
 3. **The serving drill** — a seeded mixed workload through the
    coalesced NetServer on a 4-shard plane is verb-for-verb
    BIT-IDENTICAL to the single-device path, and `PMDFC_MESH=off`
-   collapses the whole plane back to that path (the `PMDFC_NET_PIPE`
+   collapses the whole plane back to that path (the kill-switch
    conformance discipline applied to topology). KVServer's `mesh=`
    engine path rides the same drill.
 4. **Reshard restore** — snapshot on N shards, restore on M≠N: zero
@@ -214,19 +214,18 @@ def test_plane_counting_path_still_migrates_tier():
 # --- 3. the serving drill --------------------------------------------------
 
 
-def _serve_workload(backend_factory, coalesced=True):
+def _serve_workload(backend_factory):
     """Seeded mixed workload through a NetServer; returns the result
     transcript (the conformance unit of test_net.py, on the plane)."""
     from pmdfc_tpu.runtime.net import NetServer, TcpBackend
 
     srv = NetServer(backend_factory,
-                    net=NetConfig(flush_timeout_us=5000, settle_us=200)
-                    if coalesced else None,
-                    serialize_ops=not coalesced).start()
+                    net=NetConfig(flush_timeout_us=5000,
+                                  settle_us=200)).start()
     results = []
     try:
         with TcpBackend("127.0.0.1", srv.port, page_words=W,
-                        keepalive_s=None, pipeline=coalesced) as be:
+                        keepalive_s=None) as be:
             rng = np.random.default_rng(77)
             universe = _keys(256, seed=77)
             for _ in range(100):

@@ -148,7 +148,8 @@ def test_client_bounds_oversized_server_frame():
         conn, _ = lsock.accept()
         held.append(conn)  # keep alive past the test body
         conn.recv(1 << 16)  # swallow HOLA
-        _send_msg(conn, MSG_HOLASI, words=W)  # legit handshake
+        # legit handshake, pipelined channel acked
+        _send_msg(conn, MSG_HOLASI, words=W, count=1)
         conn.recv(1 << 16)  # swallow the GET
         # reply header claims a 256 MiB payload (over the 1 MiB bound)
         conn.sendall(_HDR.pack(MAGIC, MSG_SENDPAGE, 0, 0, W, 0,
@@ -539,8 +540,9 @@ def test_tcp_over_sharded_mesh_server():
 
 
 def test_engine_backend_factory_over_tcp():
-    """The production server shape: per-connection EngineBackend factories
-    (disjoint arena slices per client) in front of a running KVServer —
+    """An EngineBackend in front of a running KVServer behind the TCP
+    boundary: two interleaved connections share the one backend the
+    server's flush loop serves, and neither sees the other's pages —
     request coalescing and the TCP boundary composed."""
     from pmdfc_tpu.client.backends import EngineBackend
     from pmdfc_tpu.config import BloomConfig, IndexConfig, KVConfig
@@ -559,8 +561,8 @@ def test_engine_backend_factory_over_tcp():
             b2 = TcpBackend("127.0.0.1", srv.port, page_words=W)
             k1, k2 = _keys(32, seed=41), _keys(32, seed=42)
             p1, p2 = _pages(k1), _pages(k2)
-            # interleaved clients: distinct server-side arena slices must
-            # never bleed into each other
+            # interleaved clients: one connection's pages must never
+            # bleed into the other's replies
             b1.put(k1, p1)
             b2.put(k2, p2)
             out1, f1 = b1.get(k1)
@@ -653,37 +655,114 @@ def test_stale_delta_or_merges_instead_of_dropping():
 # --- pipelined wire protocol + cross-connection coalescer (netpipe) -----
 
 
-def test_pipeline_negotiation_and_env_killswitch(monkeypatch):
-    """Default clients negotiate the pipelined protocol (seq-echo ack in
-    the HOLASI count field); `PMDFC_NET_PIPE=off` forces lockstep on both
-    sides even when a NetConfig is supplied — and both modes serve the
-    same verbs."""
-    from pmdfc_tpu.config import NetConfig
+def test_pipeline_negotiation_and_env_killswitch():
+    """Every connection to a default `NetServer` (no `net=`) negotiates
+    the pipelined channel (seq-echo ack, bit 0 of the HOLASI count
+    field) and its put, get and invalidate answer as on any channel; a
+    client refuses a server that withholds the ack."""
+    import socket as socklib
+
+    from pmdfc_tpu.runtime import net as net_mod
 
     srv, _ = _local_server()
     with srv:
-        with TcpBackend("127.0.0.1", srv.port, page_words=W) as be:
-            assert be.pipelined  # lockstep server still acks seq-echo
-            keys = _keys(16)
-            be.put(keys, _pages(keys))
-            out, found = be.get(keys)
-            assert found.all() and np.array_equal(out, _pages(keys))
-        # explicit opt-out beats the default
-        with TcpBackend("127.0.0.1", srv.port, page_words=W,
-                        pipeline=False) as be2:
-            assert not be2.pipelined
-            out, found = be2.get(_keys(16))
+        for flags in (net_mod.PIPE_FLAG, 0):
+            with socklib.create_connection(("127.0.0.1", srv.port)) as c:
+                net_mod._send_msg(c, net_mod.MSG_HOLA,
+                                  status=net_mod.CHAN_OP | flags, words=W)
+                mt, status, count, *_ = net_mod._recv_msg(c)
+                assert (mt, status, count & 1) == (net_mod.MSG_HOLASI, 0, 1)
+        for i in range(2):
+            with TcpBackend("127.0.0.1", srv.port, page_words=W) as be:
+                keys = _keys(16, seed=i)
+                be.put(keys, _pages(keys))
+                out, found = be.get(keys)
+                assert found.all() and np.array_equal(out, _pages(keys))
+                assert be.invalidate(keys[:4]).all()
+                _, found = be.get(keys)
+                assert not found[:4].any() and found[4:].all()
+        assert srv.stats["flushes"] >= 1
+
+    lsock = socklib.socket()
+    lsock.bind(("127.0.0.1", 0))
+    lsock.listen(1)
+
+    def no_ack_server():
+        conn, _ = lsock.accept()
+        with conn:
+            net_mod._recv_msg(conn)
+            net_mod._send_msg(conn, net_mod.MSG_HOLASI, words=W)
+
+    th = threading.Thread(target=no_ack_server, daemon=True)
+    th.start()
+    with pytest.raises(ConnectionError, match="pipelined"):
+        TcpBackend("127.0.0.1", lsock.getsockname()[1], page_words=W,
+                    keepalive_s=None)
+    th.join(5)
+    lsock.close()
+
+
+def test_default_server_builds_one_backend_and_fuses_connections():
+    """`NetServer(factory)` with no `net=` serves through the flush
+    loop: the factory runs exactly once, in `start()`, and two
+    connections' GETs staged together are served in ONE flush."""
+    calls = []
+    gate, entered = threading.Event(), threading.Event()
+
+    class Gated(LocalBackend):
+        blocking = False
+
+        def get(self, keys):
+            if self.blocking:
+                self.blocking = False
+                entered.set()
+                gate.wait(10)
+            return super().get(keys)
+
+    def factory():
+        calls.append(1)
+        return Gated(page_words=W, capacity=1 << 10)
+
+    srv = NetServer(factory)
+    assert calls == []
+    srv.start()
+    with srv:
+        assert len(calls) == 1
+        bes = [TcpBackend("127.0.0.1", srv.port, page_words=W,
+                          keepalive_s=None) for _ in range(3)]
+        keys = [_keys(8, seed=30 + i) for i in range(3)]
+        for be, k in zip(bes, keys):
+            be.put(k, _pages(k))
+        # park the flush thread inside one GET, stage two more behind it
+        srv._co_backend.blocking = True
+        out: dict = {}
+
+        def get(i):
+            out[i] = bes[i].get(keys[i])
+
+        ts = [threading.Thread(target=get, args=(0,))]
+        ts[0].start()
+        assert entered.wait(10)
+        f0, c0 = srv.stats["flushes"], srv.stats["coalesced_ops"]
+        ts += [threading.Thread(target=get, args=(i,)) for i in (1, 2)]
+        for t in ts[1:]:
+            t.start()
+        deadline = time.time() + 10
+        while len(srv._staged) < 2 and time.time() < deadline:
+            time.sleep(0.005)
+        assert len(srv._staged) == 2
+        gate.set()
+        for t in ts:
+            t.join(10)
+        assert srv.stats["flushes"] - f0 == 1
+        assert srv.stats["coalesced_ops"] - c0 == 2
+        for i in range(3):
+            found_pages, found = out[i]
             assert found.all()
-    monkeypatch.setenv("PMDFC_NET_PIPE", "off")
-    srv2, _ = _local_server(net=NetConfig())
-    with srv2:
-        assert not srv2._coalesce  # env kills the coalescer too
-        with TcpBackend("127.0.0.1", srv2.port, page_words=W) as be3:
-            assert not be3.pipelined  # no ack ⇒ lockstep fallback
-            keys = _keys(8, seed=2)
-            be3.put(keys, _pages(keys))
-            _, found = be3.get(keys)
-            assert found.all()
+            assert np.array_equal(found_pages, _pages(keys[i]))
+        assert len(calls) == 1
+        for be in bes:
+            be.close()
 
 
 @pytest.mark.netpipe
@@ -744,7 +823,6 @@ def test_pipelined_storm_shared_backend():
     with srv:
         be = TcpBackend("127.0.0.1", srv.port, page_words=W,
                         keepalive_s=None, window=16)
-        assert be.pipelined
         errs: list = []
 
         def storm(i):
@@ -775,43 +853,47 @@ def test_pipelined_storm_shared_backend():
 
 @pytest.mark.netpipe
 def test_coalesced_vs_lockstep_conformance():
-    """The compatibility contract: a seeded mixed workload produces
-    verb-for-verb IDENTICAL results on the legacy lockstep path
-    (serialize_ops + non-pipelined client) and the coalesced+pipelined
-    path, against real KVs."""
-    from pmdfc_tpu.config import NetConfig
+    """The served flush loop against an independent reference: a seeded
+    120-verb mixed workload answered over the wire by a `NetServer` is
+    verb-for-verb IDENTICAL to the same workload applied in-process to a
+    twin `DirectBackend(KV)` with the same config."""
+    from pmdfc_tpu.client.backends import DirectBackend
+    from pmdfc_tpu.config import BloomConfig, IndexConfig, KVConfig, NetConfig
+    from pmdfc_tpu.kv import KV
 
-    def run(coalesced: bool):
-        srv, _ = _kv_server(
-            capacity=1 << 12,
-            **({"net": NetConfig(flush_timeout_us=5000, settle_us=200)}
-               if coalesced else {"serialize_ops": True}))
+    def run(be):
         results = []
-        with srv, TcpBackend("127.0.0.1", srv.port, page_words=W,
-                             keepalive_s=None,
-                             pipeline=coalesced) as be:
-            assert be.pipelined == coalesced
-            rng = np.random.default_rng(77)
-            universe = _keys(256, seed=77)
-            for _ in range(120):
-                op = int(rng.integers(4))
-                lo = int(rng.integers(0, 240))
-                n = int(rng.integers(1, 16))
-                sel = universe[lo:lo + n]
-                if op == 0:
-                    be.put(sel, _pages(sel))
-                    results.append(("put", n))
-                elif op in (1, 2):
-                    out, found = be.get(sel)
-                    results.append(("get", found.tolist(),
-                                    out[found].tolist()))
-                else:
-                    hit = be.invalidate(sel)
-                    results.append(("inval", hit.tolist()))
+        rng = np.random.default_rng(77)
+        universe = _keys(256, seed=77)
+        for _ in range(120):
+            op = int(rng.integers(4))
+            lo = int(rng.integers(0, 240))
+            n = int(rng.integers(1, 16))
+            sel = universe[lo:lo + n]
+            if op == 0:
+                be.put(sel, _pages(sel))
+                results.append(("put", n))
+            elif op in (1, 2):
+                out, found = be.get(sel)
+                found = np.asarray(found, bool)
+                results.append(("get", found.tolist(),
+                                np.asarray(out)[found].tolist()))
+            else:
+                hit = np.asarray(be.invalidate(sel), bool)
+                results.append(("inval", hit.tolist()))
         return results
 
-    assert run(False) == run(True), (
-        "coalesced path diverged from the lockstep reference")
+    srv, _ = _kv_server(
+        capacity=1 << 12,
+        net=NetConfig(flush_timeout_us=5000, settle_us=200))
+    with srv, TcpBackend("127.0.0.1", srv.port, page_words=W,
+                         keepalive_s=None) as be:
+        served = run(be)
+    cfg = KVConfig(index=IndexConfig(capacity=1 << 12),
+                   bloom=BloomConfig(num_bits=1 << 13),
+                   paged=True, page_words=W)
+    want = run(DirectBackend(KV(cfg)))
+    assert served == want, "the served flush loop diverged from the twin"
 
 
 # --- net-level chaos drills (ChaosProxy, deterministic armed faults) ----

@@ -4,8 +4,7 @@ One walk per function produces everything the rules need:
 
 - the with-stack of held locks at every point (lock identity resolved
   through the model: `self._lock` via the class MRO, `cs.out_cv` via
-  the unique declaring class, `with lock:` through local aliases like
-  ``lock = self.op_lock``),
+  the unique declaring class, `with lock:` through local aliases),
 - every write to an attribute (assign / augassign / del / subscript
   store / known mutating method call) with the held stack at that
   point — the guarded-by lint's raw material,
