@@ -1481,7 +1481,8 @@ class NetServer(_BaseServer):
         `stage_<phase>_<stage>_us`/`_n`: `idle` (loop top until a drain
         returns ops), `dwell` (gathering batch mates), then per phase
         `prep` (here and `_serve_coalesced`), `route`/`launch` (the
-        plane's verbs), `fetch` (`PlaneBackend._run`) and `reply`."""
+        plane's verbs), `fetch` (`PlaneBackend._run`) and `reply`. Each
+        stage also counts the thread's CPU time (`_cpu_us`)."""
         tele.bind_stages(self.stats)
         try:
             self._flush_cycles()

@@ -45,7 +45,10 @@ share:
   back-to-back stages at one clock reading per boundary. A thread that
   `bind_stages(scope)` (the NetServer flush loop binds its `stats`)
   counts each closed stage into `stage_<phase>_<name>_us` / `_n` there
-  — plain counters, live with the tracing tier off — mirrors it into
+  — plain counters, live with the tracing tier off — and the thread's
+  CPU time at the same boundary into `_cpu_us`, which splits the wall
+  time into work and waiting: off-CPU time is a wait for the GIL, a
+  lock, the device, a DMA or a core. It mirrors each stage into
   any profiler trace as `jax.profiler.TraceAnnotation("pmdfc.<phase>.
   <name>")`, and, with tracing on, rings it as a span record under the
   open `flush:<phase>` span. On an unbound thread a stage is only the
@@ -727,8 +730,8 @@ class _SpanTls(threading.local):
         self.stack: list = []
         # the stage clock (`bind_stages`): the counter scope bound to
         # this thread (None = stages are bare clock readings), the
-        # stage open on it, its (us, n) counters by stage, and the
-        # profiler annotation class (None without jax)
+        # stage open on it, its (us, n, cpu_us) counters by stage, and
+        # the profiler annotation class (None without jax)
         self.stages: Scope | None = None
         self.stage: _Stage | None = None
         self.ctrs: dict = {}
@@ -951,16 +954,18 @@ def clock_event(conn: int, offset_ns: int, rtt_ns: int) -> None:
 # -- stage clock ------------------------------------------------------------
 
 class _Stage:
-    """The stage open on a bound thread: its name, the reading it opened
-    at, its profiler annotation and its ring parent (None until an open
-    `flush:<phase>` span is seen, at open or at close)."""
+    """The stage open on a bound thread: its name, the wall and thread
+    CPU clock readings it opened at, its profiler annotation and its
+    ring parent (None until an open `flush:<phase>` span is seen, at open
+    or at close)."""
 
-    __slots__ = ("phase", "name", "t0", "ann", "parent")
+    __slots__ = ("phase", "name", "t0", "cpu0", "ann", "parent")
 
-    def __init__(self, phase, name, t0, ann, parent):
+    def __init__(self, phase, name, t0, cpu0, ann, parent):
         self.phase = phase
         self.name = name
         self.t0 = t0
+        self.cpu0 = cpu0
         self.ann = ann
         self.parent = parent
 
@@ -972,7 +977,8 @@ def bind_stages(scope: Scope | None) -> None:
     its `stats` for its lifetime."""
     tls = _SPAN_TLS
     if tls.stage is not None:
-        _stage_close(tls, tls.stage, time.monotonic_ns())
+        _stage_close(tls, tls.stage, time.monotonic_ns(),
+                     time.thread_time_ns())
         tls.stage = None
     tls.stages = scope
     tls.ctrs = {}
@@ -997,14 +1003,15 @@ def stage(phase: str | None, name: str) -> int:
     if cur is not None and cur.name == name and cur.phase == phase:
         return cur.t0
     t = time.monotonic_ns()
+    cpu = time.thread_time_ns()  # user + system: page faults count here
     if cur is not None:
-        _stage_close(tls, cur, t)
+        _stage_close(tls, cur, t, cpu)
     ann = None
     if tls.annotate is not None:
         ann = tls.annotate(f"pmdfc.{phase or 'flush'}.{name}")
         ann.__enter__()
     stack = tls.stack
-    tls.stage = _Stage(phase, name, t, ann,
+    tls.stage = _Stage(phase, name, t, cpu, ann,
                        stack[-1].sid if stack else None)
     return t
 
@@ -1025,17 +1032,20 @@ def stage_count(name: str, n: int) -> None:
     c.inc(n)
 
 
-def _stage_close(tls: _SpanTls, st: _Stage, t1: int) -> None:
-    """Count, un-annotate and ring one closed stage."""
+def _stage_close(tls: _SpanTls, st: _Stage, t1: int, cpu1: int) -> None:
+    """Count, un-annotate and ring one closed stage; `cpu1` is the
+    thread's CPU clock at its close."""
     key = (st.phase, st.name)
     c = tls.ctrs.get(key)
     if c is None:
         base = f"stage_{st.phase}_{st.name}" if st.phase \
             else f"stage_{st.name}"
         c = tls.ctrs[key] = (tls.stages.counter(f"{base}_us"),
-                             tls.stages.counter(f"{base}_n"))
+                             tls.stages.counter(f"{base}_n"),
+                             tls.stages.counter(f"{base}_cpu_us"))
     c[0].inc((t1 - st.t0 + 500) // 1000)
     c[1].inc()
+    c[2].inc((cpu1 - st.cpu0 + 500) // 1000)
     if st.ann is not None:
         st.ann.__exit__(None, None, None)
     if _STATE.tracing:

@@ -14,6 +14,7 @@ lives in this one file.
 from __future__ import annotations
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -148,3 +149,48 @@ def test_plane_get_compiles_on_four_chips(topo):
     c = fn.lower(state, keys).compile()
     assert "tpu_custom_call" in c.as_text()
     _fits(c)  # per device: one shard's state
+
+
+def _bloom_ops(hlo: str) -> dict:
+    """The instructions of an optimized module that write a whole
+    2^28-counter bloom, by kind: `copy` or `fusion` (a scatter)."""
+    out: dict = {"copy": [], "fusion": []}
+    for m in re.finditer(r"^\s*%(\S+) = s32\[268435456\]\{[^}]*\} (\w+)\(",
+                         hlo, re.M):
+        if m[2] in out:
+            out[m[2]].append(m[1])
+    return out
+
+
+def test_plane_insert_compiles_for_v5e(topo):
+    """The one-shard plane insert at the served PUT width (8,192 keys,
+    state donated) fits one chip. Its bloom passes: the insert and the
+    delete scatter-adds, and at most two whole-bloom copies, which XLA
+    makes for the two branch operands of `_bf_delete`'s `lax.cond`."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from pmdfc_tpu.parallel import partitioning as pt
+    from pmdfc_tpu.parallel import shard
+
+    cfg = _cfg()
+    mesh = Mesh(np.asarray(topo.devices[:1]), (shard.AXIS,))
+    specs = pt.state_specs(cfg, pt.rules_for_mesh(mesh, None))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct((1, *a.shape), a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        jax.eval_shape(lambda: kv_mod.init(cfg)), specs)
+    data = NamedSharding(mesh, P(shard.AXIS))
+    keys = jax.ShapeDtypeStruct((8192, 2), jnp.uint32, sharding=data)
+    pages = jax.ShapeDtypeStruct((8192, 1024), jnp.uint32, sharding=data)
+    fn = jax.jit(shard._shard_map(
+        partial(shard._plane_insert_body, cfg, 1), mesh=mesh,
+        in_specs=(specs, P(shard.AXIS), P(shard.AXIS)),
+        out_specs=(specs, (P(shard.AXIS), P(shard.AXIS)))),
+        donate_argnums=(0,))
+    c = fn.lower(state, keys, pages).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= 9 << 30
+    _fits(c)
+    ops = _bloom_ops(c.as_text())
+    assert len(ops["fusion"]) == 2, ops
+    assert len(ops["copy"]) <= 2, ops
