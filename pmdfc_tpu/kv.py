@@ -378,10 +378,18 @@ def _insert_impl(state: KVState, config: KVConfig, keys: jnp.ndarray,
     state = _bf_insert(state, config, keys, placed)
     evicted_mask = ~is_invalid(res.evicted)
     state = _bf_delete(state, config, res.evicted, evicted_mask)
+    unplaced = valid & res.dropped
+    gone, gone_mask = res.evicted, evicted_mask
+    if ops.overflow_evicts:
+        # an unplaced key is one its own batch evicted: a capacity loss
+        # (never in the bloom, so nothing to delete there)
+        gone = jnp.where(unplaced[:, None], keys, res.evicted)
+        gone_mask = evicted_mask | unplaced
+        unplaced = jnp.zeros_like(unplaced)
     # capacity evictions enter the evicted-key sketch HERE — the one
     # program that knows a key died of capacity, so a later GET's miss
     # can name the cause (`miss_evicted`, never a silent "cold")
-    state = _sketch_mark(state, config, res.evicted, evicted_mask)
+    state = _sketch_mark(state, config, gone, gone_mask)
 
     if state.pool is not None:
         tiered = isinstance(state.pool, tier_mod.TierState)
@@ -501,8 +509,8 @@ def _insert_impl(state: KVState, config: KVConfig, keys: jnp.ndarray,
 
     bumps = jnp.zeros((NSTATS,), jnp.int32)
     bumps = bumps.at[PUTS].add(valid.sum(dtype=jnp.int32))
-    bumps = bumps.at[EVICTIONS].add(evicted_mask.sum(dtype=jnp.int32))
-    bumps = bumps.at[DROPS].add((valid & res.dropped).sum(dtype=jnp.int32))
+    bumps = bumps.at[EVICTIONS].add(gone_mask.sum(dtype=jnp.int32))
+    bumps = bumps.at[DROPS].add(unplaced.sum(dtype=jnp.int32))
     if shortfall is not None:
         # tiered pool-exhaustion drops (flat: structurally zero)
         bumps = bumps.at[DROPS].add(shortfall.sum(dtype=jnp.int32))
@@ -511,7 +519,7 @@ def _insert_impl(state: KVState, config: KVConfig, keys: jnp.ndarray,
     work = jnp.stack([
         jnp.int32(0) if res.splits is None else res.splits,
         called if res.rounds is None else res.rounds,
-        evicted_mask.sum(dtype=jnp.int32),
+        gone_mask.sum(dtype=jnp.int32),
         called,
     ]).astype(jnp.int32)
     return state, res._replace(splits=None, rounds=None), work

@@ -83,6 +83,11 @@ class IndexOps:
     # slot (the probe gather runs at the chip's fixed ~79 Mrows/s issue rate,
     # so every non-gather op directly costs headline throughput).
     get_values: Callable[..., tuple] | None = None
+    # True where a batch's `dropped` keys (more new keys for a bucket than
+    # it has slots) are what a serial order inserting them first would
+    # have evicted: the KV façade then counts and sketches them as
+    # capacity evictions, not drops (linear's FIFO clusters).
+    overflow_evicts: bool = False
 
 
 _REGISTRY: dict[IndexKind, IndexOps] = {}

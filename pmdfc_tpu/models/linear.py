@@ -24,9 +24,10 @@ TPU-native redesign (not a translation):
   (`plan_insert`/`plan_rank`: dedupe-last-wins + per-cluster ranks from a
   single lexsort) rather than locks: every (cluster, rank) pair is a unique
   target lane. If a single batch carries more than S new keys for one
-  cluster the overflow keys are dropped and reported
-  (`InsertResult.dropped`) — legal under clean-cache, and it keeps the op
-  deterministic.
+  cluster the overflow keys are not placed (`InsertResult.dropped`): the
+  cluster ends as a serial insert that took them first would leave it
+  (each placed, then evicted by the S keys after it), so the KV façade
+  counts them as evictions (`IndexOps.overflow_evicts`).
 """
 
 from __future__ import annotations
@@ -391,5 +392,6 @@ register_index(
         scan=scan,
         set_values=set_values,
         get_values=get_values,
+        overflow_evicts=True,
     ),
 )

@@ -1100,7 +1100,20 @@ class ShardedKV:
     # (`telemetry.stage`); `PlaneBackend._run` enters `fetch`. An
     # insert's pages ride the router's held buffer, busy until the
     # handle is fetched (`route_buf_reused` / `route_buf_alloc` count
-    # the batches that reused it / needed a new array).
+    # the batches that reused it / needed a new array). Every launched
+    # route counts its keys, its lanes and its fullest shard's keys
+    # (`_count_route`).
+
+    @staticmethod
+    def _count_route(rb: pt.RoutedBatch) -> None:
+        """Count one launched route on the bound flush scope: real keys
+        (`route_keys`), lanes launched, `n * wl` (`route_lanes`), and the
+        fullest shard's keys (`route_shard_max_keys`); its `route` stage
+        carries the per-shard width and counts when traced."""
+        tele.stage_count("route_keys", rb.b)
+        tele.stage_count("route_lanes", len(rb.keys))
+        tele.stage_count("route_shard_max_keys", int(rb.counts.max()))
+        tele.stage_note(wl=rb.wl, counts=rb.counts)
 
     @_locked
     def plane_insert(self, keys: np.ndarray,
@@ -1114,6 +1127,7 @@ class ShardedKV:
         if rb.b == 0:
             release()
             return PlaneHandle(lambda: None, 0, rb.counts)
+        self._count_route(rb)
         t_launch = tele.stage("put", "launch")
         if self.n_replicas > 1:
             # one launch writes every replica lane (vs rf host loops)
@@ -1148,6 +1162,7 @@ class ShardedKV:
             empty = PlaneGets(rb, np.zeros((0, vw), np.uint32),
                               np.zeros(0, bool))
             return PlaneHandle(lambda: empty, 0, rb.counts)
+        self._count_route(rb)
         t_launch = tele.stage("get", "launch")
         lane = None
         if self.n_replicas > 1:
@@ -1248,6 +1263,7 @@ class ShardedKV:
         rb = self._router.build(keys)
         if rb.b == 0:
             return PlaneHandle(lambda: np.zeros(0, bool), 0, rb.counts)
+        self._count_route(rb)
         t_launch = tele.stage("del", "launch")
         if self.n_replicas > 1:
             # one launch deletes on every replica lane (loss-free: no

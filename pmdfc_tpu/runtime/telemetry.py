@@ -955,11 +955,11 @@ def clock_event(conn: int, offset_ns: int, rtt_ns: int) -> None:
 
 class _Stage:
     """The stage open on a bound thread: its name, the wall and thread
-    CPU clock readings it opened at, its profiler annotation and its
-    ring parent (None until an open `flush:<phase>` span is seen, at open
-    or at close)."""
+    CPU clock readings it opened at, its profiler annotation, its ring
+    parent (None until an open `flush:<phase>` span is seen, at open or
+    at close) and the arguments `stage_note` gave its ring span."""
 
-    __slots__ = ("phase", "name", "t0", "cpu0", "ann", "parent")
+    __slots__ = ("phase", "name", "t0", "cpu0", "ann", "parent", "args")
 
     def __init__(self, phase, name, t0, cpu0, ann, parent):
         self.phase = phase
@@ -968,6 +968,7 @@ class _Stage:
         self.cpu0 = cpu0
         self.ann = ann
         self.parent = parent
+        self.args = None
 
 
 def bind_stages(scope: Scope | None) -> None:
@@ -1032,6 +1033,28 @@ def stage_count(name: str, n: int) -> None:
     c.inc(n)
 
 
+def stage_note(**args) -> None:
+    """Give the stage open on the calling thread arguments: on its
+    profiler annotation while the profiler traces, and on its ring span
+    with the tracing tier on. A value with `tolist` (a numpy array) is
+    listed. With neither on, or on an unbound thread, it does nothing
+    but look."""
+    st = _SPAN_TLS.stage
+    if st is None:
+        return
+    ann = st.ann
+    traced = ann is not None and ann.is_enabled()
+    if not (traced or _STATE.tracing):
+        return
+    args = {k: v.tolist() if hasattr(v, "tolist") else v
+            for k, v in args.items()}
+    if traced:
+        ann.set_metadata(**{k: v if isinstance(v, (int, float, str))
+                            else str(v) for k, v in args.items()})
+    if _STATE.tracing:
+        st.args = args
+
+
 def _stage_close(tls: _SpanTls, st: _Stage, t1: int, cpu1: int) -> None:
     """Count, un-annotate and ring one closed stage; `cpu1` is the
     thread's CPU clock at its close."""
@@ -1054,7 +1077,7 @@ def _stage_close(tls: _SpanTls, st: _Stage, t1: int, cpu1: int) -> None:
             parent = tls.stack[-1].sid if tls.stack else 0
         record_tree_span("server", f"pmdfc.{st.phase or 'flush'}."
                          f"{st.name}", 0, parent, st.t0, t1,
-                         phase=st.phase or "flush")
+                         phase=st.phase or "flush", **(st.args or {}))
 
 
 # -- continuous profiling ---------------------------------------------------

@@ -122,31 +122,59 @@ def test_composed_program_fits_one_chip(one_chip, name, width):
     _fits(c)
 
 
-def test_plane_get_compiles_on_four_chips(topo):
-    """One read-only plane GET on a 4-device `kv` mesh: every shard runs
-    the fused kernel over its own 2^21-row pool (32 GiB in all)."""
+def _plane(topo, cfg, n):
+    """(mesh, state specs, stacked state shapes, data sharding) of an
+    n-device `kv` plane over the described chips."""
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from pmdfc_tpu.parallel import partitioning as pt
     from pmdfc_tpu.parallel import shard
 
-    cfg = _cfg()
-    mesh = Mesh(np.asarray(topo.devices[:4]), (shard.AXIS,))
-    rules = pt.rules_for_mesh(mesh, None)
-    specs = pt.state_specs(cfg, rules)
-    one = jax.eval_shape(lambda: kv_mod.init(cfg))
+    mesh = Mesh(np.asarray(topo.devices[:n]), (shard.AXIS,))
+    specs = pt.state_specs(cfg, pt.rules_for_mesh(mesh, None))
     state = jax.tree.map(
-        lambda a, s: jax.ShapeDtypeStruct((4, *a.shape), a.dtype,
+        lambda a, s: jax.ShapeDtypeStruct((n, *a.shape), a.dtype,
                                           sharding=NamedSharding(mesh, s)),
-        one, specs)
-    keys = jax.ShapeDtypeStruct((4 * W, 2), jnp.uint32,
-                                sharding=NamedSharding(mesh, P(shard.AXIS)))
+        jax.eval_shape(lambda: kv_mod.init(cfg)), specs)
+    return mesh, specs, state, NamedSharding(mesh, P(shard.AXIS))
+
+
+def _plane_get(topo, cfg, n, width, fused_get):
+    from jax.sharding import PartitionSpec as P
+
+    from pmdfc_tpu.parallel import shard
+
+    mesh, specs, state, data = _plane(topo, cfg, n)
+    keys = jax.ShapeDtypeStruct((n * width, 2), jnp.uint32, sharding=data)
     fn = jax.jit(shard._shard_map(
-        partial(shard._plane_get_ro_body, cfg, 4, True), mesh=mesh,
+        partial(shard._plane_get_ro_body, cfg, n, fused_get), mesh=mesh,
         in_specs=(specs, P(shard.AXIS)),
         out_specs=(P(shard.AXIS), P(shard.AXIS), P(shard.AXIS))))
-    c = fn.lower(state, keys).compile()
+    return fn.lower(state, keys).compile()
+
+
+def _plane_insert(topo, cfg, n, width):
+    from jax.sharding import PartitionSpec as P
+
+    from pmdfc_tpu.parallel import shard
+
+    mesh, specs, state, data = _plane(topo, cfg, n)
+    keys = jax.ShapeDtypeStruct((n * width, 2), jnp.uint32, sharding=data)
+    pages = jax.ShapeDtypeStruct((n * width, cfg.page_words), jnp.uint32,
+                                 sharding=data)
+    fn = jax.jit(shard._shard_map(
+        partial(shard._plane_insert_body, cfg, n), mesh=mesh,
+        in_specs=(specs, P(shard.AXIS), P(shard.AXIS)),
+        out_specs=(specs, (P(shard.AXIS), P(shard.AXIS)))),
+        donate_argnums=(0,))
+    return fn.lower(state, keys, pages).compile()
+
+
+def test_plane_get_compiles_on_four_chips(topo):
+    """One read-only plane GET on a 4-device `kv` mesh: every shard runs
+    the fused kernel over its own 2^21-row pool (32 GiB in all)."""
+    c = _plane_get(topo, _cfg(), 4, W, True)
     assert "tpu_custom_call" in c.as_text()
     _fits(c)  # per device: one shard's state
 
@@ -167,30 +195,35 @@ def test_plane_insert_compiles_for_v5e(topo):
     state donated) fits one chip. Its bloom passes: the insert and the
     delete scatter-adds, and at most two whole-bloom copies, which XLA
     makes for the two branch operands of `_bf_delete`'s `lax.cond`."""
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from pmdfc_tpu.parallel import partitioning as pt
-    from pmdfc_tpu.parallel import shard
-
-    cfg = _cfg()
-    mesh = Mesh(np.asarray(topo.devices[:1]), (shard.AXIS,))
-    specs = pt.state_specs(cfg, pt.rules_for_mesh(mesh, None))
-    state = jax.tree.map(
-        lambda a, s: jax.ShapeDtypeStruct((1, *a.shape), a.dtype,
-                                          sharding=NamedSharding(mesh, s)),
-        jax.eval_shape(lambda: kv_mod.init(cfg)), specs)
-    data = NamedSharding(mesh, P(shard.AXIS))
-    keys = jax.ShapeDtypeStruct((8192, 2), jnp.uint32, sharding=data)
-    pages = jax.ShapeDtypeStruct((8192, 1024), jnp.uint32, sharding=data)
-    fn = jax.jit(shard._shard_map(
-        partial(shard._plane_insert_body, cfg, 1), mesh=mesh,
-        in_specs=(specs, P(shard.AXIS), P(shard.AXIS)),
-        out_specs=(specs, (P(shard.AXIS), P(shard.AXIS)))),
-        donate_argnums=(0,))
-    c = fn.lower(state, keys, pages).compile()
+    c = _plane_insert(topo, _cfg(), 1, 8192)
     assert c.memory_analysis().alias_size_in_bytes >= 9 << 30
     _fits(c)
     ops = _bloom_ops(c.as_text())
     assert len(ops["fusion"]) == 2, ops
     assert len(ops["copy"]) <= 2, ops
+
+
+def test_four_chip_cell_programs_compile(topo):
+    """The `linear-32g-4chip` cell's plane programs, from its configuration
+    file, on a 4-device `kv` mesh: the composed GET (16-slot rows take no
+    kernel) at the top of the warmed ladder, 16,384 keys a shard, and the
+    donated insert at the served PUT width, 8,192 keys a shard. Each
+    device holds one shard's 2^21-row pool and bloom, and fits its chip."""
+    import json
+
+    from benchmark.run import kv_config
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "linear-32g-4chip.json")
+    with open(path) as f:
+        cell = json.load(f)
+    cfg = kv_config(cell)
+    n = cell["mesh"]["n_shards"]
+    assert n == 4 and not fused.supports(cfg)
+    get = _plane_get(topo, cfg, n, 16384, False)
+    assert "tpu_custom_call" not in get.as_text()
+    _fits(get)
+    ins = _plane_insert(topo, cfg, n, 8192)
+    assert ins.memory_analysis().alias_size_in_bytes >= 9 << 30
+    _fits(ins)
